@@ -24,16 +24,12 @@ def main():
     parser.add_argument("--dataset", type=str, default="data")
     args = parser.parse_args()
 
-    import os
-
-    if os.environ.get("HEAT_TPU_FORCE_CPU") == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
     import jax
 
     import heat_tpu as ht
+    from heat_tpu.core import serving
+
+    serving.use_entry_point_compile_cache()
 
     if args.path:
         x = ht.load_hdf5(args.path, args.dataset, split=0)

@@ -23,14 +23,10 @@ def main():
     parser.add_argument("--torch-baseline", action="store_true")
     args = parser.parse_args()
 
-    import os
-
-    if os.environ.get("HEAT_TPU_FORCE_CPU") == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
     import heat_tpu as ht
+    from heat_tpu.core import serving
+
+    serving.use_entry_point_compile_cache()
 
     ht.random.seed(0)
     x = ht.random.randn(args.n, args.f, split=0)

@@ -32,8 +32,10 @@ def _series(benchmark: str, per_device: int, sizes) -> list:
         import os
 
         env = dict(os.environ)
+        # virtual meshes are CPU meshes by construction: the children must
+        # not reach for a chip whatever JAX_PLATFORMS this process inherited
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-        env["HEAT_TPU_FORCE_CPU"] = "1"
+        env["JAX_PLATFORMS"] = "cpu"
         extra = []
         if benchmark == "lasso" and p == 1:
             # single-node external baseline (reference benchmarks/lasso/

@@ -27,8 +27,8 @@ __all__ = ["KMeans"]
 @partial(jax.jit, static_argnames=("k", "n_steps"))
 def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
     """``n_steps`` fused Lloyd iterations in ONE XLA program — amortizes the
-    per-dispatch latency (the reference pays an MPI round per iteration; a
-    remote-dispatch TPU pays one RPC per *program*, so fusing the loop is the
+    per-dispatch latency (the reference pays an MPI round per iteration; here
+    the host pays one dispatch per *program*, so fusing the loop is the
     TPU-side analog of batching the collectives).
 
     The |x|² term of the quadratic-expansion distance is loop-invariant: the
@@ -79,10 +79,10 @@ class KMeans(_KCluster):
     max_iter=300, tol=1e-4, random_state=None. ``use_fused`` (beyond the
     reference) selects the single-pass samples-in-lanes pallas Lloyd kernel
     (ops/lloyd.py): ``None`` auto-selects it on TPU backends, where it reads
-    the operand once per iteration — measured 1.65x the jnp path at ~90% of
-    the v5e HBM roofline (benchmarks/TPU_WINDOW_r04.json);
+    the operand once per iteration where the jnp path reads it twice;
     ``True`` forces it (interpret mode off-TPU — the testing path), ``False``
-    pins the jnp oracle path.
+    pins the jnp oracle path. A kernel that fails to lower or run raises:
+    there is no fallback from the fused path to the oracle.
     """
 
     def __init__(
@@ -174,47 +174,26 @@ class KMeans(_KCluster):
         xT = xsq = None
         while done < self.max_iter:
             chunk = min(8, self.max_iter - done)
-            try:
-                if mode == "single":
-                    if xT is None:
-                        xT, xsq = _lloyd._prepare_run_operands(data, self.n_clusters)
-                    centers, labels, inertia, shift = _lloyd.fused_lloyd_run(
-                        data, centers, self.n_clusters, chunk, interpret=interpret,
-                        xT=xT, xsq_sum=xsq,
-                    )
-                elif mode == "sharded":
-                    if xsq is None:
-                        xsq = _lloyd._sharded_xsq(data, n_global=n_global)
-                    centers, labels, inertia, shift = _lloyd.fused_lloyd_run_sharded(
-                        data, centers, self.n_clusters, x.comm, n_global, chunk,
-                        interpret=interpret, xsq_sum=xsq,
-                    )
-                else:
-                    centers, labels, inertia, shift = _lloyd_run(
-                        data, centers, self.n_clusters, chunk
-                    )
-                # the host read is INSIDE the try: on async backends a kernel
-                # that lowered fine can still fail at execution, surfacing
-                # only at this scalar fetch
-                shift_val = float(shift)
-            except Exception as exc:
-                if mode is None:
-                    raise
-                # the pallas kernel failed to lower/run on this backend
-                # (Mosaic support varies): fall back to the jnp oracle path
-                # rather than failing the fit — loudly, never silently
-                import warnings
-
-                warnings.warn(
-                    "KMeans fused Lloyd kernel failed on this backend "
-                    f"({repr(exc)[:160]}); falling back to the jnp path",
-                    stacklevel=2,
+            if mode == "single":
+                if xT is None:
+                    xT, xsq = _lloyd._prepare_run_operands(data, self.n_clusters)
+                centers, labels, inertia, shift = _lloyd.fused_lloyd_run(
+                    data, centers, self.n_clusters, chunk, interpret=interpret,
+                    xT=xT, xsq_sum=xsq,
                 )
-                mode = None
-                data = x.larray.astype(fdtype)
-                continue
+            elif mode == "sharded":
+                if xsq is None:
+                    xsq = _lloyd._sharded_xsq(data, n_global=n_global)
+                centers, labels, inertia, shift = _lloyd.fused_lloyd_run_sharded(
+                    data, centers, self.n_clusters, x.comm, n_global, chunk,
+                    interpret=interpret, xsq_sum=xsq,
+                )
+            else:
+                centers, labels, inertia, shift = _lloyd_run(
+                    data, centers, self.n_clusters, chunk
+                )
             done += chunk
-            if shift_val <= self.tol:
+            if float(shift) <= self.tol:
                 break
 
         self._n_iter = done

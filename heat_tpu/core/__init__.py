@@ -4,7 +4,6 @@ Mirrors the reference's flat re-export layout (heat/core/__init__.py:5-32):
 everything is importable as ``heat_tpu.<name>``.
 """
 
-from . import _compat  # install jax compatibility shims FIRST (jax.shard_map)
 from .communication import *
 from . import communication
 from .devices import *

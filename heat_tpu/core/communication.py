@@ -516,17 +516,6 @@ class MeshCommunication(Communication):
         return f"MeshCommunication({self.size} {plat} device(s), axis={self.axis_name!r})"
 
 
-def _distributed_client_live() -> bool:
-    """Whether ``jax.distributed`` is already connected, probed from runtime
-    state rather than by parsing exception wording (which changes across JAX
-    versions). Conservative: any probe failure reads as "not connected"."""
-    try:
-        state = jax._src.distributed.global_state
-        return getattr(state, "client", None) is not None
-    except (AttributeError, ImportError):
-        return False  # private-module layout changed: read as "not connected"
-
-
 def _refresh_world_state() -> None:
     """Invalidate every mesh-keyed cache after the world changed.
 
@@ -591,7 +580,7 @@ def initialize(
     are swallowed. Returns the refreshed default comm (and installs it via
     :func:`use_comm`).
     """
-    if _distributed_client_live():
+    if jax.distributed.is_initialized():
         # state probe, not message parsing: the runtime is already connected,
         # so re-initialization is a no-op regardless of how a second
         # ``jax.distributed.initialize`` would word its complaint
@@ -616,7 +605,7 @@ def initialize(
             int(os.environ.get("WORLD_SIZE", "1") or 1),  # torchrun et al.
         )
         single = (num_processes is None or num_processes == 1) and hinted_world == 1
-        if _distributed_client_live() or (
+        if jax.distributed.is_initialized() or (
             ("already" in msg or "once" in msg) and "in use" not in msg
         ):
             pass  # connected earlier: keep the live service (idempotent)

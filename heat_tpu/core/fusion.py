@@ -992,7 +992,7 @@ def _force_locked(node):
     leaves = []
     memo = {}
     _walk(node, entries, leaves, memo)
-    if _COLLECTIVES and _ENABLED and _LIVE_ROOTS and jax.core.trace_state_clean():
+    if _COLLECTIVES and _ENABLED and _LIVE_ROOTS and jax.core.trace_ctx.is_top_level():
         # never batch while executing into an enclosing jit/eval_shape
         # trace: the extra roots would come back as tracers (uncacheable —
         # see below), tracing their subgraphs and baking their operands

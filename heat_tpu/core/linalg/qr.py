@@ -82,10 +82,9 @@ def qr(
     split != 1 — the panel path's split-1 R layout must not depend on
     conditioning) and falls back to TSQR on the same probe instead of
     raising — the all-matmul speed when conditioning allows, Householder
-    stability when it does not. ``"auto"`` became the default
-    once a real-TPU capture showed the margin at the benchmark shape:
-    CholeskyQR2 1.29 TFLOP/s vs TSQR 0.19 — 6.7x
-    (benchmarks/TPU_WINDOW_r04.json, cholqr2 stage, v5e 2M x 256 f32).
+    stability when it does not. ``"auto"`` is the default because
+    CholeskyQR2's tall work is all GEMMs (MXU), where Householder TSQR is
+    mostly vector work.
     """
     sanitation.sanitize_in(a)
     if a.ndim != 2:
@@ -494,9 +493,7 @@ def _cholqr2_body(x, calc_q: bool = True):
     against the identity — instead of an (m, n) triangular solve. XLA lowers
     a big ``triangular_solve`` on TPU to a blocked substitution sweep that
     runs at a fraction of matmul rate; inverting the SMALL factor and
-    substituting a GEMM keeps the m-dimensional work entirely on the MXU
-    (the r04 capture measured the solve formulation at ~1% of the chip's
-    matmul capability; see benchmarks/tpu_window.py stage_qr_marginal).
+    substituting a GEMM keeps the m-dimensional work entirely on the MXU.
     Numerically the inverse of the small triangular factor is applied to the
     same operand the solve would see, and CholeskyQR2's second pass restores
     first-pass orthogonality loss either way.
@@ -506,7 +503,7 @@ def _cholqr2_body(x, calc_q: bool = True):
     The second-pass Gram is exactly the first pass's orthogonality error, so
     this rejects not just NaN breakdown (rank deficiency) but the gradual
     degradation where a near-``1/√ε``-conditioned operand keeps the Cholesky
-    finite while Q drifts from orthonormal (advisor finding r04#3): CholQR2
+    finite while Q drifts from orthonormal: CholQR2
     theory restores full orthogonality only while ``‖Q1ᴴQ1 − I‖ < 1``.
     Hermitian Gram (``xᴴx``) so complex operands factor correctly. With
     ``calc_q=False`` the second (largest) formation matmul is skipped — R
@@ -522,18 +519,23 @@ def _cholqr2_body(x, calc_q: bool = True):
     tighter than f32's (cond ≲ a few tens), which is the correct contract
     for a squared-condition algorithm on half-precision data. The public
     ``qr()`` never routes half dtypes here (it promotes to f32); this path
-    serves callers that explicitly want the half-width stream
-    (benchmarks/tpu_window.py stage_qr_marginal's bf16 variant)."""
-    acc_t = (
-        jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype
-    )
+    serves callers that explicitly want the half-width stream.
+
+    Full-width operands contract at ``Precision.HIGHEST``: the MXU's default
+    f32 matmul rounds its operands to bf16 (~4e-3 relative), which caps Q's
+    orthogonality at that level whatever the algorithm does — measured on a
+    v5e at 2M x 256 f32: ``‖QᵀQ − I‖_max`` 3.3e-3 at the default — and
+    shrinks the safe conditioning range from ``1/√ε_f32`` to a few tens."""
+    half = x.dtype in (jnp.bfloat16, jnp.float16)
+    acc_t = jnp.float32 if half else x.dtype
+    prec = None if half else jax.lax.Precision.HIGHEST
     eye = jnp.eye(x.shape[1], dtype=acc_t)
 
     def gram_chol(x):
         # (n, n) — contracts the (sharded) row axis; psum under GSPMD
         g = jax.lax.dot_general(
             jnp.conjugate(x), x, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_t,
+            precision=prec, preferred_element_type=acc_t,
         )
         return jnp.conjugate(jnp.linalg.cholesky(g)).mT, g  # upper factor
 
@@ -543,7 +545,7 @@ def _cholqr2_body(x, calc_q: bool = True):
     def form_q(x, r_inv):  # big GEMM; operands in the streamed dtype
         return jax.lax.dot_general(
             x, r_inv.astype(x.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_t,
+            precision=prec, preferred_element_type=acc_t,
         ).astype(x.dtype)
 
     r1, _ = gram_chol(x)
@@ -551,7 +553,7 @@ def _cholqr2_body(x, calc_q: bool = True):
     r2, g2 = gram_chol(q1)  # re-orthonormalization pass
     ok = _cholqr2_probe_ok(r1, r2, g2, eye)
     q2 = form_q(q1, inv_upper(r2)) if calc_q else None
-    return q2, r2 @ r1, ok
+    return q2, jnp.matmul(r2, r1, precision=prec), ok
 
 
 _cholqr2_kernel = functools.partial(jax.jit, static_argnames=("calc_q",))(_cholqr2_body)

@@ -276,17 +276,6 @@ def _write_atomic(path: str, payload: str) -> None:
 # ----------------------------------------------------------------------
 # the per-process facts
 # ----------------------------------------------------------------------
-def _distributed_client_live() -> bool:
-    """Whether ``jax.distributed`` is connected, probed from runtime state
-    (same probe as ``communication._distributed_client_live``, duplicated
-    here because communication imports would be cyclic at this layer)."""
-    try:
-        state = jax._src.distributed.global_state
-        return getattr(state, "client", None) is not None
-    except (AttributeError, ImportError):
-        return False  # private-module layout changed: read as "not connected"
-
-
 def process_index() -> int:
     """This controller process's id; 0 when the backend has no notion of
     processes (single host, or an unstarted distributed runtime).
@@ -299,7 +288,7 @@ def process_index() -> int:
     try:
         return int(jax.process_index())
     except RuntimeError:
-        if _distributed_client_live():  # pragma: no cover - needs a live cluster
+        if jax.distributed.is_initialized():  # pragma: no cover - needs a live cluster
             raise
         return 0
 
@@ -312,7 +301,7 @@ def process_count() -> int:
     try:
         return int(jax.process_count())
     except RuntimeError:
-        if _distributed_client_live():  # pragma: no cover - needs a live cluster
+        if jax.distributed.is_initialized():  # pragma: no cover - needs a live cluster
             raise
         return 1
 
@@ -980,8 +969,13 @@ def spawn_local(
         base_env["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={devices_per_process}"
         ).strip()
-        base_env.setdefault("JAX_PLATFORMS", "cpu")
         overrides = env or {}
+        if "JAX_PLATFORMS" not in overrides:
+            # spawn_local worlds are gloo CPU worlds by construction. An
+            # inherited JAX_PLATFORMS (e.g. "tpu" on a chip host) must not
+            # leak in: the parent may hold the chip, and a child that
+            # reaches for it fails or hangs
+            base_env["JAX_PLATFORMS"] = "cpu"
         if "HEAT_TPU_BARRIER_TIMEOUT_MS" not in overrides:
             base_env["HEAT_TPU_BARRIER_TIMEOUT_MS"] = f"{barrier_timeout_ms:g}"
         if "HEAT_TPU_HEARTBEAT_MS" not in overrides:

@@ -19,9 +19,12 @@ sampling machinery is thread-local, and the global rollup stays intact
 underneath.
 
 **Persistent program cache** — ``HEAT_TPU_PROGRAM_CACHE_DIR`` (or
-:func:`arm_cache`) wires jax's compilation cache to ``<dir>/xla`` and keeps
-an append-only index of fusion's DAG-signature program keys in
-``<dir>/programs.jsonl``. A fresh process that forces a previously-seen
+:func:`arm_cache`) keeps an append-only index of fusion's DAG-signature
+program keys in ``<dir>/programs.jsonl`` and wires jax's compilation cache to
+``<dir>/xla`` — unless ``JAX_COMPILATION_CACHE_DIR`` is set, in which case
+the XLA cache stays where the environment put it and no code path repoints
+it (:func:`use_entry_point_compile_cache` is the entry points' form of the
+same rule). A fresh process that forces a previously-seen
 signature records a ``disk_hit`` instead of a ``compile`` (the compiled
 binary comes off disk), so a warm-started service reaches steady state with
 zero recompiles; :func:`warmup` pre-bakes representative chains ahead of
@@ -68,6 +71,7 @@ __all__ = [
     "Session",
     "arm_cache",
     "cache_stats",
+    "use_entry_point_compile_cache",
     "sessions_block",
     "session_reports",
     "set_admission",
@@ -798,37 +802,65 @@ class Session:
 # ----------------------------------------------------------------------
 # the persistent cache: arming + warmup
 # ----------------------------------------------------------------------
+#: the entry points' XLA compile cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: ONE fixed directory inside the checkout, derived from the package's
+#: location (the path is part of the cache key — a directory that moves with
+#: the cwd, a pid or the clock never hits). Git-ignored.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def _cache_small_programs() -> None:
+    """Tiny programs must cache too: drop jax's default minimum-compile-time
+    and minimum-entry-size thresholds."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def use_entry_point_compile_cache() -> str:
+    """Turn XLA's persistent compile cache on for an entry point
+    (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``,
+    ``scripts/multiproc_trainer.py``) and return the directory in use.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already keeps its cache
+    there and nothing here sets another; otherwise the cache lives at
+    :data:`DEFAULT_COMPILE_CACHE_DIR`. Importing ``heat_tpu`` never calls
+    this — a library import (and the test suite) writes no cache."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    _cache_small_programs()
+    return path
+
+
 def arm_cache(path: str) -> Dict[str, Any]:
     """Arm the persistent program cache at ``path`` (the programmatic form
-    of ``HEAT_TPU_PROGRAM_CACHE_DIR``): wire jax's compilation cache to
-    ``<path>/xla`` (best-effort — accounting works even where the backend
-    does not persist binaries) and load the program-key index from
-    ``<path>/programs.jsonl``. Returns ``{"dir", "index_keys", "skipped"}``."""
+    of ``HEAT_TPU_PROGRAM_CACHE_DIR``): load the program-key index from
+    ``<path>/programs.jsonl`` and make sure jax's compilation cache holds
+    the binaries the index promises. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set the XLA cache stays THERE (only the index lives under ``path``);
+    otherwise it is wired to ``<path>/xla``. Returns ``{"dir",
+    "index_keys", "skipped"}``."""
     global _CACHE_DIR, _INDEX, _XLA_CACHE_WIRED, _XLA_PREV_CONFIG
     os.makedirs(path, exist_ok=True)
     if not _XLA_CACHE_WIRED:
-        try:
-            import jax
+        import jax
 
-            _XLA_PREV_CONFIG = (
-                jax.config.jax_compilation_cache_dir,
-                jax.config.jax_persistent_cache_min_compile_time_secs,
-                jax.config.jax_persistent_cache_min_entry_size_bytes,
-            )
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(path, "xla"))
-            # tiny serving programs must cache too: drop the default
-            # minimum-compile-time and minimum-entry-size thresholds
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            _XLA_CACHE_WIRED = True
-        except Exception as exc:  # pragma: no cover - backend-dependent
-            warnings.warn(
-                f"could not wire jax's compilation cache ({exc!r}); the "
-                "program-key index still arms (disk hits are counted, the "
-                "backend just recompiles)",
-                stacklevel=2,
-            )
+        _XLA_PREV_CONFIG = (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            jax.config.jax_persistent_cache_min_entry_size_bytes,
+        )
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", os.path.join(path, "xla"))
+        _cache_small_programs()
+        _XLA_CACHE_WIRED = True
     _CACHE_DIR = path
     _INDEX = _DiskIndex(os.path.join(path, "programs.jsonl"))
     _INDEX.load()
@@ -845,18 +877,16 @@ def disarm_cache() -> None:
     _INDEX = None
     fusion._DISK_INDEX = None
     if _XLA_CACHE_WIRED and _XLA_PREV_CONFIG is not None:
-        try:
-            import jax
+        import jax
 
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", _XLA_PREV_CONFIG[0])
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", _XLA_PREV_CONFIG[1]
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", _XLA_PREV_CONFIG[2]
-            )
-        except Exception:  # pragma: no cover - backend-dependent
-            pass
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", _XLA_PREV_CONFIG[1]
+        )
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", _XLA_PREV_CONFIG[2]
+        )
         _XLA_CACHE_WIRED = False
         _XLA_PREV_CONFIG = None
 

@@ -787,12 +787,9 @@ def _in_trace() -> bool:
     """Whether a jax trace is active right now (verbose events only: a
     collective recorded from inside a ``shard_map`` kernel is stamped at
     TRACE time, not execution time — the timeline marks it so)."""
-    try:
-        import jax
+    import jax
 
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax-version safety
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 #: sleep per recorded collective when the ``trace.hostdelay`` fault site is

@@ -173,19 +173,16 @@ def flash_attention(
       differentiable, XLA schedules the tiles.
     * ``'pallas'`` — the hand-tiled TPU kernel (:mod:`heat_tpu.ops.flash`);
       owns the (q, k) tile grid, skips above-diagonal tiles when causal.
-      Its win over dense is memory class (O(seq) vs O(seq²)); on speed the
-      r04 real-v5e capture measured it at 0.44 TFLOP/s marginal vs dense's
-      0.69 at 4k causal f32 with its then-default (128, 128) tiles — a
-      0.65x REGRESSION (git-banked attention stage, r04 window; recovered
-      per VERDICT r04). Differentiable via a custom VJP whose backward
-      re-runs the scan path (same O(seq) memory).
+      Its win over dense is memory class (O(seq) vs O(seq²)); its speed
+      against dense and scan is not measured on current code.
+      Differentiable via a custom VJP whose backward re-runs the scan path
+      (same O(seq) memory).
       ``block_size`` does not apply — the kernel picks its own 128-aligned
       tiles (pass ``block_q``/``block_k`` to
       :func:`heat_tpu.ops.flash.flash_attention_tpu` directly to tune them).
     * ``'auto'`` — ``'scan'``, everywhere. The pallas kernel is opt-in until
-      a banked real-TPU capture shows it beating the scan path at the
-      r05 defaults (the measured-fastest path owns the default; see
-      benchmarks/tpu_window.py stage_attention / stage_attention_sweep).
+      a chip measurement shows it beating the scan path (the
+      measured-fastest path owns the default).
     """
     if impl not in ("auto", "scan", "pallas"):
         raise ValueError(f"unknown flash impl {impl!r}")

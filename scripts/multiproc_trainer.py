@@ -96,9 +96,10 @@ def main() -> int:
     ap.add_argument("--hang-at-step", type=int, default=-1)
     args = ap.parse_args()
 
-    from heat_tpu.core import elastic, multihost, resilience
+    from heat_tpu.core import elastic, multihost, resilience, serving
     from heat_tpu.utils.checkpoint import load_checkpoint, save_checkpoint
 
+    serving.use_entry_point_compile_cache()
     multihost.initialize_distributed()
     rank = multihost.process_index()
     world = multihost.process_count()

@@ -393,7 +393,15 @@ PY
 # no measurement) must accept a banked round artifact against itself —
 # exercises record loading, envelope unwrap and threshold plumbing
 echo "=== bench sentinel smoke (--against/--record) ==="
-python bench.py --against BENCH_r05.json --record BENCH_r05.json
+SENTINEL_REC="$(mktemp)"
+cat > "$SENTINEL_REC" <<'JSON'
+{"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
+ "parsed": {"metric": "kmeans_iters_per_sec_10Mx16_k8", "value": 10.0,
+            "platform": "tpu", "lloyd_tflops": 0.8, "flight_overhead_pct": 0.5,
+            "lint_findings": 0}}
+JSON
+python bench.py --against "$SENTINEL_REC" --record "$SENTINEL_REC"
+rm -f "$SENTINEL_REC"
 # static-analysis leg (heat_tpu/analysis): the AST lint must be clean
 # against the committed baseline (zero NEW findings — suppressions carry
 # their justifications inline), the AOT program auditor over a cache

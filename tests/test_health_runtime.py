@@ -507,9 +507,14 @@ class TestBenchSentinel(unittest.TestCase):
         self.assertTrue(verdict["notes"])
 
     def test_load_record_unwraps_round_artifact(self):
-        rec = self.bench._load_record(os.path.join(_REPO, "BENCH_r05.json"))
-        self.assertIn("value", rec)
-        self.assertNotIn("parsed", rec)
+        envelope = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
+                    "parsed": dict(self.base)}
+        with tempfile.TemporaryDirectory() as tmp:
+            path = os.path.join(tmp, "BENCH_envelope.json")
+            with open(path, "w") as fh:
+                json.dump(envelope, fh)
+            rec = self.bench._load_record(path)
+        self.assertEqual(rec, self.base)
 
 
 if __name__ == "__main__":

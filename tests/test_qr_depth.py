@@ -217,9 +217,9 @@ class TestCholQR2(TestCase):
             ht.linalg.qr(ht.array(a_np, split=0), method="cholqr2")
 
     def test_auto_is_the_default_method(self):
-        # the default flipped to "auto" on the measured 6.7x v5e margin
-        # (benchmarks/TPU_WINDOW_r04.json cholqr2 stage): a bare qr() on a
-        # well-conditioned tall-skinny operand must take the cholqr2 path
+        # "auto" is the default (CholeskyQR2's tall work is all GEMMs): a
+        # bare qr() on a well-conditioned tall-skinny operand must take the
+        # cholqr2 path
         rng = np.random.default_rng(23)
         a_np = rng.standard_normal((64, 4)).astype(np.float32)
         q, r = ht.linalg.qr(ht.array(a_np, split=0))

@@ -366,6 +366,69 @@ class TestPersistentCache(ServingCase):
             self.assertGreaterEqual(warm["index_keys"], cold["compiles"])
 
 
+class TestCompileCachePlacement(unittest.TestCase):
+    """Where XLA's persistent compile cache lives: wherever
+    ``JAX_COMPILATION_CACHE_DIR`` says, untouched by every code path; else,
+    for entry points only, one fixed git-ignored path inside the checkout."""
+
+    _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def _run(self, script, cwd, **env_overrides):
+        env = {
+            k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR", "HEAT_TPU_PROGRAM_CACHE_DIR")
+        }
+        env.update(env_overrides, JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = self._REPO + os.pathsep + env.get("PYTHONPATH", "")
+        proc = subprocess.run(
+            [sys.executable, "-c", script], env=env, cwd=cwd,
+            capture_output=True, text=True, timeout=240,
+        )
+        self.assertEqual(proc.returncode, 0, f"{proc.stdout}\n{proc.stderr}")
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    def test_env_dir_survives_every_arming_path(self):
+        script = (
+            "import json, sys, jax, heat_tpu\n"
+            "from heat_tpu.core import serving\n"
+            "seen = {'import': jax.config.jax_compilation_cache_dir}\n"
+            "serving.disarm_cache()\n"
+            "seen['disarm_import'] = jax.config.jax_compilation_cache_dir\n"
+            "serving.arm_cache(sys.argv[1])\n"
+            "seen['arm'] = jax.config.jax_compilation_cache_dir\n"
+            "serving.disarm_cache()\n"
+            "seen['disarm'] = jax.config.jax_compilation_cache_dir\n"
+            "seen['entry'] = serving.use_entry_point_compile_cache()\n"
+            "seen['entry_config'] = jax.config.jax_compilation_cache_dir\n"
+            "print(json.dumps(seen))\n"
+        )
+        with tempfile.TemporaryDirectory() as d:
+            xla, armed, other = (os.path.join(d, n) for n in ("xla-env", "armed", "other"))
+            seen = self._run(
+                script.replace("sys.argv[1]", repr(other)), d,
+                JAX_COMPILATION_CACHE_DIR=xla, HEAT_TPU_PROGRAM_CACHE_DIR=armed,
+            )
+            self.assertEqual(set(seen.values()), {xla}, seen)
+            # the program-key index may live where it likes
+            self.assertTrue(os.path.isdir(armed) and os.path.isdir(other))
+
+    def test_entry_point_default_is_one_fixed_path_in_the_checkout(self):
+        want = os.path.join(self._REPO, ".jax_cache")
+        self.assertEqual(serving.DEFAULT_COMPILE_CACHE_DIR, want)
+        script = (
+            "import json, jax, heat_tpu\n"
+            "from heat_tpu.core import serving\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "print(json.dumps([before, serving.use_entry_point_compile_cache(),"
+            " jax.config.jax_compilation_cache_dir]))\n"
+        )
+        with tempfile.TemporaryDirectory() as elsewhere:
+            for cwd in (elsewhere, self._REPO):
+                before, returned, configured = self._run(script, cwd)
+                self.assertIsNone(before, "importing heat_tpu must arm no cache")
+                self.assertEqual((returned, configured), (want, want))
+
+
 # ----------------------------------------------------------------------
 # admission control + gate composition
 # ----------------------------------------------------------------------

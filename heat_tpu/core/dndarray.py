@@ -36,6 +36,7 @@ Key semantic notes
 from __future__ import annotations
 
 import functools
+import operator
 import warnings
 from typing import Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -56,6 +57,7 @@ _T_LARRAY = telemetry.force_trigger("larray")
 _T_INDEXING = telemetry.force_trigger("indexing")
 _T_PYTREE = telemetry.force_trigger("pytree")
 _T_COLLECTIVE = telemetry.force_trigger("collective")
+_ITEM = operator.methodcaller("item")
 
 Scalar = Union[int, float, bool, complex]
 
@@ -225,32 +227,38 @@ class DNDarray:
                 # forced inside an enclosing trace: the value belongs to that
                 # trace — hand it over but never store it on the wrapper
                 return arr
-            split = self.__split
-            if split is not None and (arr.ndim == 0 or split >= arr.ndim):
-                split = None
-            if resilience._ERRSTATE is not None or resilience._TLS_ARMED:
-                # numeric error policy at the forcing seam, on the LOGICAL
-                # extent only: the padding suffix of a ragged split holds
-                # unspecified garbage (log(0) = -inf) and must not be
-                # checked. A raise leaves the wrapper unforced (the cached
-                # program makes re-forcing under "ignore" cheap).
-                check_val = arr
-                if split is not None and int(arr.shape[split]) != self.__gshape[split]:
-                    idx = [slice(None)] * arr.ndim
-                    idx[split] = slice(0, self.__gshape[split])
-                    check_val = arr[tuple(idx)]
-                # provenance: the fused program key stamped on the root at
-                # force time + the chain's correlation id — a nonfinite
-                # finding names its producer, not just the catch point
-                resilience.check_nonfinite(
-                    check_val, "force",
-                    program=getattr(lazy, "program", None), cid=lazy.cid,
-                )
-            arr = _ensure_split(arr, split, self.__comm)
-            self.__array = arr
-            # re-attribute the forced value: the async future ("fusion")
-            # has been claimed by this wrapper
-            memledger.tag(arr, "dndarray")
+            # heat.place, while tracing: errstate check, placement, re-tag
+            span = telemetry.Phases("heat.place", cid=lazy.cid) if telemetry.tracing() else None
+            try:
+                split = self.__split
+                if split is not None and (arr.ndim == 0 or split >= arr.ndim):
+                    split = None
+                if resilience._ERRSTATE is not None or resilience._TLS_ARMED:
+                    # numeric error policy at the forcing seam, on the LOGICAL
+                    # extent only: the padding suffix of a ragged split holds
+                    # unspecified garbage (log(0) = -inf) and must not be
+                    # checked. A raise leaves the wrapper unforced (the cached
+                    # program makes re-forcing under "ignore" cheap).
+                    check_val = arr
+                    if split is not None and int(arr.shape[split]) != self.__gshape[split]:
+                        idx = [slice(None)] * arr.ndim
+                        idx[split] = slice(0, self.__gshape[split])
+                        check_val = arr[tuple(idx)]
+                    # provenance: the fused program key stamped on the root at
+                    # force time + the chain's correlation id — a nonfinite
+                    # finding names its producer, not just the catch point
+                    resilience.check_nonfinite(
+                        check_val, "force",
+                        program=getattr(lazy, "program", None), cid=lazy.cid,
+                    )
+                arr = _ensure_split(arr, split, self.__comm)
+                self.__array = arr
+                # re-attribute the forced value: the async future ("fusion")
+                # has been claimed by this wrapper
+                memledger.tag(arr, "dndarray")
+            finally:
+                if span is not None:
+                    fusion.note_phase("place", span.close())
         return arr
 
     def _force_payload(self, scope) -> jax.Array:
@@ -278,6 +286,21 @@ class DNDarray:
             if isinstance(arr, fusion.LazyArray) and arr._value is None:
                 return telemetry.record_blocking_sync(kind, cid=arr.cid)
         return None
+
+    def _host_read(self, kind: str, fetch):
+        """``fetch(self.larray)``, the blocking device-to-host read of
+        ``item()``/``numpy()``. The payload is forced first, so that while
+        tracing the read is a ``heat.read`` span (and ``phase_read_ns``) of
+        its own, after and never around ``heat.force``."""
+        cid = getattr(self.__array, "cid", 0)  # the pending chain's, else 0
+        arr = self.larray
+        if not telemetry.tracing():
+            return fetch(arr)
+        span = telemetry.Phases("heat.read", cid=cid, kind=kind)
+        try:
+            return fetch(arr)
+        finally:
+            fusion.note_phase("read", span.close())
 
     @property
     def larray(self) -> jax.Array:
@@ -725,7 +748,7 @@ class DNDarray:
         with health_runtime.watch(
             "sync:numpy", cid=None if token is None else token.get("cid")
         ):
-            out = np.asarray(jax.device_get(self.larray))
+            out = np.asarray(self._host_read("numpy", jax.device_get))
         telemetry.end_blocking_sync(token)
         return out
 
@@ -741,7 +764,7 @@ class DNDarray:
         with health_runtime.watch(
             "sync:item", cid=None if token is None else token.get("cid")
         ):
-            out = self.larray.item()
+            out = self._host_read("item", _ITEM)
         telemetry.end_blocking_sync(token)
         return out
 

@@ -411,6 +411,7 @@ def record(fn, children, **kw) -> LazyArray:
     node = LazyArray(fn, tuple(children), kw_t, shape, dtype, depth, cid)
     if _SESSION_OF is not None:
         node.session = _SESSION_OF()
+    _STATS["records"] += 1
     return node
 
 
@@ -467,6 +468,7 @@ def record_multi(fn, children, **kw) -> Tuple[LazyArray, ...]:
         pick = LazyArray(_pick_op, (parent,), (("i", i),), shape, dtype, depth + 1, cid)
         pick.session = session
         picks.append(pick)
+    _STATS["records"] += 1 + len(picks)
     return tuple(picks)
 
 
@@ -510,7 +512,33 @@ _STATS = {
     "evictions": 0,
     "degraded": 0,
     "quarantine_hits": 0,
+    # DAG nodes recorded (record/record_multi), always counted. The add takes
+    # no lock: recorders run outside _FORCE_LOCK, and under CPython 3.12's
+    # GIL a dict-item += on an int is not interrupted
+    "records": 0,
 }
+# where a forced result's host time goes, in nanoseconds of perf_counter_ns
+# per phase; they grow only while telemetry.tracing() (telemetry on, or a
+# profiler session recording), beside the heat.force / heat.place / heat.read
+# spans of the same intervals. phase_forces counts the non-recursive forces
+# timed; places/reads are DNDarray's seams (note_phase)
+_FORCE_PHASES = ("admit", "walk", "lookup", "dispatch", "install")
+_STATS.update({f"phase_{name}_ns": 0 for name in _FORCE_PHASES})
+_STATS.update(
+    phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0
+)
+# place and read are timed outside _FORCE_LOCK, from any serving thread: their
+# adds take this lock, which only the traced path ever touches
+_PHASE_LOCK = threading.Lock()
+
+
+def note_phase(name: str, ns: int) -> None:
+    """Count one ``heat.place`` / ``heat.read`` interval of ``ns``
+    nanoseconds (``DNDarray``'s forcing seam and host boundary, while
+    ``telemetry.tracing()``)."""
+    with _PHASE_LOCK:
+        _STATS[f"phase_{name}s"] += 1
+        _STATS[f"phase_{name}_ns"] += ns
 
 # serving seams (core/serving.py installs these as module attributes — the
 # telemetry ``_MEM_HOOK`` set-attribute pattern; each costs one ``is None``
@@ -944,18 +972,41 @@ def force(node):
         return node
     if node._value is not None:
         return node._value
+    if not telemetry.tracing():
+        return _force(node, None)
+    # the phases of this forced result: spans on the profiler's clock and
+    # the phase_* counters (telemetry.Phases). A recursive force (the drain
+    # policy) is one childless span and counts nothing: its time belongs to
+    # the phase of the outer force that caused it
+    recursive = {"recursive": 1} if getattr(_FORCE_TLS, "held", 0) else {}
+    ph = telemetry.Phases(
+        "heat.force", split=not recursive, cid=node.cid,
+        trigger=telemetry.current_trigger(), **recursive,
+    )
+    try:
+        return _force(node, ph)
+    finally:
+        ph.close()
+
+
+def _force(node, ph):
+    """:func:`force` past its early returns; ``ph`` is the force's
+    ``telemetry.Phases`` while tracing, else None."""
+    held = getattr(_FORCE_TLS, "held", 0)
+    if ph is not None:
+        ph.phase("admit")  # the time work waited: window, admission, the lock
     # micro batch window (serving arms it): sleep with the GIL released so
     # concurrent clients can register their roots, then re-check — a
     # neighbour's batch may have materialized this node during the window.
     # Skipped on recursive forces (drain policy) which already hold the lock.
-    if _BATCH_WINDOW_S > 0.0 and not getattr(_FORCE_TLS, "held", 0):
+    if _BATCH_WINDOW_S > 0.0 and not held:
         time.sleep(_BATCH_WINDOW_S)
         if node._value is not None:
             return node._value
     # local capture: a concurrent last-session exit may uninstall the hook
     # between the None check and the call
     admit = _ADMIT_HOOK
-    if admit is not None and not getattr(_FORCE_TLS, "held", 0):
+    if admit is not None and not held:
         # serving admission gate (core/serving.py): per-session + global
         # token buckets, BEFORE the force lock so a tenant blocked on refill
         # (`wait` policy) never convoys other sessions' dispatches behind
@@ -977,16 +1028,26 @@ def force(node):
     # lock is reentrant for the drain policy's recursive forces). Re-check
     # after acquiring — another thread's batch may have materialized us.
     with _FORCE_LOCK:
-        _FORCE_TLS.held = getattr(_FORCE_TLS, "held", 0) + 1
+        _FORCE_TLS.held = held + 1
         try:
-            return _force_locked(node)
+            return _force_locked(node, ph)
         finally:
-            _FORCE_TLS.held -= 1
+            _FORCE_TLS.held = held
+            if ph is not None and not held:
+                # fold this force's phases in under the lock (admit was
+                # timed outside it and carried here: no update is lost)
+                ph.phase(None)
+                if "lookup" in ph.ns:  # past the walk: counted in "forces"
+                    _STATS["phase_forces"] += 1
+                    for name, ns in ph.ns.items():
+                        _STATS[f"phase_{name}_ns"] += ns
 
 
-def _force_locked(node):
+def _force_locked(node, ph):
     if node._value is not None:
         return node._value
+    if ph is not None:
+        ph.phase("walk")
     roots = [node]
     entries = []
     leaves = []
@@ -1001,6 +1062,8 @@ def _force_locked(node):
     entries.append(("R", tuple(memo[id(r)] for r in roots)))
     sig = tuple(entries)
     _STATS["forces"] += 1
+    if ph is not None:
+        ph.phase("lookup")
     info = None  # per-program accounting; stays None for eager replays
     disk_warm = False  # this force's miss was served by the persistent index
     if _QUARANTINE and sig in _QUARANTINE:
@@ -1015,6 +1078,8 @@ def _force_locked(node):
             telemetry.record_force(
                 telemetry.current_trigger(), node.depth, compiled=False, cid=node.cid
             )
+        if ph is not None:
+            ph.phase("dispatch")
         values = _build(sig)(*leaves)
     else:
         prog = _PROGRAMS.get(sig)
@@ -1084,6 +1149,9 @@ def _force_locked(node):
                 # some recursive path materialized this very chain while the
                 # gate held it: the dispatch is done, do not run it again
                 return node._value
+        if ph is not None:
+            ph.note(program=info["key"])
+            ph.phase("dispatch")
         try:
             if resilience._ARMED:
                 # jax.jit builds lazily, so the XLA compile happens inside the
@@ -1099,14 +1167,15 @@ def _force_locked(node):
                 # the health layer starts the dispatch→done clock (a fresh
                 # build's call duration is the compile-time sample)
                 cids = [r.cid for r in roots]
-                t_disp = time.perf_counter()
                 with health_runtime.watch(
                     "dispatch", program=info["key"], cid=node.cid, cids=cids
                 ):
                     values = prog(*leaves)
-                if telemetry._MODE:
+                if telemetry._MODE and ph is not None:
+                    # the one timing of the jitted call: the dispatch phase
+                    # (ph is None only if telemetry came on mid-force)
                     health_runtime.note_dispatch(
-                        info["key"], cids, missed, time.perf_counter() - t_disp
+                        info["key"], cids, missed, ph.phase("install") * 1e-9
                     )
             else:
                 values = prog(*leaves)
@@ -1126,6 +1195,8 @@ def _force_locked(node):
                 raise
             values = _degrade(sig, leaves, exc, missed)
             info = None  # the eager replay is not a program dispatch
+    if ph is not None:
+        ph.phase("install")
     # under an enclosing trace the jit bind joins that trace and the values
     # are tracers even though every leaf is concrete (verified on jax
     # 0.4.37); caching is gated on each value's actual concreteness, not
@@ -1222,10 +1293,7 @@ def clear_cache() -> None:
     _QUARANTINE.clear()
     with _ROOTS_LOCK:
         _LIVE_ROOTS.clear()
-    _STATS.update(
-        compiles=0, hits=0, disk_hits=0, forces=0, evictions=0, degraded=0,
-        quarantine_hits=0,
-    )
+    _STATS.update(dict.fromkeys(_STATS, 0))
 
 
 def clear_quarantine() -> None:

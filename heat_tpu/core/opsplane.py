@@ -165,6 +165,8 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_fusion_quarantine_hits_total", (_C, "Forces that skipped a quarantined compile.", [])),
         ("heat_tpu_fusion_cache_size", (_G, "Compiled programs currently cached.", [])),
         ("heat_tpu_fusion_quarantined", (_G, "Program keys currently quarantined.", [])),
+        ("heat_tpu_fusion_phase_forces_total", (_C, "Forced results whose phases were timed (telemetry on or a profiler session recording).", [])),
+        ("heat_tpu_fusion_phase_seconds_total", (_C, "Host time of timed forced results, by phase (admit/walk/lookup/dispatch/install/place/read).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -284,6 +286,12 @@ def _collect_fusion(out: List[Sample]) -> None:
         "quarantine_hits",
     ):
         out.append((f"heat_tpu_fusion_{field}_total", {}, float(stats[field])))
+    out.append(("heat_tpu_fusion_phase_forces_total", {}, float(stats["phase_forces"])))
+    for phase in (*fusion._FORCE_PHASES, "place", "read"):
+        out.append((
+            "heat_tpu_fusion_phase_seconds_total", {"phase": phase},
+            stats[f"phase_{phase}_ns"] * 1e-9,
+        ))
     out.append(("heat_tpu_fusion_cache_size", {}, float(stats["size"])))
     out.append(("heat_tpu_fusion_quarantined", {}, float(stats["quarantined"])))
 

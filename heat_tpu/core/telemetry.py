@@ -78,6 +78,17 @@ multi-tenant serving layer attaches to. Completed scopes are archived under
 closing inside an active span are attributed to it, and every span records
 its own wall time into the Timer registry under ``span:<path>``.
 
+The forcing path's phases (:class:`Phases`, switched by :func:`tracing`:
+telemetry on, or a ``jax.profiler`` session recording) are the one place the
+program times itself on the profiler's clock: ``heat.force`` with its
+``admit``/``walk``/``lookup``/``dispatch``/``install`` children,
+``heat.place`` and ``heat.read`` are ``TraceAnnotation``s on the host line
+of the session's ``.xplane.pb``, beside its device lines, and the same
+intervals feed ``fusion.cache_stats()``'s ``phase_*`` keys. The timeline's
+own timestamps (``perf_counter``) cannot be laid beside the profile's
+events: the xplane's clock starts with its session. :func:`span` is such an
+annotation too.
+
 :func:`report` returns the whole picture as one structured dict — including
 a ``memory`` block (``profiling.device_memory_stats``, live-buffer bytes,
 the owner-attributed ledger + high watermark and the admission-gate state
@@ -115,7 +126,10 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 __all__ = [
+    "Phases",
     "RetraceWarning",
     "TimelineDroppedWarning",
     "active",
@@ -172,6 +186,7 @@ __all__ = [
     "spans",
     "trace_collective_parity",
     "trace_events",
+    "tracing",
     "unfused_reasons",
     "validate_trace",
     "verbose",
@@ -311,6 +326,85 @@ def enabled(mode=1):
         yield
     finally:
         set_mode(prev)
+
+
+def tracing() -> bool:
+    """Whether the forcing path times its phases: telemetry is on, or a
+    profiler session is recording (``jax.profiler.start_trace`` however it
+    was started: ``utils/profiling.trace``, a TensorBoard capture, a
+    benchmark's traced run). The one switch of :class:`Phases`; no knob of
+    its own."""
+    return _MODE > 0 or _Annotation.is_enabled()
+
+
+def _annotate(name: str, **stats):
+    """An entered ``jax.profiler.TraceAnnotation``, or None when no profiler
+    session is recording. The span lands on ``/host:CPU`` of the session's
+    ``.xplane.pb``, on the profiler's own clock: it starts with the session,
+    so no timestamp taken here could be laid beside the profile's events.
+    How closely the profiler aligns its device lines with its host lines is
+    the profiler's affair (PERF.md, section 7)."""
+    if not _Annotation.is_enabled():
+        return None
+    ann = _Annotation(name, **stats)
+    ann.__enter__()
+    return ann
+
+
+class Phases:
+    """One traced region of the forcing path, as the profiler and the
+    counters see it: a parent span ``name`` whose children (``name.<phase>``)
+    lie side by side, each closing where the next opens, and the same
+    boundaries read once each from ``time.perf_counter_ns`` into :attr:`ns`
+    (phase -> nanoseconds). Callers create one only while :func:`tracing`.
+    ``split=False`` keeps the parent and the clock reads and opens no child
+    span (a recursive force: its time belongs to the phase that caused it)."""
+
+    __slots__ = ("name", "ns", "_split", "_span", "_child", "_cur", "_t0", "_t")
+
+    def __init__(self, name: str, split: bool = True, **stats):
+        self.name = name
+        self.ns: Dict[str, int] = {}
+        # every clock read lies outside the span it bounds (before it opens,
+        # after it closes): a counted interval encloses its span
+        self._t0 = self._t = time.perf_counter_ns()
+        self._span = _annotate(name, **stats)
+        self._split = split and self._span is not None
+        self._child = self._cur = None
+
+    def phase(self, name: str) -> int:
+        """Close the running phase and open ``name`` (nothing to do when it
+        is the running one); returns the nanoseconds the closed one took."""
+        if name is not None and name == self._cur:
+            return 0
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+            self._child = None
+        now = time.perf_counter_ns()
+        took = 0
+        if self._cur is not None:
+            took = now - self._t
+            self.ns[self._cur] = self.ns.get(self._cur, 0) + took
+        self._cur, self._t = name, now
+        if name is not None and self._split:
+            self._child = _annotate(self.name + "." + name)
+        return took
+
+    def note(self, **stats) -> None:
+        """Add keyword stats to the parent span (what was not known when it
+        opened: the program key)."""
+        if self._span is not None:
+            self._span.set_metadata(**stats)
+
+    def close(self) -> int:
+        """Close the running phase and the parent; returns the nanoseconds
+        from open to close."""
+        self.phase(None)
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+            self._t = time.perf_counter_ns()
+        return self._t - self._t0
 
 
 # ----------------------------------------------------------------------
@@ -1340,10 +1434,14 @@ def span(name: str):
     within them, and mirror their own wall time into the Timer registry as
     ``span:<path>`` so the two report surfaces stay joined. In verbose mode
     each span emits ``span_begin``/``span_end`` timeline events — the B/E
-    duration pair of the exported trace. Yields the full span path (or None
-    when telemetry is off)."""
+    duration pair of the exported trace. While a profiler session records,
+    the span is also a ``TraceAnnotation`` of the same path, so it shows on
+    the device trace (with telemetry off: of the bare ``name``, since no
+    path is kept). Yields the full span path (or None when telemetry is
+    off)."""
     if not _MODE:
-        yield None
+        with _Annotation(name):
+            yield None
         return
     spans = _span_stack()
     path = (spans[-1].path + "/" + name) if spans else name
@@ -1352,7 +1450,8 @@ def span(name: str):
     if _MODE >= 2:
         _emit("span_begin", name=path)
     try:
-        yield path
+        with _Annotation(path):
+            yield path
     finally:
         spans.pop()
         elapsed = time.perf_counter() - frame.t0

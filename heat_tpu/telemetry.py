@@ -10,6 +10,7 @@ exported Chrome/Perfetto trace files without writing any analysis code:
     $ python -m heat_tpu.telemetry validate-trace trace.json
     $ python -m heat_tpu.telemetry analyze trace.json           # tracelens verdict
     $ python -m heat_tpu.telemetry analyze new.json --against old.json --json
+    $ python -m heat_tpu.telemetry gaps /tmp/profile_dir       # device idle by heat.* span
     $ python -m heat_tpu.telemetry memory                 # live process ledger
     $ python -m heat_tpu.telemetry memory report.json --json
     $ python -m heat_tpu.telemetry health                 # flight/watchdog/SLO
@@ -655,6 +656,116 @@ def _show_numerics(doc: Dict[str, Any], out) -> None:
         print(f"  {f.get('severity', '?').upper()}: {f.get('message')}", file=out)
 
 
+# ----------------------------------------------------------------------
+# gaps: the device's idle time by the program span that covered it, from a
+# profiler trace (.xplane.pb) alone
+# ----------------------------------------------------------------------
+def _innermost(spans) -> List[tuple]:
+    """Nested ``(start, end, name)`` spans as sorted disjoint pieces, each
+    named by the innermost span over it (of spans that overlap without
+    nesting, as two forcing threads' do: by the one opened last)."""
+    out, stack, cursor = [], [], 0.0  # stack of (end, name), outermost first
+    for s, e, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][0] <= s:
+            end, inner = stack.pop()
+            out.append((cursor, end, inner))
+            cursor = max(cursor, end)
+        if stack:
+            out.append((cursor, s, stack[-1][1]))
+        cursor = s
+        stack.append((e, name))
+    for end, inner in reversed(stack):
+        out.append((cursor, end, inner))
+        cursor = max(cursor, end)
+    return [p for p in out if p[1] > p[0]]
+
+
+def _overlap(pieces, gaps) -> Dict[str, float]:
+    """Per name, the length of the sorted disjoint ``pieces`` inside the
+    sorted disjoint ``gaps``."""
+    by: Dict[str, float] = {}
+    j = 0
+    for s, e, name in pieces:
+        while j < len(gaps) and gaps[j][1] <= s:
+            j += 1
+        k = j
+        while k < len(gaps) and gaps[k][0] < e:
+            by[name] = by.get(name, 0.0) + min(e, gaps[k][1]) - max(s, gaps[k][0])
+            k += 1
+    return by
+
+
+def _gaps_doc(path: str) -> Dict[str, Any]:
+    """Busy and idle seconds of the busiest device in a profiler trace, and
+    the idle time by the innermost ``heat.*`` span over it (``outside``:
+    under none), inside the window from the first such span's start to the
+    last one's end. A device is a ``/device:*`` plane's ``XLA Ops`` line; a
+    CPU backend's operations are the host events with an ``hlo_op``, by
+    ``device_ordinal``. The shares are exact; which span a gap falls under
+    is as good as the profiler's alignment of its device lines with its
+    host lines (0.3-1.6 ms apart on a v5e: PERF.md, section 7)."""
+    import glob
+    import os
+
+    import jax
+
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True))
+        if not found:
+            raise ValueError(f"no .xplane.pb under {path}")
+        path = found[-1]
+    devices: Dict[str, list] = {}
+    spans = []
+
+    def seconds(e, name):
+        return e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, name
+
+    planes = list(jax.profiler.ProfileData.from_file(path).planes)
+    for plane in planes:
+        for line in plane.lines:
+            if plane.name.startswith("/device:") and line.name == "XLA Ops":
+                devices[plane.name] = [seconds(e, "busy") for e in line.events]
+            elif plane.name == "/host:CPU":
+                spans += [seconds(e, e.name) for e in line.events if e.name.startswith("heat.")]
+    if not any(devices.values()):  # a CPU backend: no device plane
+        for e in (e for pl in planes if pl.name == "/host:CPU" for ln in pl.lines for e in ln.events):
+            stats = dict(e.stats)
+            if "hlo_op" in stats:
+                devices.setdefault(f"cpu:{stats.get('device_ordinal', 0)}", []).append(seconds(e, "busy"))
+    if not any(devices.values()):
+        raise ValueError(f"{path} holds no device operation")
+    every = spans or [op for ops in devices.values() for op in ops]
+    lo, hi = min(iv[0] for iv in every), max(iv[1] for iv in every)
+    busy = {  # per device, the union of its (nesting) operations inside the window
+        name: _innermost((max(s, lo), min(e, hi), b) for s, e, b in ops if e > lo and s < hi)
+        for name, ops in devices.items()
+    }
+    busy_s = {name: sum(e - s for s, e, _ in pieces) for name, pieces in busy.items()}
+    device = max(busy_s, key=busy_s.get)
+    edges = [lo] + [t for piece in busy[device] for t in piece[:2]] + [hi]
+    gaps = [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+    idle = (hi - lo) - busy_s[device]
+    by_span = _overlap(_innermost(spans), gaps)
+    by_span["outside"] = idle - sum(by_span.values())
+    return {
+        "source": path, "device": device, "devices": len(devices),
+        "window_s": hi - lo, "busy_s": busy_s[device], "idle_s": idle,
+        "idle_pct": 100.0 * idle / (hi - lo),
+        "idle_by_span_s": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
+    }
+
+
+def _show_gaps(doc: Dict[str, Any], out) -> None:
+    print(
+        f"gaps ({doc['source']}): {doc['device']} (busiest of {doc['devices']}), window "
+        f"{doc['window_s']:.6f} s, busy {doc['busy_s']:.6f} s, idle {doc['idle_s']:.6f} s "
+        f"({doc['idle_pct']:.2f} %); idle by innermost heat.* span:",
+        file=out,
+    )
+    for name, secs in doc["idle_by_span_s"].items():
+        print(f"  {name:<24} {secs:.6f} s  {100.0 * secs / (doc['idle_s'] or 1.0):5.1f} %", file=out)
+
+
 def main(argv: Optional[List[str]] = None, out=None) -> int:
     out = out if out is not None else sys.stdout
     parser = argparse.ArgumentParser(
@@ -753,6 +864,14 @@ def main(argv: Optional[List[str]] = None, out=None) -> int:
         help="analyze a window with dropped events anyway (attribution "
         "undercounts the evicted prefix; refused with exit 2 otherwise)",
     )
+    p_gaps = sub.add_parser(
+        "gaps",
+        help="a jax.profiler trace: busy and idle seconds of the busiest "
+        "device, and the idle time by the innermost heat.* span "
+        "(heat.force.<phase>, heat.place, heat.read) that covered it",
+    )
+    p_gaps.add_argument("trace", help="a profiler trace directory or an .xplane.pb file")
+    p_gaps.add_argument("--json", action="store_true", help="emit JSON instead of text")
     p_ops = sub.add_parser(
         "ops",
         help="live ops plane: scrape an endpoint, strict-check its "
@@ -816,6 +935,17 @@ def main(argv: Optional[List[str]] = None, out=None) -> int:
             print(json.dumps(_core._jsonable(doc), indent=2, sort_keys=True), file=out)
         else:
             _show_sessions(doc, out)
+        return 0
+    if args.cmd == "gaps":
+        try:
+            doc = _gaps_doc(args.trace)
+        except (ValueError, OSError) as exc:
+            print(f"ERROR: {exc}", file=out)
+            return 2
+        if args.json:
+            print(json.dumps(doc, indent=2), file=out)
+        else:
+            _show_gaps(doc, out)
         return 0
     if args.cmd == "ops":
         if args.action == "scrape":

@@ -60,7 +60,10 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
 
 def annotate(name: str):
     """Named trace region: ``with annotate("lloyd_step"): ...`` or as a
-    decorator. Regions nest and appear on the device timeline."""
+    decorator. Regions nest and appear on the device timeline. A bare alias
+    of ``jax.profiler.TraceAnnotation``, which counts nothing: the region
+    that is also counted and timed is ``heat_tpu.telemetry.span``, itself an
+    annotation of its path while a profiler session records."""
     return jax.profiler.TraceAnnotation(name)
 
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import factories, fusion, sanitation, types
+from . import factories, fusion, sanitation, telemetry, types
 from ._operations import __binary_op as _binary_op
 from ._operations import __local_op as _local_op
 from ._operations import __reduce_op as _reduce_op
@@ -594,15 +594,94 @@ def percentile(
 
 
 def std(x: DNDarray, axis=None, ddof: int = 0, **kwargs) -> DNDarray:
-    """Standard deviation (reference statistics.py:1936-1996). The sqrt goes
-    through the L3 local engine so var+sqrt stay one recorded chain."""
+    """Standard deviation (reference statistics.py:1936-1996): the root of :func:`var`.
+
+    ``var`` reads the operand once and never returns a negative number, so the
+    root is never NaN for finite data (see there for the algorithm and its
+    error bound). The sqrt goes through the L3 local engine so var+sqrt stay
+    one recorded chain, one program at the force."""
     v = var(x, axis, ddof=ddof, **kwargs)
     return _local_op(jnp.sqrt, v, no_cast=True)
 
 
+# a shift taken from 1/64 of each reduced line bounds the one-pass formula's
+# error amplification at 64 for any data (see _shifted_var)
+_SLAB_SHARE = 64
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "keepdims", "ddof"))
+def _shifted_var(src, axis=None, keepdims=False, ddof=0):
+    """One-pass variance of a real floating array: ``jnp.var``'s calling
+    convention, one read of ``src`` where ``jnp.var`` makes two.
+
+    ``jnp.var`` reduces twice in sequence (the mean, then the centred
+    squares), so an operand larger than the chip's fast memory streams from
+    HBM twice. Here a shift ``c`` is taken from a leading slab of each
+    reduced line (1/64 of the first reduced axis: the slab's mean, held
+    inside the slab's ``[min, max]``), and ONE pass computes the sibling sums
+    ``S1 = sum(x - c)`` and ``S2 = sum((x - c)^2)``, which XLA emits as one
+    multi-output reduce fusion. ``var = max((S2 - S1^2/n) / (n - ddof), 0)``.
+
+    Error: the shifted formula amplifies rounding over the two-pass one by
+    ``kappa^2 = 1 + (mu - c)^2 / sigma^2``. With ``c`` the mean of a slab
+    holding a share ``p`` of the line, the between-group variance of slab
+    against rest is part of ``sigma^2``, so ``(mu - c)^2 / sigma^2 <=
+    (1 - p) / p`` and ``kappa^2 <= 1/p <= 64`` for ANY data (met by a step:
+    one level in the slab, another after it); i.i.d. data gives ``1 + 1/m``
+    for a slab of ``m`` elements. The unshifted ``E[x^2] - E[x]^2`` has
+    ``kappa^2 = 1 + mu^2 / sigma^2``, unbounded. The clamp keeps a
+    rounding-negative difference from reaching ``std``'s root and lets NaN
+    through. A constant line gives exactly 0: its slab's min and max hold
+    ``c`` at the constant though a float mean of equal values may miss it.
+    Sums accumulate in at least float32 (as ``jnp.var``); the result has
+    ``src``'s dtype.
+    """
+    acc = jnp.promote_types(src.dtype, jnp.float32)
+    if axis is None:
+        axes = tuple(range(src.ndim))
+    else:
+        axes = tuple(a % src.ndim for a in ((axis,) if isinstance(axis, int) else axis))
+    n = float(_axis_count(src.shape, axes))
+    x = src.astype(acc)
+    if n and axes:
+        lead = axes[0]
+        rows = -(-src.shape[lead] // _SLAB_SHARE)
+        # the shift's value does not enter the derivative: var is invariant in c
+        slab = jax.lax.stop_gradient(jax.lax.slice_in_dim(src, 0, rows, axis=lead)).astype(acc)
+        # one variadic reduce: sibling jnp.sum/min/max split into two reads of
+        # the slab when the minor axis is reduced (XLA:TPU, libtpu 0.0.34)
+        total, lo, hi = jax.lax.reduce(
+            (slab, slab, slab),
+            (jnp.zeros((), acc), jnp.array(jnp.inf, acc), jnp.array(-jnp.inf, acc)),
+            lambda a, b: (a[0] + b[0], jax.lax.min(a[1], b[1]), jax.lax.max(a[2], b[2])),
+            axes,
+        )
+        # a rounded mean can leave [min, max]; inside it, constant lines shift to 0
+        c = jnp.clip(total / (n * rows / src.shape[lead]), lo, hi)
+        x = x - jnp.expand_dims(c, axes)
+    s1 = jnp.sum(x, axis=axes, keepdims=keepdims)
+    s2 = jnp.sum(x * x, axis=axes, keepdims=keepdims)
+    # 0/0 = NaN where the line is empty or n - ddof <= 0, as jnp.var
+    v = (s2 - s1 * (s1 / n)) / builtins.max(n - ddof, 0.0)
+    return jnp.maximum(v, 0).astype(src.dtype)
+
+
 def var(x: DNDarray, axis=None, ddof: int = 0, **kwargs) -> DNDarray:
-    """Variance (reference statistics.py:2046-2126; pairwise moment merging
-    __merge_moments :1043-1113 replaced by one sharded jnp.var)."""
+    """Variance (reference statistics.py:2046-2126) in one read of the operand.
+
+    The reference takes local moments once per rank and merges them pairwise
+    (``__merge_moments`` :1043-1113). Here every real floating input records
+    :func:`_shifted_var`, one-pass shifted-data moments whose two sums are
+    siblings of one reduce fusion (GSPMD adds their psum across the split
+    axis): ``var = max((S2 - S1^2/n) / (n - ddof), 0)`` with ``S1``, ``S2``
+    the sums of ``x - c`` and its square and ``c`` the mean of the leading
+    1/64 of each reduced line. The rounding error is at most ``kappa^2 = 1 +
+    (mu - c)^2/sigma^2 <= 64`` times the two-pass formula's for any data
+    (worst case a step between slab and rest; ``1 + 1/m`` for i.i.d. data
+    and a slab of ``m`` elements). The clamp at 0 keeps :func:`std` from the
+    root of a rounding-negative number, and a constant input gives exactly
+    0. Exact dtypes are promoted to float first; complex input keeps the
+    two-pass ``jnp.var``."""
     sanitation.sanitize_in(x)
     if not isinstance(ddof, int):
         raise TypeError(f"ddof must be integer, is {type(ddof)}")
@@ -613,7 +692,10 @@ def var(x: DNDarray, axis=None, ddof: int = 0, **kwargs) -> DNDarray:
     keepdims = bool(kwargs.get("keepdims", False))
     if types.heat_type_is_exact(x.dtype):
         x = x.astype(types.promote_types(x.dtype, types.float32))
-    return _reduce_op(jnp.var, x, axis, keepdims=keepdims, ddof=ddof)
+    onepass = not types.heat_type_is_complexfloating(x.dtype)
+    if telemetry._MODE:
+        telemetry.record_var_path("onepass" if onepass else "twopass")
+    return _reduce_op(_shifted_var if onepass else jnp.var, x, axis, keepdims=keepdims, ddof=ddof)
 
 
 def mpi_argmax(a, b):

@@ -174,6 +174,7 @@ __all__ = [
     "record_nonfinite",
     "record_retrace",
     "record_unfused",
+    "record_var_path",
     "report",
     "report_json",
     "reset",
@@ -189,6 +190,7 @@ __all__ = [
     "tracing",
     "unfused_reasons",
     "validate_trace",
+    "var_paths",
     "verbose",
 ]
 
@@ -422,7 +424,7 @@ class _State:
 
     __slots__ = (
         "path", "t0", "wall_s", "calls", "collectives", "forces", "retraces",
-        "compiles", "dispatches", "degraded", "unfused", "nonfinite",
+        "compiles", "dispatches", "degraded", "unfused", "var_paths", "nonfinite",
         "io_retries", "checkpoint", "fused_collectives", "async_", "blocking",
         "sync_wait", "faults", "spans", "events", "events_dropped",
     )
@@ -442,6 +444,7 @@ class _State:
         self.dispatches: Dict[str, Dict[str, int]] = {}
         self.degraded: Dict[str, Dict[str, Any]] = {}
         self.unfused: Dict[str, Dict[str, int]] = {}
+        self.var_paths: Dict[str, int] = {}
         self.nonfinite: Dict[str, int] = {}
         self.io_retries: Dict[str, int] = {}
         self.checkpoint: Dict[str, int] = {}
@@ -517,6 +520,7 @@ def _merge_state(dst: _State, src: _State) -> None:
         d["last_error"] = rec["last_error"] or d["last_error"]
     for eng, rec in src.unfused.items():
         _add_int(dst.unfused.setdefault(eng, {}), rec)
+    _add_int(dst.var_paths, src.var_paths)
     _add_int(dst.nonfinite, src.nonfinite)
     _add_int(dst.io_retries, src.io_retries)
     _add_int(dst.checkpoint, src.checkpoint)
@@ -1297,6 +1301,22 @@ def unfused_reasons() -> Dict[str, Dict[str, int]]:
     """Per-engine reasons ops fell back to the eager engine instead of
     deferring into the fusion DAG."""
     return {k: dict(v) for k, v in _cur().unfused.items()}
+
+
+def record_var_path(path: str) -> None:
+    """Count which algorithm ``ht.var`` recorded: ``onepass`` (shifted-data
+    moments, every real floating input) or ``twopass`` (``jnp.var``, complex
+    input). Read with :func:`var_paths`; not part of ``report()``, whose
+    key set streaming consumers pin."""
+    if not _MODE:
+        return
+    for st in _states():
+        st.var_paths[path] = st.var_paths.get(path, 0) + 1
+
+
+def var_paths() -> Dict[str, int]:
+    """Per-algorithm counts of ``ht.var`` calls (see :func:`record_var_path`)."""
+    return dict(_cur().var_paths)
 
 
 # ----------------------------------------------------------------------

@@ -16,8 +16,9 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from ..core import types
+from ..core import fusion, telemetry, types
 from ..core.dndarray import DNDarray, _ensure_split
+from ..ops import lloyd as _lloyd
 from ..spatial.distance import _sq_euclidian_fast as _sq_dist
 from ._kcluster import _KCluster
 
@@ -51,12 +52,18 @@ def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
 def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
     if xsq_sum is None:
         xsq_sum = jnp.sum(data * data)
+    # float32 (or wider) rows multiply in float32 on the MXU, as the fused
+    # kernel's do; the XLA default would round both operands to bfloat16
+    precision = _lloyd.mxu_precision(data.dtype)
     # score = d² − |x|² (row-constant offset): same argmin, cheaper to form
-    score = jnp.sum(centers * centers, axis=1) - 2.0 * (data @ centers.T)  # (n, k)
+    score = jnp.sum(centers * centers, axis=1) - 2.0 * jnp.matmul(
+        data, centers.T, precision=precision
+    )  # (n, k)
     labels = jnp.argmin(score, axis=1).astype(jnp.int32)
     onehot = jax.nn.one_hot(labels, k, dtype=data.dtype)  # (n, k)
     counts = jnp.sum(onehot, axis=0)  # (k,)
-    sums = onehot.T @ data  # (k, f) — MXU; psum over the sharded rows
+    # (k, f) — MXU; psum over the sharded rows
+    sums = jnp.matmul(onehot.T, data, precision=precision)
     new_centers = jnp.where(
         counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1.0), centers
     )
@@ -70,6 +77,11 @@ def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
 
 _lloyd_step = partial(jax.jit, static_argnames=("k",))(_lloyd_iter)
 """One Lloyd iteration (data (n, f) row-sharded, centers (k, f) replicated)."""
+
+
+def _no_phase(name: str) -> int:
+    """``telemetry.Phases.phase`` of a fit that is not traced."""
+    return 0
 
 
 class KMeans(_KCluster):
@@ -109,8 +121,6 @@ class KMeans(_KCluster):
     def _fused_mode(self, x: DNDarray):
         """Resolve the Lloyd dispatch: ('single'|'sharded', interpret) or
         (None, False) for the jnp path."""
-        from ..ops import lloyd as _lloyd
-
         n, f = int(x.shape[0]), int(x.shape[1])
         k = self.n_clusters
         if self.use_fused is False:
@@ -139,15 +149,45 @@ class KMeans(_KCluster):
         return None, False
 
     def fit(self, x: DNDarray) -> "KMeans":
-        """Cluster ``x`` (n_samples, n_features) (reference kmeans.py:102-139)."""
-        from ..ops import lloyd as _lloyd
+        """Cluster ``x`` (n_samples, n_features) (reference kmeans.py:102-139).
 
+        While ``telemetry.tracing()`` the fit is a ``heat.kmeans.fit`` span
+        (stats ``mode``, ``n``, ``f``, ``k``) whose children lie side by
+        side: ``.init`` (the initial centres), ``.prepare`` (dtype cast, the
+        samples-in-lanes transpose and Σ|x|² dispatch), ``.dispatch`` (each
+        Lloyd program's call), ``.sync`` (each blocking read of the shift,
+        and of the inertia), ``.wrap`` (centres and labels back into
+        ``DNDarray``s); the same intervals add to ``fusion.cache_stats()``'s
+        ``phase_kmeans_*`` keys."""
         if not isinstance(x, DNDarray):
             raise ValueError(f"input needs to be a DNDarray, but was {type(x)}")
         if x.ndim != 2:
             raise ValueError(f"input needs to be 2D, but was {x.ndim}D")
-        centers = self._initialize_cluster_centers(x)
         mode, interpret = self._fused_mode(x)
+        if not telemetry.tracing():
+            self._fit(x, mode, interpret, _no_phase)
+            return self
+        ph = telemetry.Phases(
+            "heat.kmeans.fit", mode=mode or "jnp",
+            n=int(x.shape[0]), f=int(x.shape[1]), k=self.n_clusters,
+        )
+        try:
+            dispatches, syncs = self._fit(x, mode, interpret, ph.phase)
+        finally:
+            ph.close()
+        fusion.note_kmeans_fit(ph.ns, dispatches, syncs)
+        return self
+
+    def _fit(self, x: DNDarray, mode, interpret: bool, mark):
+        """:meth:`fit` past its checks, on the dispatch ``_fused_mode``
+        resolved. ``mark(name)`` opens the fit's next phase
+        (``telemetry.Phases.phase``; nothing when the fit is not traced).
+        Returns the Lloyd programs dispatched and the blocking host reads
+        made."""
+        k, n_global = self.n_clusters, int(x.shape[0])
+        mark("init")
+        centers = self._initialize_cluster_centers(x)
+        mark("prepare")
         fdtype = jnp.promote_types(x.dtype.jax_type(), jnp.float32)
         # bfloat16 stays bfloat16 through the fused kernel (half the HBM
         # traffic of the f32 stream; accumulators are f32 inside) — the jnp
@@ -161,43 +201,48 @@ class KMeans(_KCluster):
         else:
             data = x.larray.astype(ddtype)
         centers = jnp.asarray(centers, fdtype)
+        # the loop-invariant operands (the samples-in-lanes transpose and
+        # Σ|x|²) are full-data passes: computed ONCE here, not per chunk
+        xT = xsq = None
+        if mode == "single":
+            xT, xsq = _lloyd._prepare_run_operands(data, k)
+        elif mode == "sharded":
+            xsq = _lloyd._sharded_xsq(data, n_global=n_global)
 
         # iterations run in fused chunks of up to 8 per dispatch; convergence
         # is checked at chunk boundaries (coarser than the reference's
-        # per-iteration check, identical fixed point). The loop-invariant
-        # operands (the samples-in-lanes transpose and Σ|x|²) are computed
-        # ONCE here, not per chunk — they are full-data passes.
+        # per-iteration check, identical fixed point)
         labels = None
         inertia = None
-        done = 0
-        n_global = int(x.shape[0])
-        xT = xsq = None
+        done = dispatches = syncs = 0
         while done < self.max_iter:
             chunk = min(8, self.max_iter - done)
+            mark("dispatch")
             if mode == "single":
-                if xT is None:
-                    xT, xsq = _lloyd._prepare_run_operands(data, self.n_clusters)
                 centers, labels, inertia, shift = _lloyd.fused_lloyd_run(
-                    data, centers, self.n_clusters, chunk, interpret=interpret,
-                    xT=xT, xsq_sum=xsq,
+                    data, centers, k, chunk, interpret=interpret, xT=xT, xsq_sum=xsq,
                 )
             elif mode == "sharded":
-                if xsq is None:
-                    xsq = _lloyd._sharded_xsq(data, n_global=n_global)
                 centers, labels, inertia, shift = _lloyd.fused_lloyd_run_sharded(
-                    data, centers, self.n_clusters, x.comm, n_global, chunk,
+                    data, centers, k, x.comm, n_global, chunk,
                     interpret=interpret, xsq_sum=xsq,
                 )
             else:
-                centers, labels, inertia, shift = _lloyd_run(
-                    data, centers, self.n_clusters, chunk
-                )
+                centers, labels, inertia, shift = _lloyd_run(data, centers, k, chunk)
+            dispatches += 1
             done += chunk
+            mark("sync")
+            syncs += 1
             if float(shift) <= self.tol:
                 break
 
         self._n_iter = done
-        self._inertia = float(inertia) if inertia is not None else None
+        if inertia is not None:
+            mark("sync")
+            syncs += 1
+            inertia = float(inertia)
+        self._inertia = inertia
+        mark("wrap")
         self._cluster_centers = DNDarray(
             _ensure_split(centers, None, x.comm),
             tuple(centers.shape),
@@ -207,4 +252,4 @@ class KMeans(_KCluster):
             x.comm,
         )
         self._labels = self._wrap_labels(labels, x)
-        return self
+        return dispatches, syncs

@@ -527,8 +527,14 @@ _STATS.update({f"phase_{name}_ns": 0 for name in _FORCE_PHASES})
 _STATS.update(
     phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0
 )
-# place and read are timed outside _FORCE_LOCK, from any serving thread: their
-# adds take this lock, which only the traced path ever touches
+# an estimator's fit is timed the same way, under the same switch
+# (cluster/kmeans.py: the heat.kmeans.fit span and its children): fits, the
+# programs they dispatched, their blocking host reads, nanoseconds per phase
+_KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
+_STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
+_STATS.update(phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0)
+# place, read and a fit are timed outside _FORCE_LOCK, from any serving thread:
+# their adds take this lock, which only the traced path ever touches
 _PHASE_LOCK = threading.Lock()
 
 
@@ -539,6 +545,18 @@ def note_phase(name: str, ns: int) -> None:
     with _PHASE_LOCK:
         _STATS[f"phase_{name}s"] += 1
         _STATS[f"phase_{name}_ns"] += ns
+
+
+def note_kmeans_fit(ns: dict, dispatches: int, syncs: int) -> None:
+    """Count one ``heat.kmeans.fit``: the nanoseconds of each phase it went
+    through (``telemetry.Phases.ns``), the Lloyd programs it dispatched and
+    the blocking host reads it made (while ``telemetry.tracing()``)."""
+    with _PHASE_LOCK:
+        _STATS["phase_kmeans_fits"] += 1
+        _STATS["phase_kmeans_dispatches"] += dispatches
+        _STATS["phase_kmeans_syncs"] += syncs
+        for name, took in ns.items():
+            _STATS[f"phase_kmeans_{name}_ns"] += took
 
 # serving seams (core/serving.py installs these as module attributes — the
 # telemetry ``_MEM_HOOK`` set-attribute pattern; each costs one ``is None``

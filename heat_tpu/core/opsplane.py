@@ -167,6 +167,10 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_fusion_quarantined", (_G, "Program keys currently quarantined.", [])),
         ("heat_tpu_fusion_phase_forces_total", (_C, "Forced results whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_fusion_phase_seconds_total", (_C, "Host time of timed forced results, by phase (admit/walk/lookup/dispatch/install/place/read).", ["phase"])),
+        ("heat_tpu_kmeans_fits_total", (_C, "KMeans fits whose phases were timed (telemetry on or a profiler session recording).", [])),
+        ("heat_tpu_kmeans_dispatches_total", (_C, "Lloyd programs dispatched by timed KMeans fits.", [])),
+        ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (shift, inertia) made by timed KMeans fits.", [])),
+        ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -291,6 +295,13 @@ def _collect_fusion(out: List[Sample]) -> None:
         out.append((
             "heat_tpu_fusion_phase_seconds_total", {"phase": phase},
             stats[f"phase_{phase}_ns"] * 1e-9,
+        ))
+    for count in ("fits", "dispatches", "syncs"):
+        out.append((f"heat_tpu_kmeans_{count}_total", {}, float(stats[f"phase_kmeans_{count}"])))
+    for phase in fusion._KMEANS_PHASES:
+        out.append((
+            "heat_tpu_kmeans_phase_seconds_total", {"phase": phase},
+            stats[f"phase_kmeans_{phase}_ns"] * 1e-9,
         ))
     out.append(("heat_tpu_fusion_cache_size", {}, float(stats["size"])))
     out.append(("heat_tpu_fusion_quarantined", {}, float(stats["quarantined"])))

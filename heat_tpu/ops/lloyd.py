@@ -3,7 +3,7 @@
 The jnp Lloyd step (`cluster/kmeans.py:_lloyd_iter`) necessarily reads the
 (n, f) data from HBM twice per iteration — once for the assignment matmul
 ``x @ cᵀ`` and once for the update matmul ``onehotᵀ @ x`` — and materializes
-the (n, k) one-hot operand for the MXU. At the benchmark shape (10M x 16
+the (n, k) one-hot operand for the MXU. At the benchmark shape (2^26 x 16
 f32) the iteration is pure HBM bandwidth, so the floor is set by bytes
 moved, not FLOPs.
 
@@ -28,15 +28,28 @@ and every reduction in the kernel is lane-preserving. The one-time
 iteration loop; per-iteration HBM traffic is n·f reads and NOTHING
 per-row written (labels are not an iteration output at all — a separate
 fused jnp epilogue computes the final assignment once per program, against
-the centers of the last iteration, which is the jnp oracle's exact label
+the centers of the last iteration, which is the jnp path's exact label
 convention).
 
+Precision follows the rows' dtype alone. The MXU multiplies bfloat16: left
+at the default, float32 operands are rounded to bfloat16 and multiplied in
+one pass, which put ``KMeans.fit``'s float32 centres 1.1-1.4 % off a plain
+float32 Lloyd (PERF.md, PR 26). float32 rows (and wider, carried as float32)
+therefore multiply in float32: both contractions take their float32 operand
+as three bfloat16 pieces that add up to it exactly (:func:`_bf16_pieces`),
+and because (k, f) fills a corner of a 128 x 128 MXU tile, the pieces are
+stacked along the contracted and the output axes of ONE bfloat16 pass, so
+every piece product is exact in the float32 accumulator, at the MXU cost of
+the single rounded pass. bfloat16 rows are one piece: they keep their
+bfloat16 multiplication with float32 accumulation and half the HBM stream.
+The label epilogue and the jnp path ask XLA for the same
+(:func:`mxu_precision`).
+
 This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here on
-TPU (``fused_supported`` / ``fused_sharded_supported``), keeping the jnp
-path as the fallback and numerical oracle; bench.py's primary kmeans metric
-measures whichever path the product dispatches (``lloyd_path`` in the
-record), with the other path alongside (``lloyd_jnp_iters_per_sec`` /
-``lloyd_fused_vs_jnp``). :func:`fused_lloyd_iter` is
+TPU (``fused_supported`` / ``fused_sharded_supported``) and takes the jnp
+path (``cluster/kmeans.py:_lloyd_run``) for wider shapes or
+``use_fused=False``; a kernel that fails to lower raises.
+:func:`fused_lloyd_iter` is
 single-device (its pallas_call has no partitioning spec);
 :func:`fused_lloyd_iter_sharded` / :func:`fused_lloyd_run_sharded` are the
 multi-chip forms: a shard_map running the kernel per device and merging the
@@ -66,17 +79,59 @@ __all__ = [
 ]
 
 
-def _block_cols(f: int, k: int) -> int:
-    """Samples (lanes) per grid step, sized against the scoped-VMEM budget
-    on a v5e (16 MB limit). Live vectors per lane: the double-buffered
-    (f, block) input plus the (k, block)-shaped score/onehot/min chain —
-    all sublane-padded to multiples of 8. Budget ≤ 12 MB leaves headroom
-    for the (f, k)/(k, 1) accumulators and c/csq. (An earlier (block, f)
-    kernel ignored lane padding and hit the 16 MB scoped limit to within
-    1.5 KB; this sizing is measured, not aspirational.)"""
-    fp = 8 * ((f + 7) // 8)
-    kp = 8 * ((k + 7) // 8)
-    per_lane = 4 * (2 * fp + 3 * kp + 8)
+def mxu_precision(dtype) -> Optional[jax.lax.Precision]:
+    """What a contraction on rows of ``dtype`` asks of XLA (the jnp Lloyd
+    path, the label epilogue): float32 and wider multiply in float32
+    (``HIGHEST``; the default rounds both operands to bfloat16 and multiplies
+    once), bfloat16 rows keep their one bfloat16 pass."""
+    return None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+
+def _bf16_pieces(x: jax.Array) -> tuple:
+    """``x`` as bfloat16 arrays that add up to it exactly: itself if it is
+    bfloat16, else a float32's significand cut into 8 + 8 + 8 bits. The
+    product of two pieces is exact in the MXU's float32 accumulator, so one
+    bfloat16 pass over all pairs of pieces is the float32 product.
+
+    The cuts are made on the bits (the low half of the word masked off), not
+    by converting to bfloat16 and back: XLA takes a float32 -> bfloat16 ->
+    float32 round trip for the identity (``xla_allow_excess_precision``), and
+    the remainder it was taken for would be zero (seen on a v5e: centres cut
+    this way outside the kernel scored as bfloat16)."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+
+    def head(v):  # the leading 8 bits of the significand: a bfloat16's worth, as float32
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    hi = head(x)
+    rest = x - hi  # exact: at most 16 bits are left
+    mid = head(rest)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
+
+
+def _pad8(n: int) -> int:
+    return 8 * ((n + 7) // 8)
+
+
+def _block_cols(f: int, k: int, itemsize: int = 4) -> int:
+    """Samples (lanes) per grid step, sized against the scoped-VMEM limit
+    of a v5e (16 MB) for rows of ``itemsize`` bytes. What stays live per
+    lane for a whole block is the double-buffered (f, block) input, the
+    stack of bfloat16 pieces (three per float32 row, one per bfloat16 row)
+    and the (k, block)-shaped dot/score/onehot chain, sublane-padded to
+    multiples of 8. The constants are measured, not aspirational: the
+    largest block Mosaic accepts under 16 MB was bisected over 13 (f, k)
+    from (4, 3) to (512, 128) in both dtypes (compiles for a described v5e)
+    and asks ``15 f + 12 k + 56`` bytes a lane for float32 rows and
+    ``6.1 f + 11.7 k + 65`` for bfloat16 (8 % more at f = 512); the 12 MB
+    budget leaves the rest for the accumulators and the centre operand. (An
+    earlier (block, f) kernel ignored lane padding and hit the limit to
+    within 1.5 KB.)"""
+    fp, kp = _pad8(f), _pad8(k)
+    pieces = 3 if itemsize >= 4 else 1
+    per_lane = (2 * itemsize + 2 * pieces + 1) * fp + 12 * kp + 64
     blk = (12 << 20) // per_lane
     return max(1024, min(65536, blk // 128 * 128))
 
@@ -103,11 +158,11 @@ def _lloyd_kernel(
     csq_ref,
     c_ref,
     nvalid_ref,
-    sumsT_ref,
+    sums_ref,
     counts_ref,
     inertia_ref,
     *,
-    k: int,
+    kp: int,
     block: int,
 ):
     """One (f, block) sample block; accumulators live across the whole grid.
@@ -115,6 +170,14 @@ def _lloyd_kernel(
     device's share of the global padding under the sharded wrapper) are
     masked out of every accumulator. n_valid is a runtime (1, 1) scalar
     operand so each device can carry its own count.
+
+    ``kp`` is k padded to a sublane multiple: the centre rows beyond k are
+    zero and carry ``csq = +inf``, so no sample is ever assigned to them.
+    With ``p`` the number of bfloat16 pieces of a row's dtype, ``c_ref`` is
+    (p·kp, p·f): row group ``i`` holds piece ``i`` of −2c against every
+    piece of x, so the groups of the one dot add up to −2 c·x with every
+    piece product exact; ``sums_ref`` is (kp, p·f), one column group per piece
+    of x against the one-hot matrix (exact in bfloat16), folded by the caller.
 
     Every intermediate is 2-D: Mosaic lays a 1-D (block,) value out with a
     replicated sublane and chaining argmin / where / reduce through that
@@ -131,26 +194,32 @@ def _lloyd_kernel(
     # contraction, so zero invalid samples rather than relying on
     # multiplicative masking downstream.
     xb = jnp.where(valid, xT_ref[:, :], 0)  # (f, block)
+    pieces = _bf16_pieces(xb)
+    stack = jnp.concatenate(pieces, axis=0)  # (p·f, block) bf16
 
-    # (k, block) assignment scores; |x|² omitted (sample-constant for argmin)
-    score = csq_ref[:, :] - 2.0 * jnp.dot(
-        c_ref[:, :], xb, preferred_element_type=jnp.float32
+    # (kp, block) assignment scores; |x|² omitted (sample-constant for argmin)
+    dots = jnp.dot(c_ref[:, :], stack, preferred_element_type=jnp.float32)
+    score = csq_ref[:, :] + sum(
+        dots[g * kp : (g + 1) * kp] for g in range(len(pieces))
     )
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
     labels = jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)  # (1, block)
-    onehot = (labels == kcol).astype(xb.dtype) * valid.astype(xb.dtype)  # (k, block)
+    onehot = jnp.logical_and(labels == kcol, valid).astype(jnp.bfloat16)  # (kp, block)
 
     @pl.when(i == 0)
     def _init():
-        sumsT_ref[:, :] = jnp.zeros_like(sumsT_ref)
+        sums_ref[:, :] = jnp.zeros_like(sums_ref)
         counts_ref[:, :] = jnp.zeros_like(counts_ref)
         inertia_ref[:, :] = jnp.zeros_like(inertia_ref)
 
-    # sumsᵀ (f, k): contract the lane (sample) axes of both operands on the
-    # MXU — dot_general, so the (k, block) onehot is never transposed
-    sumsT_ref[:, :] += jax.lax.dot_general(
-        xb, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(sumsT_ref.dtype)
+    # sums by piece, (kp, p·f): contract the lane (sample) axes of both
+    # operands on the MXU — dot_general, so neither is transposed. The one-hot
+    # rows are the streamed operand: with the p·f stack rows streamed against
+    # it the same product took 9.5 ms a pass where this takes 7.4 (v5e, 2^26 x
+    # 16, k = 8: PERF.md, PR 29)
+    sums_ref[:, :] += jax.lax.dot_general(
+        onehot, stack, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
     # accumulate the count in f32: a bf16 onehot sum saturates at 256
     counts_ref[:, :] += jnp.sum(
         onehot, axis=1, keepdims=True, dtype=counts_ref.dtype
@@ -170,7 +239,8 @@ def _prepare(data: jax.Array, block: int) -> jax.Array:
     bfloat16 stays bfloat16 — the kernel's contractions accumulate in f32
     (``preferred_element_type``) while the streamed operand keeps half the
     HBM footprint, doubling the bandwidth-bound iteration rate. Everything
-    else (f64 included: Mosaic cannot lower it) is carried as f32."""
+    else (f64 included: Mosaic cannot lower it) is carried as f32 and
+    multiplied in f32."""
     x = data if data.dtype == jnp.bfloat16 else data.astype(jnp.float32)
     n = x.shape[0]
     n_pad = -(-n // block) * block
@@ -180,48 +250,65 @@ def _prepare(data: jax.Array, block: int) -> jax.Array:
     return xT
 
 
+def _prepare_for(data: jax.Array, k: int) -> jax.Array:
+    """:func:`_prepare` at the block the kernel takes for ``k`` clusters and
+    the dtype ``data`` is streamed in."""
+    itemsize = 2 if data.dtype == jnp.bfloat16 else 4
+    return _prepare(data, _block_cols(data.shape[1], k, itemsize))
+
+
 def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool):
-    """Invoke the kernel on a prepared (f, n_pad) operand. Returns the raw
-    (sumsT, counts, inertia) accumulators — labels are deliberately NOT a
-    kernel output (see the module docstring on lane padding)."""
+    """Invoke the kernel on a prepared (f, n_pad) operand. Returns the
+    (sumsT (f, k), counts (k, 1), inertia (1, 1)) accumulators — labels are
+    deliberately NOT a kernel output (see the module docstring on lane
+    padding)."""
     f, n_pad = xT.shape
-    block = _block_cols(f, k)
+    block = _block_cols(f, k, xT.dtype.itemsize)
     assert n_pad % block == 0, (n_pad, block)
+    kp = _pad8(k)
     c32 = centers.astype(jnp.float32)
-    csq = jnp.sum(c32 * c32, axis=1, keepdims=True)  # (k, 1) — always f32
-    # the score dot's operands must share the streamed dtype (bf16 stays
-    # bf16 on the MXU; accumulation is f32 via preferred_element_type)
-    cx = c32.astype(xT.dtype)
+    rows = ((0, kp - k), (0, 0))  # rows beyond k: zero centres under csq = +inf, never the argmin
+    csq = jnp.pad(  # always from f32 centres
+        jnp.sum(c32 * c32, axis=1, keepdims=True), rows, constant_values=jnp.inf
+    )
+    # the score dot's operands share the streamed dtype's pieces: −2c (exact)
+    # as bf16 for bf16 rows, as its three pieces for f32 rows, each group
+    # repeated against every piece of x (kernel docstring)
+    c_pieces = _bf16_pieces(jnp.pad(-2.0 * c32, rows).astype(xT.dtype))
+    p = len(c_pieces)
+    cx = jnp.tile(jnp.concatenate(c_pieces, axis=0), (1, p))  # (p·kp, p·f)
     nv = jnp.reshape(n_valid.astype(jnp.int32), (1, 1))
 
-    return pl.pallas_call(
-        functools.partial(_lloyd_kernel, k=k, block=block),
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+
+    sums, counts, inertia = pl.pallas_call(
+        functools.partial(_lloyd_kernel, kp=kp, block=block),
         out_shape=(
-            jax.ShapeDtypeStruct((f, k), jnp.float32),
-            jax.ShapeDtypeStruct((k, 1), jnp.float32),
+            jax.ShapeDtypeStruct((kp, p * f), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         grid=(n_pad // block,),
         in_specs=[
             pl.BlockSpec((f, block), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, f), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            whole((kp, 1)),
+            whole((p * kp, p * f)),
+            whole((1, 1)),
         ],
-        out_specs=(
-            pl.BlockSpec((f, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ),
+        out_specs=(whole((kp, p * f)), whole((kp, 1)), whole((1, 1))),
         interpret=interpret,
+        name="lloyd_pass",
     )(xT, csq, cx, nv)
+    sums = sum(sums[:k, g * f : (g + 1) * f] for g in range(p))  # fold x's pieces
+    return sums.T, counts[:k], inertia
 
 
 def _kernel_call(data, centers, k: int, n_valid, interpret: bool):
     """Pad, transpose, and invoke the kernel on one device's rows — the
     (n, f)-in convenience form (single calls and tests; iteration loops use
     :func:`_prepare` + :func:`_kernel_call_T` so the transpose hoists)."""
-    xT = _prepare(data, _block_cols(data.shape[1], k))
+    xT = _prepare_for(data, k)
     return _kernel_call_T(xT, centers, k, n_valid, interpret)
 
 
@@ -230,11 +317,12 @@ def _assign_labels(data: jax.Array, centers: jax.Array) -> jax.Array:
     ``centers``. Runs ONCE per program as the label epilogue — per-row labels
     are not a kernel output (module docstring).
 
-    The score is computed in the STREAMED dtype: for bfloat16 data the dot's
-    operands stay bf16 with f32 accumulation, exactly like the kernel's
-    score contraction — an all-f32 epilogue would disagree with the bf16
-    argmin that produced the kernel's sums/counts for boundary samples, so
-    ``labels_`` could contradict ``cluster_centers_`` (advisor r04#2)."""
+    The score is computed in the kernel's arithmetic for the STREAMED dtype:
+    bfloat16 rows against bfloat16 centres with f32 accumulation, float32
+    rows multiplied in float32 (:func:`mxu_precision`) — a score rounded
+    otherwise would disagree with the argmin that produced the kernel's
+    sums/counts for boundary samples, so ``labels_`` could contradict
+    ``cluster_centers_`` (advisor r04#2)."""
     c32 = centers.astype(jnp.float32)
     csq = jnp.sum(c32 * c32, axis=1)  # always from the UNQUANTIZED centers,
     # exactly like _kernel_call_T's csq operand
@@ -243,7 +331,8 @@ def _assign_labels(data: jax.Array, centers: jax.Array) -> jax.Array:
     else:
         x, c = data.astype(jnp.float32), c32
     dot = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=mxu_precision(x.dtype), preferred_element_type=jnp.float32,
     )
     score = csq[None, :] - 2.0 * dot
     return jnp.argmin(score, axis=1).astype(jnp.int32)
@@ -306,7 +395,7 @@ def prepare_run_operands(data: jax.Array, k: int):
     data passes on every chunk."""
     x32 = data.astype(jnp.float32)
     return (
-        _prepare(data, _block_cols(data.shape[1], k)),
+        _prepare_for(data, k),
         jnp.sum(x32 * x32),
     )
 
@@ -336,7 +425,7 @@ def fused_lloyd_run(
         x32 = data.astype(jnp.float32)
         xsq_sum = jnp.sum(x32 * x32)
     if xT is None:
-        xT = _prepare(data, _block_cols(data.shape[1], k))
+        xT = _prepare_for(data, k)
     n_valid = jnp.asarray(data.shape[0], jnp.int32)
 
     def body(i, carry):
@@ -469,8 +558,7 @@ def _sharded_run_fn(mesh, axis, p, k, n_global, n_steps, interpret):
         local_rows = xl.shape[0]
         idx = jax.lax.axis_index(axis)
         local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
-        f = xl.shape[1]
-        xT = _prepare(xl, _block_cols(f, k))  # once per program, per device
+        xT = _prepare_for(xl, k)  # once per program, per device
 
         def body(i, carry):
             c, _, _, _ = carry

@@ -1,0 +1,270 @@
+"""``KMeans.fit`` on float32 rows computes in float32 (ISSUE 29): against the
+plain reference of the ``kmeans_f32_k8`` configuration on every path a fit
+can take, the bfloat16-cast fit as the control, a structural guard on the
+traced programs for what only the chip shows (the MXU's rounding), and the
+spans and counters inside ``fit``.
+
+The limits. Unstructured N(0, 1) rows, 30 iterations from seeded rows. A
+program that multiplies in float32 makes every assignment the reference
+makes, so its centres differ from the reference's by summation order alone
+(a few 1e-8 relative) and no label differs; ONE differing assignment would
+move a centre by about |x - c| / n_k, 1e-3 of the centres' norm at these
+sizes, and the fits part from there. So: centres within 1e-5 (relative
+Frobenius norm), no label different, inertia within 1e-5 (the fused paths
+restore it as sum|x|^2 + sum min(score), a float32 cancellation of about
+1e-7 of sum|x|^2). The seeds are ones on which no score of the two
+formulations (quadratic expansion here, direct differences there) ties
+within rounding: about one fit in ten at this size has such a tie.
+"""
+
+import glob
+import os
+import tempfile
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import heat_tpu as ht
+from chipbench import spec
+from heat_tpu.cluster import KMeans
+from heat_tpu.cluster.kmeans import _lloyd_iter
+from heat_tpu.core import fusion, telemetry
+from heat_tpu.ops import lloyd
+
+N, F, K, ITERS = 8192, 16, 8, 30
+CENTERS_LIMIT, INERTIA_LIMIT = 1e-5, 1e-5
+MODES = ("single", "sharded", "jnp")
+KMEANS_KEYS = [f"phase_kmeans_{name}_ns" for name in fusion._KMEANS_PHASES] + [
+    "phase_kmeans_fits", "phase_kmeans_dispatches", "phase_kmeans_syncs",
+]
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return spec.load_module("references", "kmeans_f32_k8.py")
+
+
+@pytest.fixture(scope="module")
+def rows():
+    rng = np.random.default_rng(29)
+    data = rng.standard_normal((N, F)).astype(np.float32)
+    return data, data[np.sort(rng.choice(N, size=K, replace=False))]
+
+
+def fit(mode, data, init, monkeypatch, dtype=None, iters=ITERS):
+    """One ``KMeans.fit`` through the public API on the path ``mode``: the
+    sharded kernel in interpret mode on the suite's virtual devices, the
+    single-device kernel likewise (steered here: off the chip ``_fused_mode``
+    takes it on one device only), or the jnp path."""
+    x = ht.array(data, split=None if mode == "single" else 0)
+    if dtype is not None:
+        x = x.astype(dtype)
+    km = KMeans(n_clusters=K, init=ht.array(init), max_iter=iters, tol=0.0,
+                use_fused=False if mode == "jnp" else True)
+    if mode == "single":
+        monkeypatch.setattr(KMeans, "_fused_mode", lambda self, x: ("single", True))
+    assert (km._fused_mode(x)[0] or "jnp") == mode
+    return km.fit(x)
+
+
+def gaps(km, want):
+    centers, labels, inertia = want
+    got = np.asarray(km.cluster_centers_.numpy(), np.float64)
+    return (
+        float(np.linalg.norm(got - centers) / np.linalg.norm(centers)),
+        abs(km.inertia_ - inertia) / inertia,
+        int((km.labels_.numpy() != np.asarray(labels)).sum()),
+    )
+
+
+@pytest.fixture(scope="module")
+def want(reference, rows):
+    data, init = rows
+    return reference.lloyd(jnp.asarray(data), init, ITERS)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_float32_fit_is_the_plain_reference(mode, rows, want, monkeypatch):
+    km = fit(mode, *rows, monkeypatch)
+    centers_gap, inertia_gap, labels_differ = gaps(km, want)
+    assert km.n_iter_ == ITERS
+    assert centers_gap <= CENTERS_LIMIT, centers_gap
+    assert inertia_gap <= INERTIA_LIMIT, inertia_gap
+    assert labels_differ == 0
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_bfloat16_cast_fit_falls_outside_the_limits(mode, rows, want, monkeypatch):
+    """The control: rows cast to bfloat16 (the kernel then multiplies
+    bfloat16 with float32 accumulation, the jnp path float32 on rounded
+    rows). It has to fail the float32 limits, and by a wide margin."""
+    km = fit(mode, *rows, monkeypatch, dtype=ht.bfloat16)
+    centers_gap, _, labels_differ = gaps(km, want)
+    assert centers_gap > 100 * CENTERS_LIMIT, centers_gap
+    assert labels_differ > 0
+
+
+def _dots(jaxpr, out):
+    """Every ``dot_general`` equation of ``jaxpr``, descending into the
+    jaxprs its equations carry (``pallas_call``, ``jit``, ``while`` ...)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _dots(inner, out)
+    return out
+
+
+def _float32_products_only(eqn) -> bool:
+    """A contraction multiplies in float32 if XLA is told to (``HIGHEST`` on
+    both operands) or if it is built from exact pieces: bfloat16 operands
+    accumulated in float32, whose products the MXU does not round."""
+    operands = [v.aval.dtype for v in eqn.invars]
+    if all(d == jnp.bfloat16 for d in operands):
+        return eqn.params["preferred_element_type"] == jnp.float32
+    precision = eqn.params["precision"]
+    return precision is not None and all(p == jax.lax.Precision.HIGHEST for p in tuple(precision))
+
+
+TRACED = {
+    "fused_lloyd_run": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 2),
+    "assign_labels": lloyd._assign_labels,
+    "lloyd_iter": lambda x, c: _lloyd_iter(x, c, K),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TRACED))
+def test_no_contraction_on_float32_rows_is_left_at_the_default(name):
+    """What only the chip shows: the XLA default multiplies float32 operands
+    on the MXU in one bfloat16 pass, and a CPU computes the same program in
+    float32 whatever it asks for. So the traced programs are held to asking."""
+    x = jax.ShapeDtypeStruct((4096, F), jnp.float32)
+    c = jax.ShapeDtypeStruct((K, F), jnp.float32)
+    dots = _dots(jax.make_jaxpr(TRACED[name])(x, c).jaxpr, [])
+    assert dots, "the traced program holds no contraction"
+    assert all(_float32_products_only(eqn) for eqn in dots), [str(eqn) for eqn in dots]
+
+
+def test_bfloat16_rows_keep_their_one_bfloat16_pass():
+    x = jax.ShapeDtypeStruct((4096, F), jnp.bfloat16)
+    c = jax.ShapeDtypeStruct((K, F), jnp.float32)
+    for name in ("fused_lloyd_run", "assign_labels"):
+        dots = _dots(jax.make_jaxpr(TRACED[name])(x, c).jaxpr, [])
+        assert dots and all(v.aval.dtype == jnp.bfloat16 for eqn in dots for v in eqn.invars), name
+        assert all(eqn.params["precision"] is None for eqn in dots), name
+    assert lloyd.mxu_precision(jnp.bfloat16) is None
+    assert lloyd.mxu_precision(jnp.float32) == lloyd.mxu_precision(jnp.float64) == jax.lax.Precision.HIGHEST
+
+
+def test_bf16_pieces_add_up_exactly():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray((rng.standard_normal(4096) * 10.0 ** rng.integers(-6, 7, 4096)).astype(np.float32))
+    pieces = lloyd._bf16_pieces(x)
+    assert len(pieces) == 3 and all(p.dtype == jnp.bfloat16 for p in pieces)
+    total = sum(np.asarray(p, np.float64) for p in pieces)
+    np.testing.assert_array_equal(total, np.asarray(x, np.float64))
+    low = x.astype(jnp.bfloat16)
+    assert lloyd._bf16_pieces(low) == (low,)
+
+
+def _kmeans_delta(before):
+    after = fusion.cache_stats()
+    return {key: after[key] - before[key] for key in KMEANS_KEYS}
+
+
+@pytest.mark.parametrize("mode", ("sharded", "jnp"))
+def test_counters_of_one_fit_with_telemetry_on(mode, rows, monkeypatch):
+    data, init = rows
+    fit(mode, data[:1024], init, monkeypatch)  # compiled before the counted fit
+    before = fusion.cache_stats()
+    with telemetry.enabled(1):
+        km = fit(mode, data[:1024], init, monkeypatch)
+    got = _kmeans_delta(before)
+    assert km.n_iter_ == ITERS
+    # four programs of up to 8 iterations, a blocking read of the shift after
+    # each, and one of the inertia
+    assert (got["phase_kmeans_fits"], got["phase_kmeans_dispatches"], got["phase_kmeans_syncs"]) == (1, 4, 5)
+    for name in fusion._KMEANS_PHASES:
+        assert got[f"phase_kmeans_{name}_ns"] > 0, name
+
+
+def test_counters_stay_where_they_are_with_telemetry_off(rows, monkeypatch):
+    data, init = rows
+    before = fusion.cache_stats()
+    assert not telemetry.tracing()
+    fit("jnp", data[:1024], init, monkeypatch, iters=3)
+    assert _kmeans_delta(before) == dict.fromkeys(KMEANS_KEYS, 0)
+
+
+def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
+    """``heat.kmeans.fit`` with its children side by side, in the session's
+    own ``.xplane.pb``, and the ``gaps`` verb's reader sees them."""
+    data, init = rows
+    fit("jnp", data[:1024], init, monkeypatch)
+    with tempfile.TemporaryDirectory() as directory:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(directory, profiler_options=options)
+        try:
+            fit("jnp", data[:1024], init, monkeypatch)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(directory, "plugins", "profile", "*", "*.xplane.pb"))
+        spans = []
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)  # nanobind's stats type
+            for plane in jax.profiler.ProfileData.from_file(path).planes:
+                if plane.name == "/host:CPU":
+                    spans += [
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                        for line in plane.lines for e in line.events if e.name.startswith("heat.kmeans.")
+                    ]
+    (parent,) = [sp for sp in spans if sp[0] == "heat.kmeans.fit"]
+    assert (parent[3]["mode"], int(parent[3]["n"]), int(parent[3]["f"]), int(parent[3]["k"])) == ("jnp", 1024, F, K)
+    children = sorted((sp for sp in spans if sp is not parent), key=lambda sp: sp[1])
+    names = [sp[0].rsplit(".", 1)[1] for sp in children]
+    assert names == ["init", "prepare"] + ["dispatch", "sync"] * 4 + ["wrap"]
+    assert all(parent[1] <= sp[1] and sp[2] <= parent[2] for sp in children)
+    assert all(a[2] <= b[1] for a, b in zip(children, children[1:])), "children overlap"
+
+
+# -- the kernel at the benchmark's widths, compiled for a described v5e (no chip):
+# what interpret mode cannot show (scoped VMEM, Mosaic's refusals). All in this
+# one file, behind one fixture (on-chip-measurement guide, section 2).
+@pytest.fixture(scope="module")
+def one_v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its library is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype,f,k", [("float32", 16, 8), ("bfloat16", 16, 8), ("float32", 512, 128), ("float32", 20, 17)])
+def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k):
+    block = lloyd._block_cols(f, k, jnp.dtype(dtype).itemsize)
+    xT = jax.ShapeDtypeStruct((f, 4 * block), jnp.dtype(dtype), sharding=one_v5e)
+    c = jax.ShapeDtypeStruct((k, f), jnp.float32, sharding=one_v5e)
+    nv = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_v5e)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # such a compile cannot be read back without a chip
+    try:
+        # the chip runs with 64-bit mode off; under the suite's x64 ``jnp.argmin``
+        # asks for an int64 index, which Mosaic refuses (PERF.md, section 7)
+        with jax.enable_x64(False):
+            call = jax.jit(lambda xT, c, nv: lloyd._kernel_call_T(xT, c, k, nv, False))
+            text = call.lower(xT, c, nv).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert "lloyd_pass" in text and "tpu_custom_call" in text

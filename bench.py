@@ -67,8 +67,8 @@ def annotate_roofline(rec: dict) -> None:
     peaks = _roofline_peaks(rec["device_kind"])
     n = rec.get("n") or 0
     # kmeans (config 3): HBM-bound. The fused pallas path reads the operand
-    # ONCE per iteration and writes nothing per-row (labels are a one-off
-    # epilogue, cancelled by the marginal); the jnp path reads twice
+    # ONCE per iteration and writes nothing per-row (labels are the last
+    # pass's one-off store, cancelled by the marginal); the jnp path reads twice
     # (assignment + update contractions) and writes the label vector.
     rate = rec.get("lloyd_iters_per_sec_marginal") or rec.get("value")
     if rate and n:

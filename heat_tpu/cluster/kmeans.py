@@ -91,7 +91,9 @@ class KMeans(_KCluster):
     max_iter=300, tol=1e-4, random_state=None. ``use_fused`` (beyond the
     reference) selects the single-pass samples-in-lanes pallas Lloyd kernel
     (ops/lloyd.py): ``None`` auto-selects it on TPU backends, where it reads
-    the operand once per iteration where the jnp path reads it twice;
+    the operand once per iteration where the jnp path reads it twice, and
+    the last pass of each program writes the labels it assigned (``labels_``
+    is the assignment ``inertia_`` is summed over; no separate label pass);
     ``True`` forces it (interpret mode off-TPU — the testing path), ``False``
     pins the jnp oracle path. A kernel that fails to lower or run raises:
     there is no fallback from the fused path to the oracle.
@@ -172,18 +174,20 @@ class KMeans(_KCluster):
             n=int(x.shape[0]), f=int(x.shape[1]), k=self.n_clusters,
         )
         try:
-            dispatches, syncs = self._fit(x, mode, interpret, ph.phase)
+            counts = self._fit(x, mode, interpret, ph.phase)
         finally:
             ph.close()
-        fusion.note_kmeans_fit(ph.ns, dispatches, syncs)
+        fusion.note_kmeans_fit(ph.ns, *counts)
         return self
 
     def _fit(self, x: DNDarray, mode, interpret: bool, mark):
         """:meth:`fit` past its checks, on the dispatch ``_fused_mode``
         resolved. ``mark(name)`` opens the fit's next phase
         (``telemetry.Phases.phase``; nothing when the fit is not traced).
-        Returns the Lloyd programs dispatched and the blocking host reads
-        made."""
+        Returns the Lloyd programs dispatched, the blocking host reads made
+        and the XLA label passes over the rows that those programs ran (the
+        fused programs' labels are their last kernel pass's, the jnp
+        program's ride in its loop carry: none)."""
         k, n_global = self.n_clusters, int(x.shape[0])
         mark("init")
         centers = self._initialize_cluster_centers(x)
@@ -252,4 +256,5 @@ class KMeans(_KCluster):
             x.comm,
         )
         self._labels = self._wrap_labels(labels, x)
-        return dispatches, syncs
+        epilogues = dispatches * _lloyd.RUN_LABEL_EPILOGUES if mode else 0
+        return dispatches, syncs, epilogues

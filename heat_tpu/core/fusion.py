@@ -529,10 +529,14 @@ _STATS.update(
 )
 # an estimator's fit is timed the same way, under the same switch
 # (cluster/kmeans.py: the heat.kmeans.fit span and its children): fits, the
-# programs they dispatched, their blocking host reads, nanoseconds per phase
+# programs they dispatched, their blocking host reads, the XLA label passes
+# over the rows those programs ran, nanoseconds per phase
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
 _STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
-_STATS.update(phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0)
+_STATS.update(
+    phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
+    phase_kmeans_label_epilogues=0,
+)
 # place, read and a fit are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches
 _PHASE_LOCK = threading.Lock()
@@ -547,14 +551,16 @@ def note_phase(name: str, ns: int) -> None:
         _STATS[f"phase_{name}_ns"] += ns
 
 
-def note_kmeans_fit(ns: dict, dispatches: int, syncs: int) -> None:
+def note_kmeans_fit(ns: dict, dispatches: int, syncs: int, label_epilogues: int) -> None:
     """Count one ``heat.kmeans.fit``: the nanoseconds of each phase it went
-    through (``telemetry.Phases.ns``), the Lloyd programs it dispatched and
-    the blocking host reads it made (while ``telemetry.tracing()``)."""
+    through (``telemetry.Phases.ns``), the Lloyd programs it dispatched, the
+    blocking host reads it made and the XLA label passes over the rows that
+    its programs ran (while ``telemetry.tracing()``)."""
     with _PHASE_LOCK:
         _STATS["phase_kmeans_fits"] += 1
         _STATS["phase_kmeans_dispatches"] += dispatches
         _STATS["phase_kmeans_syncs"] += syncs
+        _STATS["phase_kmeans_label_epilogues"] += label_epilogues
         for name, took in ns.items():
             _STATS[f"phase_kmeans_{name}_ns"] += took
 

@@ -170,6 +170,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_kmeans_fits_total", (_C, "KMeans fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_kmeans_dispatches_total", (_C, "Lloyd programs dispatched by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (shift, inertia) made by timed KMeans fits.", [])),
+        ("heat_tpu_kmeans_label_epilogues_total", (_C, "XLA label passes over the rows run by the Lloyd programs of timed KMeans fits.", [])),
         ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
@@ -296,7 +297,7 @@ def _collect_fusion(out: List[Sample]) -> None:
             "heat_tpu_fusion_phase_seconds_total", {"phase": phase},
             stats[f"phase_{phase}_ns"] * 1e-9,
         ))
-    for count in ("fits", "dispatches", "syncs"):
+    for count in ("fits", "dispatches", "syncs", "label_epilogues"):
         out.append((f"heat_tpu_kmeans_{count}_total", {}, float(stats[f"phase_kmeans_{count}"])))
     for phase in fusion._KMEANS_PHASES:
         out.append((

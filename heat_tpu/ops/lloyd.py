@@ -25,11 +25,13 @@ jnp path's 1.3 GB, a 0.34x "speedup". With samples in lanes the minor axis
 is the long one (no padding, any f), the sublane axis is f (padded to 8),
 and every reduction in the kernel is lane-preserving. The one-time
 ``transpose`` to (f, n) costs one data pass and is hoisted out of the
-iteration loop; per-iteration HBM traffic is n·f reads and NOTHING
-per-row written (labels are not an iteration output at all — a separate
-fused jnp epilogue computes the final assignment once per program, against
-the centers of the last iteration, which is the jnp path's exact label
-convention).
+iteration loop; per-iteration HBM traffic is n·f reads and nothing per-row
+written, except in the LAST pass of a program: that one stores the ``labels``
+row it already holds, a lane-dense (1, block) int32 block (4 bytes a sample
+beside the 4·f it reads). So a program's labels are the assignment against
+the centers that went INTO its last iteration (the jnp path's exact label
+convention), and they are the very assignment that produced that iteration's
+sums, counts and inertia: no XLA pass over the rows computes labels.
 
 Precision follows the rows' dtype alone. The MXU multiplies bfloat16: left
 at the default, float32 operands are rounded to bfloat16 and multiplied in
@@ -42,8 +44,7 @@ stacked along the contracted and the output axes of ONE bfloat16 pass, so
 every piece product is exact in the float32 accumulator, at the MXU cost of
 the single rounded pass. bfloat16 rows are one piece: they keep their
 bfloat16 multiplication with float32 accumulation and half the HBM stream.
-The label epilogue and the jnp path ask XLA for the same
-(:func:`mxu_precision`).
+The jnp path asks XLA for the same (:func:`mxu_precision`).
 
 This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here on
 TPU (``fused_supported`` / ``fused_sharded_supported``) and takes the jnp
@@ -81,7 +82,7 @@ __all__ = [
 
 def mxu_precision(dtype) -> Optional[jax.lax.Precision]:
     """What a contraction on rows of ``dtype`` asks of XLA (the jnp Lloyd
-    path, the label epilogue): float32 and wider multiply in float32
+    path): float32 and wider multiply in float32
     (``HIGHEST``; the default rounds both operands to bfloat16 and multiplies
     once), bfloat16 rows keep their one bfloat16 pass."""
     return None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
@@ -109,6 +110,12 @@ def _bf16_pieces(x: jax.Array) -> tuple:
     rest = x - hi  # exact: at most 16 bits are left
     mid = head(rest)
     return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
+
+
+RUN_LABEL_EPILOGUES = 0
+"""XLA passes over the rows that one ``fused_lloyd_run*`` program makes for
+its labels: none, its last kernel pass writes them. ``KMeans.fit`` adds it per
+program it dispatches (``fusion.cache_stats()["phase_kmeans_label_epilogues"]``)."""
 
 
 def _pad8(n: int) -> int:
@@ -161,6 +168,7 @@ def _lloyd_kernel(
     sums_ref,
     counts_ref,
     inertia_ref,
+    labels_ref=None,
     *,
     kp: int,
     block: int,
@@ -169,7 +177,10 @@ def _lloyd_kernel(
     Samples at column index >= nvalid (tail padding: ragged sizes, or a
     device's share of the global padding under the sharded wrapper) are
     masked out of every accumulator. n_valid is a runtime (1, 1) scalar
-    operand so each device can carry its own count.
+    operand so each device can carry its own count. ``labels_ref``, where
+    the call has that output, takes the block's (1, block) argmin row as it
+    is, unmasked: what it holds at columns >= nvalid is unspecified (the
+    output ends at the operand's own length, beyond which nothing is kept).
 
     ``kp`` is k padded to a sublane multiple: the centre rows beyond k are
     zero and carry ``csq = +inf``, so no sample is ever assigned to them.
@@ -205,6 +216,8 @@ def _lloyd_kernel(
     kcol = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
     labels = jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)  # (1, block)
     onehot = jnp.logical_and(labels == kcol, valid).astype(jnp.bfloat16)  # (kp, block)
+    if labels_ref is not None:
+        labels_ref[:, :] = labels
 
     @pl.when(i == 0)
     def _init():
@@ -257,11 +270,17 @@ def _prepare_for(data: jax.Array, k: int) -> jax.Array:
     return _prepare(data, _block_cols(data.shape[1], k, itemsize))
 
 
-def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool):
+def _kernel_call_T(
+    xT, centers, k: int, n_valid, interpret: bool, n_labels: Optional[int] = None
+):
     """Invoke the kernel on a prepared (f, n_pad) operand. Returns the
-    (sumsT (f, k), counts (k, 1), inertia (1, 1)) accumulators — labels are
-    deliberately NOT a kernel output (see the module docstring on lane
-    padding)."""
+    (sumsT (f, k), counts (k, 1), inertia (1, 1)) accumulators and, given
+    ``n_labels`` (a program's last pass, a kernel of its own name), the
+    (n_labels,) int32 assignment of the operand's first ``n_labels`` samples
+    against ``centers`` as a fourth. ``n_labels`` is the operand's length
+    before ``_prepare`` padded it: the output is exactly that long, Pallas
+    clips the last block's write to it, and nobody copies a padded row to
+    cut it (0.84 ms for 2^26 labels on a v5e: PERF.md, PR 30)."""
     f, n_pad = xT.shape
     block = _block_cols(f, k, xT.dtype.itemsize)
     assert n_pad % block == 0, (n_pad, block)
@@ -282,13 +301,19 @@ def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool):
     def whole(shape):
         return pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
 
-    sums, counts, inertia = pl.pallas_call(
+    out_shape = [
+        jax.ShapeDtypeStruct((kp, p * f), jnp.float32),
+        jax.ShapeDtypeStruct((kp, 1), jnp.float32),
+        jax.ShapeDtypeStruct((1, 1), jnp.float32),
+    ]
+    out_specs = [whole((kp, p * f)), whole((kp, 1)), whole((1, 1))]
+    if n_labels is not None:
+        assert n_pad - block < n_labels <= n_pad, (n_labels, n_pad, block)
+        out_shape.append(jax.ShapeDtypeStruct((1, n_labels), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, block), lambda i: (0, i), memory_space=pltpu.VMEM))
+    sums, counts, inertia, *labels = pl.pallas_call(
         functools.partial(_lloyd_kernel, kp=kp, block=block),
-        out_shape=(
-            jax.ShapeDtypeStruct((kp, p * f), jnp.float32),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ),
+        out_shape=out_shape,
         grid=(n_pad // block,),
         in_specs=[
             pl.BlockSpec((f, block), lambda i: (0, i), memory_space=pltpu.VMEM),
@@ -296,53 +321,29 @@ def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool):
             whole((p * kp, p * f)),
             whole((1, 1)),
         ],
-        out_specs=(whole((kp, p * f)), whole((kp, 1)), whole((1, 1))),
+        out_specs=out_specs,
         interpret=interpret,
-        name="lloyd_pass",
+        name="lloyd_pass" if n_labels is None else "lloyd_pass_labels",
     )(xT, csq, cx, nv)
     sums = sum(sums[:k, g * f : (g + 1) * f] for g in range(p))  # fold x's pieces
-    return sums.T, counts[:k], inertia
+    return (sums.T, counts[:k], inertia, *(row[0] for row in labels))
 
 
-def _kernel_call(data, centers, k: int, n_valid, interpret: bool):
+def _kernel_call(data, centers, k: int, n_valid, interpret: bool, emit_labels: bool = False):
     """Pad, transpose, and invoke the kernel on one device's rows — the
     (n, f)-in convenience form (single calls and tests; iteration loops use
-    :func:`_prepare` + :func:`_kernel_call_T` so the transpose hoists)."""
+    :func:`_prepare` + :func:`_kernel_call_T` so the transpose hoists).
+    ``emit_labels`` asks for the labels of the rows of ``data`` as well."""
     xT = _prepare_for(data, k)
-    return _kernel_call_T(xT, centers, k, n_valid, interpret)
-
-
-def _assign_labels(data: jax.Array, centers: jax.Array) -> jax.Array:
-    """The assignment step alone, as one fused XLA pass: labels w.r.t.
-    ``centers``. Runs ONCE per program as the label epilogue — per-row labels
-    are not a kernel output (module docstring).
-
-    The score is computed in the kernel's arithmetic for the STREAMED dtype:
-    bfloat16 rows against bfloat16 centres with f32 accumulation, float32
-    rows multiplied in float32 (:func:`mxu_precision`) — a score rounded
-    otherwise would disagree with the argmin that produced the kernel's
-    sums/counts for boundary samples, so ``labels_`` could contradict
-    ``cluster_centers_`` (advisor r04#2)."""
-    c32 = centers.astype(jnp.float32)
-    csq = jnp.sum(c32 * c32, axis=1)  # always from the UNQUANTIZED centers,
-    # exactly like _kernel_call_T's csq operand
-    if data.dtype == jnp.bfloat16:
-        x, c = data, c32.astype(jnp.bfloat16)
-    else:
-        x, c = data.astype(jnp.float32), c32
-    dot = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
-        precision=mxu_precision(x.dtype), preferred_element_type=jnp.float32,
-    )
-    score = csq[None, :] - 2.0 * dot
-    return jnp.argmin(score, axis=1).astype(jnp.int32)
+    n_labels = data.shape[0] if emit_labels else None
+    return _kernel_call_T(xT, centers, k, n_valid, interpret, n_labels)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def fused_lloyd_iter(
     data: jax.Array, centers: jax.Array, k: int, xsq_sum=None, interpret: bool = False
 ):
-    """One Lloyd iteration in a single accumulator pass (+ label epilogue).
+    """One Lloyd iteration in a single pass that writes its labels too.
 
     Returns ``(new_centers, labels, inertia, shift)`` with the same contract
     as ``cluster.kmeans._lloyd_iter`` (inertia includes the Σ|x|² term;
@@ -351,17 +352,17 @@ def fused_lloyd_iter(
     iteration loop, or it is computed here (costing the one extra data read
     the kernel exists to avoid).
 
-    Cost note (advisor r04#4): every call pays the ``_assign_labels``
-    epilogue — a FULL extra data pass — plus the Σ|x|² pass when ``xsq_sum``
-    is not supplied, so a Python loop over single calls reads the data ~3x
-    per iteration. Iteration loops should use :func:`fused_lloyd_run`
-    (labels once per N-step program) with :func:`prepare_run_operands`
-    hoisting the transpose/Σ|x|² across chunks — that combination is the
-    advertised one-read-per-iteration path.
+    Cost note (advisor r04#4): every call pays the samples-in-lanes copy,
+    the label store and, when ``xsq_sum`` is not supplied, the Σ|x|² pass, so
+    a Python loop over single calls moves the data ~3x per iteration.
+    Iteration loops should use :func:`fused_lloyd_run` (labels stored by the
+    last of N passes only) with :func:`prepare_run_operands` hoisting the
+    transpose/Σ|x|² across chunks — that combination is the advertised
+    one-read-per-iteration path.
     """
     n = data.shape[0]
-    sumsT, counts, inertia = _kernel_call(
-        data, centers, k, jnp.asarray(n, jnp.int32), interpret
+    sumsT, counts, inertia, labels = _kernel_call(
+        data, centers, k, jnp.asarray(n, jnp.int32), interpret, emit_labels=True
     )
     if xsq_sum is None:
         x32 = data.astype(jnp.float32)
@@ -369,7 +370,7 @@ def fused_lloyd_iter(
     new_centers, inertia_full, shift = _finalize(
         sumsT, counts, inertia, centers, xsq_sum
     )
-    return new_centers, _assign_labels(data, centers), inertia_full, shift
+    return new_centers, labels, inertia_full, shift
 
 
 def _finalize(sumsT, counts, inertia, centers, xsq_sum):
@@ -405,6 +406,20 @@ _prepare_run_operands = functools.partial(jax.jit, static_argnames="k")(
 )
 
 
+def _steps(step, centers, n_steps: int):
+    """``n_steps`` Lloyd iterations by ``step(centers, emit_labels)`` (one
+    kernel pass and the centre update: ``(new_centers, inertia, shift)``,
+    with the pass's labels as a fourth when it emits them). The last step is
+    peeled off the loop: it is the one pass that writes labels. Returns
+    ``(centers, labels, inertia, shift)``."""
+    acc = jnp.zeros((), jnp.float32)
+    centers, _, _ = jax.lax.fori_loop(
+        0, n_steps - 1, lambda i, carry: step(carry[0], False), (centers, acc, acc)
+    )
+    centers, inertia, shift, labels = step(centers, True)
+    return centers, labels, inertia, shift
+
+
 @functools.partial(jax.jit, static_argnames=("k", "n_steps", "interpret"))
 def fused_lloyd_run(
     data: jax.Array,
@@ -419,28 +434,24 @@ def fused_lloyd_run(
     ``cluster.kmeans._lloyd_run``): Σ|x|² and the samples-in-lanes transpose
     hoisted (within the program — pass ``xT``/``xsq_sum`` from
     :func:`prepare_run_operands` to hoist them across chunked calls too),
-    one kernel pass per step, labels from ONE epilogue pass against the last
-    iteration's input centers (the jnp oracle's exact label convention)."""
+    one kernel pass per step, the last of which also writes its labels: the
+    assignment against the last iteration's input centers (the jnp oracle's
+    exact label convention), which that iteration's inertia is summed over."""
     if xsq_sum is None:
         x32 = data.astype(jnp.float32)
         xsq_sum = jnp.sum(x32 * x32)
     if xT is None:
         xT = _prepare_for(data, k)
-    n_valid = jnp.asarray(data.shape[0], jnp.int32)
+    n = data.shape[0]
+    n_valid = jnp.asarray(n, jnp.int32)
 
-    def body(i, carry):
-        centers, _, _, _ = carry
-        sumsT, counts, inertia = _kernel_call_T(xT, centers, k, n_valid, interpret)
-        new_centers, inertia_full, shift = _finalize(
-            sumsT, counts, inertia, centers, xsq_sum
+    def step(c, emit_labels):
+        sumsT, counts, inertia, *labels = _kernel_call_T(
+            xT, c, k, n_valid, interpret, n if emit_labels else None
         )
-        return (new_centers, centers, inertia_full, shift)
+        return (*_finalize(sumsT, counts, inertia, c, xsq_sum), *labels)
 
-    acc = jnp.zeros((), jnp.float32)
-    centers, used, inertia, shift = jax.lax.fori_loop(
-        0, n_steps, body, (centers, centers, acc, acc)
-    )
-    return centers, _assign_labels(data, used), inertia, shift
+    return _steps(step, centers, n_steps)
 
 
 def fused_lloyd_iter_sharded(
@@ -458,43 +469,45 @@ def fused_lloyd_iter_sharded(
     multiple of the mesh size, suffix-padded when the logical ``n_global``
     is ragged. Each device runs the single-pass kernel on its own block —
     masking its share of the global padding — and the (f, k)/(k, 1)/scalar
-    accumulators merge with one ``psum``. Labels come from the shared jnp
-    epilogue on the row-sharded global view (no collectives: the matmul
-    against replicated centers and the argmin are row-local), sliced to the
-    logical length ``n_global``.
+    accumulators merge with one ``psum``. Labels are each device's kernel
+    output for its own rows (no collective), row-sharded like ``data`` and
+    sliced to the logical length ``n_global``.
 
-    Same return contract as :func:`fused_lloyd_iter`. The whole iteration
-    (shard_map + epilogue) is jitted, cached per (mesh, k, shapes).
+    Same return contract as :func:`fused_lloyd_iter`. The whole iteration is
+    jitted, cached per (mesh, k, shapes).
     """
     fn = _sharded_fn(comm.mesh, comm.axis_name, comm.size, k, int(n_global), bool(interpret))
     return fn(data, centers, xsq_sum)
 
 
 def _sharded_iter_fn(mesh, axis, k, n_global, interpret):
-    """Traced (data, centers, xsq_sum) -> (new_centers, inertia, shift) over
-    a row-sharded physical payload (single iteration; the fused-run form
-    keeps its loop inside the shard_map instead — see _sharded_run_fn)."""
+    """Traced (data, centers, xsq_sum) -> (new_centers, inertia, shift,
+    labels of the physical rows) over a row-sharded physical payload (single
+    iteration; the fused-run form keeps its loop inside the shard_map
+    instead — see _sharded_run_fn)."""
     from jax.sharding import PartitionSpec as P
 
     def device_step(xl, c):
         local_rows = xl.shape[0]
         idx = jax.lax.axis_index(axis)
         local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
-        sums, counts, inertia = _kernel_call(xl, c, k, local_valid, interpret)
+        sums, counts, inertia, labels = _kernel_call(
+            xl, c, k, local_valid, interpret, emit_labels=True
+        )
         sums = jax.lax.psum(sums, axis)
         counts = jax.lax.psum(counts, axis)
         inertia = jax.lax.psum(inertia, axis)
-        return sums, counts, inertia
+        return sums, counts, inertia, labels
 
     def step(data, centers, xsq_sum):
-        sums, counts, inertia = jax.shard_map(
+        sums, counts, inertia, labels = jax.shard_map(
             device_step,
             mesh=mesh,
             in_specs=(P(axis, None), P()),
-            out_specs=(P(), P(), P()),
+            out_specs=(P(), P(), P(), P(axis)),
             check_vma=False,  # pallas_call outputs carry no vma annotation
         )(data, centers)
-        return _finalize(sums, counts, inertia, centers, xsq_sum)
+        return (*_finalize(sums, counts, inertia, centers, xsq_sum), labels)
 
     return step
 
@@ -521,9 +534,8 @@ def _sharded_fn(mesh, axis, p, k, n_global, interpret):
     def run(data, centers, xsq_sum):
         if xsq_sum is None:
             xsq_sum = _logical_xsq_sum(data, n_global)
-        new_centers, inertia, shift = step(data, centers, xsq_sum)
-        labels = _assign_labels(data, centers)[:n_global]
-        return new_centers, labels, inertia, shift
+        new_centers, inertia, shift, labels = step(data, centers, xsq_sum)
+        return new_centers, labels[:n_global], inertia, shift
 
     return run
 
@@ -543,7 +555,7 @@ def fused_lloyd_run_sharded(
     ``xsq_sum`` to hoist it across chunked calls too; the per-device
     transpose lives inside the shard_map and is paid once per program), the
     fori_loop of single-pass kernel steps INSIDE the shard_map, one psum
-    per step."""
+    per step, and each device's last pass writing the labels of its rows."""
     fn = _sharded_run_fn(
         comm.mesh, comm.axis_name, comm.size, k, int(n_global), int(n_steps), bool(interpret)
     )
@@ -560,31 +572,28 @@ def _sharded_run_fn(mesh, axis, p, k, n_global, n_steps, interpret):
         local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
         xT = _prepare_for(xl, k)  # once per program, per device
 
-        def body(i, carry):
-            c, _, _, _ = carry
-            sumsT, counts, inertia = _kernel_call_T(xT, c, k, local_valid, interpret)
+        def step(c, emit_labels):
+            sumsT, counts, inertia, *labels = _kernel_call_T(
+                xT, c, k, local_valid, interpret, local_rows if emit_labels else None
+            )
             sumsT = jax.lax.psum(sumsT, axis)
             counts = jax.lax.psum(counts, axis)
             inertia = jax.lax.psum(inertia, axis)
-            new_c, inertia_full, shift = _finalize(sumsT, counts, inertia, c, xsq_sum)
-            return (new_c, c, inertia_full, shift)
+            return (*_finalize(sumsT, counts, inertia, c, xsq_sum), *labels)
 
-        acc = jnp.zeros((), jnp.float32)
-        c0 = c0.astype(jnp.float32)
-        return jax.lax.fori_loop(0, n_steps, body, (c0, c0, acc, acc))
+        return _steps(step, c0.astype(jnp.float32), n_steps)
 
     @jax.jit
     def run(data, centers, xsq_sum=None):
         if xsq_sum is None:
             xsq_sum = _logical_xsq_sum(data, n_global)
-        new_c, used, inertia, shift = jax.shard_map(
+        new_c, labels, inertia, shift = jax.shard_map(
             device_run,
             mesh=mesh,
             in_specs=(P(axis, None), P(), P()),
-            out_specs=(P(), P(), P(), P()),
+            out_specs=(P(), P(axis), P(), P()),
             check_vma=False,  # pallas_call outputs carry no vma annotation
         )(data, centers, xsq_sum)
-        labels = _assign_labels(data, used)[:n_global]
-        return new_c.astype(centers.dtype), labels, inertia, shift
+        return new_c.astype(centers.dtype), labels[:n_global], inertia, shift
 
     return run

@@ -38,7 +38,7 @@ N, F, K, ITERS = 8192, 16, 8, 30
 CENTERS_LIMIT, INERTIA_LIMIT = 1e-5, 1e-5
 MODES = ("single", "sharded", "jnp")
 KMEANS_KEYS = [f"phase_kmeans_{name}_ns" for name in fusion._KMEANS_PHASES] + [
-    "phase_kmeans_fits", "phase_kmeans_dispatches", "phase_kmeans_syncs",
+    "phase_kmeans_fits", "phase_kmeans_dispatches", "phase_kmeans_syncs", "phase_kmeans_label_epilogues",
 ]
 
 
@@ -134,7 +134,7 @@ def _float32_products_only(eqn) -> bool:
 
 TRACED = {
     "fused_lloyd_run": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 2),
-    "assign_labels": lloyd._assign_labels,
+    "fused_lloyd_iter": lambda x, c: lloyd.fused_lloyd_iter(x, c, K),
     "lloyd_iter": lambda x, c: _lloyd_iter(x, c, K),
 }
 
@@ -154,7 +154,7 @@ def test_no_contraction_on_float32_rows_is_left_at_the_default(name):
 def test_bfloat16_rows_keep_their_one_bfloat16_pass():
     x = jax.ShapeDtypeStruct((4096, F), jnp.bfloat16)
     c = jax.ShapeDtypeStruct((K, F), jnp.float32)
-    for name in ("fused_lloyd_run", "assign_labels"):
+    for name in ("fused_lloyd_run", "fused_lloyd_iter"):
         dots = _dots(jax.make_jaxpr(TRACED[name])(x, c).jaxpr, [])
         assert dots and all(v.aval.dtype == jnp.bfloat16 for eqn in dots for v in eqn.invars), name
         assert all(eqn.params["precision"] is None for eqn in dots), name
@@ -190,8 +190,28 @@ def test_counters_of_one_fit_with_telemetry_on(mode, rows, monkeypatch):
     # four programs of up to 8 iterations, a blocking read of the shift after
     # each, and one of the inertia
     assert (got["phase_kmeans_fits"], got["phase_kmeans_dispatches"], got["phase_kmeans_syncs"]) == (1, 4, 5)
+    # no program runs an XLA label pass over the rows: the fused programs' last
+    # kernel pass writes the labels, the jnp program carries them
+    assert got["phase_kmeans_label_epilogues"] == 0
     for name in fusion._KMEANS_PHASES:
         assert got[f"phase_kmeans_{name}_ns"] > 0, name
+
+
+def test_label_epilogues_reader_on_a_made_up_window():
+    """``label_epilogues_per_op``: the counter's growth over the fits', 0 when
+    it stood still, ``None`` for a program without it or a window without fits."""
+    import types
+
+    read = spec.load_module("layer_metrics", "label_epilogues_per_op.py").read
+
+    def window(before, after):
+        return types.SimpleNamespace(counters={"before": {"fusion": before}, "after": {"fusion": after}})
+
+    fits, key = "phase_kmeans_fits", "phase_kmeans_label_epilogues"
+    assert read(window({fits: 3, key: 12}, {fits: 21, key: 12})) == 0
+    assert read(window({fits: 3, key: 12}, {fits: 21, key: 84})) == 4
+    assert read(window({fits: 3}, {fits: 21})) is None
+    assert read(window({fits: 3, key: 12}, {fits: 3, key: 12})) is None
 
 
 def test_counters_stay_where_they_are_with_telemetry_off(rows, monkeypatch):
@@ -251,8 +271,12 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("emit_labels", [False, True], ids=["plain", "labels"])
 @pytest.mark.parametrize("dtype,f,k", [("float32", 16, 8), ("bfloat16", 16, 8), ("float32", 512, 128), ("float32", 20, 17)])
-def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k):
+def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k, emit_labels):
+    """The plain pass and the program's last one, which stores its (1, block)
+    labels row too (into an output that ends inside the last block, off a
+    lane-tile boundary), at the block ``_block_cols`` gives both."""
     block = lloyd._block_cols(f, k, jnp.dtype(dtype).itemsize)
     xT = jax.ShapeDtypeStruct((f, 4 * block), jnp.dtype(dtype), sharding=one_v5e)
     c = jax.ShapeDtypeStruct((k, f), jnp.float32, sharding=one_v5e)
@@ -263,8 +287,10 @@ def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k):
         # the chip runs with 64-bit mode off; under the suite's x64 ``jnp.argmin``
         # asks for an int64 index, which Mosaic refuses (PERF.md, section 7)
         with jax.enable_x64(False):
-            call = jax.jit(lambda xT, c, nv: lloyd._kernel_call_T(xT, c, k, nv, False))
+            n_labels = 4 * block - 77 if emit_labels else None
+            call = jax.jit(lambda xT, c, nv: lloyd._kernel_call_T(xT, c, k, nv, False, n_labels))
             text = call.lower(xT, c, nv).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
     assert "lloyd_pass" in text and "tpu_custom_call" in text
+    assert ("lloyd_pass_labels" in text) == emit_labels

@@ -1,4 +1,5 @@
-"""Fused pallas Lloyd kernel vs the jnp reference implementation.
+"""Fused pallas Lloyd kernel vs the jnp reference implementation, its labels
+(the last pass's own argmin row, ISSUE 30) included.
 
 Runs in pallas interpret mode on CPU (the same strategy as
 tests/test_ops_pallas.py); real-TPU timing lives in bench.py's primary
@@ -10,6 +11,83 @@ import numpy as np
 import pytest
 
 from harness import TestCase
+
+
+def _rows(seed, n, f, k, scale=2.0):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    data = rng.standard_normal((n, f)).astype(np.float32)
+    return data, jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * scale)
+
+
+@pytest.mark.parametrize("path,n_steps", [("single", 1), ("single", 8), ("sharded", 1), ("sharded", 8)])
+def test_run_labels_are_the_assignment_to_the_last_steps_input_centres(path, n_steps):
+    """A program's labels come from its LAST kernel pass: the assignment
+    against the centres that went into that iteration (what the jnp program
+    carries), whatever the number of plain passes before it, none included."""
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+    from heat_tpu.cluster.kmeans import _lloyd_iter, _lloyd_run
+    from heat_tpu.ops.lloyd import fused_lloyd_run, fused_lloyd_run_sharded
+
+    comm = ht.get_comm()
+    n, f, k = 512 * comm.size + 5, 8, 5  # ragged: a masked tail, a physical pad when sharded
+    data_np, centers = _rows(21, n, f, k)
+    if path == "single":
+        got = fused_lloyd_run(jnp.asarray(data_np), centers, k, n_steps, interpret=True)
+    else:
+        x = ht.array(data_np, split=0)
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, n_steps, interpret=True)
+    ref = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps)
+    assert got[1].shape == (n,) and got[1].dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-4, atol=1e-4)
+    # and spelled out: one more oracle step from the centres of n_steps - 1
+    before = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps - 1)[0] if n_steps > 1 else centers
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(_lloyd_iter(jnp.asarray(data_np), before, k)[1]))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_labels_bincount_is_the_kernels_counts_with_garbage_in_the_pad(dtype):
+    """The labels ARE the assignment that built the sums and counts: their
+    bincount is the kernel's counts exactly, in either dtype, and inf / NaN
+    rows beyond ``n_valid`` reach neither."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.ops.lloyd import _kernel_call
+
+    n, f, k = 3000, 16, 6
+    data_np, centers = _rows(23, n, f, k)
+    poisoned = np.concatenate(
+        [data_np, np.full((40, f), np.inf, np.float32), np.full((8, f), np.nan, np.float32)]
+    )
+    _, counts, inertia, labels = jax.jit(
+        lambda d, c: _kernel_call(d, c, k, jnp.asarray(n, jnp.int32), True, emit_labels=True)
+    )(jnp.asarray(poisoned).astype(dtype), centers)
+    assert labels.shape == (n + 48,) and np.isfinite(float(inertia[0, 0]))  # one per row handed over
+    valid = np.asarray(labels)[:n]
+    assert valid.min() >= 0 and valid.max() < k
+    np.testing.assert_array_equal(np.bincount(valid, minlength=k), np.asarray(counts)[:, 0])
+
+
+def test_bfloat16_labels_are_the_argmin_of_the_streamed_scores():
+    """bfloat16 rows score against -2c rounded to bfloat16 and |c|^2 of the
+    unrounded centres: the labels are that argmin, not the float32 one."""
+    import jax.numpy as jnp
+
+    from heat_tpu.ops.lloyd import fused_lloyd_iter
+
+    n, f, k = 4096, 16, 4
+    data_np, centers = _rows(11, n, f, k)
+    low = jnp.asarray(data_np).astype(jnp.bfloat16)
+    got = np.asarray(fused_lloyd_iter(low, centers, k, interpret=True)[1])
+    c64 = np.asarray(centers, np.float64)
+    cq = np.asarray((-2.0 * centers).astype(jnp.bfloat16).astype(jnp.float32), np.float64)
+    score = (c64 * c64).sum(axis=1)[None, :] + np.asarray(low.astype(jnp.float32), np.float64) @ cq.T
+    np.testing.assert_array_equal(got, score.argmin(axis=1))
 
 
 class TestFusedLloyd(TestCase):
@@ -170,17 +248,17 @@ class TestFusedLloyd(TestCase):
             np.asarray(got[0], np.float32), np.asarray(ref[0]), rtol=0.05, atol=0.05
         )
         np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=0.05)
-        # labels come from the f32 epilogue: near-exact (ties aside)
+        # labels are the kernel's bfloat16-scored argmin: the float32
+        # oracle's but for samples near a boundary
         assert (np.asarray(got[1]) == np.asarray(ref[1])).mean() > 0.97
 
     def test_bf16_labels_consistent_with_kernel_counts(self):
         # advisor r04#2: labels_ must agree with the assignment that produced
-        # cluster_centers_. The epilogue now scores in the STREAMED dtype
-        # (bf16 operands, f32 accumulation — the kernel's exact contraction
-        # class), so bincount(labels) must reproduce the kernel's counts.
+        # cluster_centers_. They are the kernel's own argmin row (the one its
+        # one-hot rows are built from), so bincount(labels) IS its counts.
         import jax.numpy as jnp
 
-        from heat_tpu.ops.lloyd import _assign_labels, _kernel_call
+        from heat_tpu.ops.lloyd import _kernel_call
 
         rng = np.random.default_rng(17)
         n, f, k = 4096, 16, 4
@@ -188,12 +266,11 @@ class TestFusedLloyd(TestCase):
             jnp.bfloat16
         )
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * 2)
-        _, counts, _ = _kernel_call(data, centers, k, jnp.asarray(n, jnp.int32), True)
-        labels = _assign_labels(data, centers)
+        _, counts, _, labels = _kernel_call(
+            data, centers, k, jnp.asarray(n, jnp.int32), True, emit_labels=True
+        )
         binc = np.bincount(np.asarray(labels), minlength=k).astype(np.float32)
-        # identical scoring dtype; only summation-order ulps can differ, so
-        # demand near-exact agreement (the old f32 epilogue sat near 0.97)
-        assert np.abs(binc - np.asarray(counts)[:, 0]).sum() <= n * 0.001
+        np.testing.assert_array_equal(binc, np.asarray(counts)[:, 0])
 
     def test_bf16_sharded_ragged_matches_oracle(self):
         # the harshest combination: bfloat16 stream x physical pad (ragged
@@ -221,6 +298,7 @@ class TestFusedLloyd(TestCase):
         )
         np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=0.05)
         assert got[1].shape[0] == n
+        assert (np.asarray(got[1]) == np.asarray(ref[1])).mean() > 0.9
 
     def test_kmeans_fit_keeps_bf16_stream(self):
         import jax.numpy as jnp

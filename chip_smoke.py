@@ -20,8 +20,8 @@ tiny on the CPU mesh (passing ``interpret=True``); ``main`` always runs the
 ``FULL`` sizes and always demands the chip.
 
 Size note: BASELINE.md's cdist config (100k x 64) writes a 40 GB result and
-does not fit one 16 GB chip; the width run here is bench.py's 32768 x 64
-(a 4.3 GB result).
+does not fit one 16 GB chip; the width run here is 32768 x 64 (a 4.3 GB
+result).
 """
 
 import functools
@@ -35,7 +35,7 @@ import warnings
 
 import numpy as np
 
-#: the widths bench.py and BASELINE.md track
+#: the widths BASELINE.md tracks (cdist cut to one chip: see above)
 FULL = {
     "kmeans": dict(n=10_000_000, f=16, k=8, iters=10),
     "cdist": dict(n=32768, f=64, block=256),

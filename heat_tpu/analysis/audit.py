@@ -350,11 +350,11 @@ def render_audit(findings: List[AuditFinding], audited: int) -> str:
 # cache warming: the bench-shaped workloads
 # ----------------------------------------------------------------------
 def warm_bench_cache(rounds: int = 2) -> int:
-    """Populate the sharded-program cache with the bench workloads' program
-    shapes (eager chain, moments, reduction chain — the same op families
-    bench.py measures), so a standalone ``python -m heat_tpu.analysis audit
-    --warm bench`` audits a representative cache. Returns the number of
-    cached programs afterwards. Deterministic data; a handful of dispatches."""
+    """Populate the sharded-program cache with bench-shaped program shapes
+    (eager chain, moments, reduction chain), so a standalone ``python -m
+    heat_tpu.analysis audit --warm bench`` audits a representative cache.
+    Returns the number of cached programs afterwards. Deterministic data; a
+    handful of dispatches."""
     import numpy as np
 
     import heat_tpu as ht

@@ -75,10 +75,6 @@ def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
     return new_centers, labels, inertia, shift
 
 
-_lloyd_step = partial(jax.jit, static_argnames=("k",))(_lloyd_iter)
-"""One Lloyd iteration (data (n, f) row-sharded, centers (k, f) replicated)."""
-
-
 def _no_phase(name: str) -> int:
     """``telemetry.Phases.phase`` of a fit that is not traced."""
     return 0

@@ -823,7 +823,7 @@ def _cache_small_programs() -> None:
 
 def use_entry_point_compile_cache() -> str:
     """Turn XLA's persistent compile cache on for an entry point
-    (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``,
+    (``chip_smoke.py``, ``chipbench/run.py``,
     ``scripts/multiproc_trainer.py``) and return the directory in use.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already keeps its cache
     there and nothing here sets another; otherwise the cache lives at

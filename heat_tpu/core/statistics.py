@@ -212,8 +212,8 @@ _ONEHOT_BINCOUNT_MAX = 1024
 def _fast_bincount(idx: jax.Array, length: int, weights: Optional[jax.Array] = None) -> jax.Array:
     """Counting core shared by bincount/histc/histogram.
 
-    XLA lowers ``.at[].add`` scatters on TPU to a slow sort-based expansion
-    (~17x slower than needed, measured on v5e); for a moderate number of bins
+    XLA lowers ``.at[].add`` scatters on TPU to a sort-based expansion (its
+    cost is not measured under the ledger); for a moderate number of bins
     the count is an MXU/VPU-shaped reduction instead: a one-hot compare that
     XLA fuses into the sum without materializing the (n, length) matrix.
     Falls back to the scatter path when bins are many or on CPU, where

@@ -13,8 +13,8 @@ pallas kernels where the schedule matters. Current contents:
   comparison against XLA's autofusion, which the default ``spatial.cdist``
   path uses).
 - :mod:`~heat_tpu.ops.lloyd` — single-pass fused Lloyd iteration for
-  k-means (single-device and shard_map forms; measured beside the jnp path
-  in ``bench.py``).
+  k-means (single-device and shard_map forms; the benchmark cell
+  ``kmeans_fit_1c`` measures it through ``KMeans.fit``).
 """
 
 from . import flash, lloyd, pairwise

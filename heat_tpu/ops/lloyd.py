@@ -19,13 +19,12 @@ TRANSPOSED operand ``xT (f, n)`` — features in sublanes, samples in lanes:
     counts += Σₗ onehot                  (k, 1)      accumulator
 
 Why transposed: TPU vector memory pads the MINOR axis to 128 lanes. In the
-natural (block, f) layout a narrow f (the benchmark's f=16) pads 8x — the
-kernel was measured on a real v5e moving ~5 GB per iteration against the
-jnp path's 1.3 GB, a 0.34x "speedup". With samples in lanes the minor axis
-is the long one (no padding, any f), the sublane axis is f (padded to 8),
-and every reduction in the kernel is lane-preserving. The one-time
-``transpose`` to (f, n) costs one data pass and is hoisted out of the
-iteration loop; per-iteration HBM traffic is n·f reads and nothing per-row
+natural (block, f) layout a narrow f (the benchmark's f=16) pads 8x, so the
+kernel would move eight times the bytes the rows hold. With samples in lanes
+the minor axis is the long one (no padding, any f), the sublane axis is f
+(padded to 8), and every reduction in the kernel is lane-preserving. The
+one-time ``transpose`` to (f, n) costs one data pass and is hoisted out of
+the iteration loop; per-iteration HBM traffic is n·f reads and nothing per-row
 written, except in the LAST pass of a program: that one stores the ``labels``
 row it already holds, a lane-dense (1, block) int32 block (4 bytes a sample
 beside the 4·f it reads). So a program's labels are the assignment against

@@ -218,7 +218,7 @@ PY
 rm -rf "$MP_SCRATCH"
 # serving leg (core/serving.py, ISSUE 15): the multi-tenant session layer —
 # the suite drives N=8 threaded clients through session isolation, admission
-# gates and cross-session batching (zero steady-state retraces, flat p99);
+# gates and cross-session batching (zero steady-state retraces);
 # then the persistent program cache's cross-process contract runs for real:
 # a COLD process populates HEAT_TPU_PROGRAM_CACHE_DIR, and a second WARM
 # process replaying the same chain must record ZERO compiles (disk warm
@@ -389,19 +389,6 @@ health_runtime.set_slo(dispatch_ms=None)
 print(f"autoscale leg: 0 interactive failures, {len(shed_hits)} batch "
       f"sheds, decisions={d}")
 PY
-# bench regression-sentinel smoke: the file-vs-file compare path (no jax,
-# no measurement) must accept a banked round artifact against itself —
-# exercises record loading, envelope unwrap and threshold plumbing
-echo "=== bench sentinel smoke (--against/--record) ==="
-SENTINEL_REC="$(mktemp)"
-cat > "$SENTINEL_REC" <<'JSON'
-{"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
- "parsed": {"metric": "kmeans_iters_per_sec_10Mx16_k8", "value": 10.0,
-            "platform": "tpu", "lloyd_tflops": 0.8, "flight_overhead_pct": 0.5,
-            "lint_findings": 0}}
-JSON
-python bench.py --against "$SENTINEL_REC" --record "$SENTINEL_REC"
-rm -f "$SENTINEL_REC"
 # static-analysis leg (heat_tpu/analysis): the AST lint must be clean
 # against the committed baseline (zero NEW findings — suppressions carry
 # their justifications inline), the AOT program auditor over a cache

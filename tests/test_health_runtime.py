@@ -18,7 +18,6 @@ per test and tearDown restores the ambient config).
 """
 
 import importlib
-import importlib.util
 import io
 import json
 import os
@@ -449,72 +448,6 @@ class TestHealthCLI(HealthCase):
         self.assertIn("sync", doc["health"])
         self.assertIn("watchdog", doc["health"])
         self.assertGreaterEqual(doc["health"]["sync"]["*"]["count"], 1)
-
-
-class TestBenchSentinel(unittest.TestCase):
-    """compare_records / --against: the noise-robust regression gate."""
-
-    @classmethod
-    def setUpClass(cls):
-        spec = importlib.util.spec_from_file_location(
-            "heat_bench_under_test", os.path.join(_REPO, "bench.py")
-        )
-        cls.bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(cls.bench)
-        cls.base = {
-            "metric": "kmeans_iters_per_sec", "value": 10.0, "platform": "tpu",
-            "lloyd_tflops": 0.8, "flight_overhead_pct": 0.5,
-            "telemetry_overhead_pct": 3.0, "lint_findings": 0,
-        }
-
-    def test_identical_records_pass(self):
-        verdict = self.bench.compare_records(dict(self.base), dict(self.base))
-        self.assertTrue(verdict["ok"], verdict)
-
-    def test_rate_regression_detected(self):
-        fresh = dict(self.base, lloyd_tflops=0.3)
-        verdict = self.bench.compare_records(fresh, dict(self.base))
-        self.assertFalse(verdict["ok"])
-        self.assertTrue(any("lloyd_tflops" in r for r in verdict["regressions"]))
-
-    def test_noise_within_slack_passes(self):
-        fresh = dict(self.base, lloyd_tflops=0.8 * 0.75, value=10.0 * 0.75)
-        verdict = self.bench.compare_records(fresh, dict(self.base))
-        self.assertTrue(verdict["ok"], verdict)
-
-    def test_overhead_ceiling_enforced_even_without_banked(self):
-        banked = {k: v for k, v in self.base.items() if k != "flight_overhead_pct"}
-        fresh = dict(self.base, flight_overhead_pct=7.5)
-        verdict = self.bench.compare_records(fresh, banked)
-        self.assertFalse(verdict["ok"])
-        self.assertTrue(any("flight_overhead_pct" in r for r in verdict["regressions"]))
-
-    def test_platform_mismatch_skips_rates(self):
-        fresh = dict(self.base, platform="cpu", lloyd_tflops=0.01, value=0.1)
-        verdict = self.bench.compare_records(fresh, dict(self.base))
-        self.assertTrue(verdict["ok"], verdict)
-        self.assertTrue(any("platform" in n for n in verdict["notes"]))
-
-    def test_monotone_counter_growth_regresses(self):
-        fresh = dict(self.base, lint_findings=2)
-        verdict = self.bench.compare_records(fresh, dict(self.base))
-        self.assertFalse(verdict["ok"])
-
-    def test_missing_keys_are_notes_not_failures(self):
-        fresh = {"metric": "kmeans_iters_per_sec", "value": 9.5, "platform": "tpu"}
-        verdict = self.bench.compare_records(fresh, dict(self.base))
-        self.assertTrue(verdict["ok"], verdict)
-        self.assertTrue(verdict["notes"])
-
-    def test_load_record_unwraps_round_artifact(self):
-        envelope = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
-                    "parsed": dict(self.base)}
-        with tempfile.TemporaryDirectory() as tmp:
-            path = os.path.join(tmp, "BENCH_envelope.json")
-            with open(path, "w") as fh:
-                json.dump(envelope, fh)
-            rec = self.bench._load_record(path)
-        self.assertEqual(rec, self.base)
 
 
 if __name__ == "__main__":

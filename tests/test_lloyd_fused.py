@@ -2,9 +2,8 @@
 (the last pass's own argmin row, ISSUE 30) included.
 
 Runs in pallas interpret mode on CPU (the same strategy as
-tests/test_ops_pallas.py); real-TPU timing lives in bench.py's primary
-kmeans metric (``lloyd_path: fused_pallas``) and its ``lloyd_fused_vs_jnp``
-margin field.
+tests/test_ops_pallas.py); real-TPU timing is the benchmark cell
+``kmeans_fit_1c`` (``BENCHMARK.json``, ``chipbench/run.py``).
 """
 
 import numpy as np

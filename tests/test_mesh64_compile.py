@@ -209,9 +209,8 @@ class TestMesh64Compile(unittest.TestCase):
             ("tri_solve", 6),
             ("det", 8),
             ("cholesky", 8),
-            # the weak-scaling attribution budgets (WEAK_SCALING_ATTRIBUTION
-            # _r05.json): a 10-iteration Lloyd program carries a constant
-            # handful of all-reduces, NOT 10x per-iteration growth
+            # a 10-iteration Lloyd program carries a constant handful of
+            # all-reduces, NOT 10x per-iteration growth
             ("lloyd10", 4),
             ("lasso_gram_pre", 2),
         ):

@@ -56,26 +56,38 @@ class MultihostCase(TestCase):
 
 
 class TestLeaseDaemon(MultihostCase):
+    """The daemon's own steps, driven by hand on an unstarted daemon:
+    ``_scan`` judges ``time.time()`` against each lease's mtime and the
+    daemon's ``_started_at``, so a test ages a peer with ``os.utime`` (or
+    the daemon by backdating ``_started_at``) instead of racing a 20 ms
+    thread on a shared CPU. The real thread is started once, by
+    ``test_heartbeat_fault_site_counts_missed_beats``, under a 10 s lease."""
+
+    LEASE_S = 30.0
+    AGED_S = 60.0
+
+    def _daemon(self, mesh, world):
+        return multihost._HeartbeatDaemon(
+            mesh, 0, world, 0, interval_s=1.0, lost_after_s=self.LEASE_S
+        )
+
+    def _beat_as(self, mesh, peer, age_s=0.0):
+        lease = multihost._lease_path(mesh, 0, peer)
+        multihost._write_atomic(lease, "{}")
+        stamp = time.time() - age_s
+        os.utime(lease, (stamp, stamp))
+
     def test_stale_peer_declared_lost_with_marker_and_event(self):
         with tempfile.TemporaryDirectory() as mesh:
+            daemon = self._daemon(mesh, world=2)
             # peer 1 beat once, long ago (backdated mtime = a dead process)
-            lease = multihost._lease_path(mesh, 0, 1)
-            os.makedirs(os.path.dirname(lease), exist_ok=True)
-            with open(lease, "w") as fh:
-                fh.write("{}")
-            past = time.time() - 60.0
-            os.utime(lease, (past, past))
-
+            self._beat_as(mesh, 1, age_s=self.AGED_S)
             with telemetry.enabled(2), warnings.catch_warnings():
                 warnings.simplefilter("ignore")
-                self.assertTrue(
-                    multihost.start_heartbeat(
-                        mesh=mesh, process=0, world=2, epoch=0,
-                        interval_ms=20.0, lost_ms=80.0,
-                    )
-                )
-                self.assertTrue(_wait_for(lambda: 1 in multihost.lost_peers()))
+                daemon._beat(1)
+                daemon._scan()
                 kinds = [e.get("kind") for e in telemetry.events()]
+            self.assertEqual(multihost.lost_peers(), frozenset({1}))
             self.assertIn("peer_lost", kinds)
             # the declaration is control flow at the next safe boundary...
             with self.assertRaises(multihost.PeerLostError) as ctx:
@@ -88,54 +100,50 @@ class TestLeaseDaemon(MultihostCase):
                 self.assertEqual(json.load(fh)["peer"], 1)
 
     def test_beating_peer_stays_live_and_silent_peer_gets_grace(self):
-        with tempfile.TemporaryDirectory() as mesh:
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")
-                multihost.start_heartbeat(
-                    mesh=mesh, process=0, world=3, epoch=0,
-                    interval_ms=20.0, lost_ms=150.0,
-                )
-                # peer 1 beats (we play it); peer 2 never starts
-                lease1 = multihost._lease_path(mesh, 0, 1)
-                os.makedirs(os.path.dirname(lease1), exist_ok=True)
-                deadline = time.monotonic() + 0.3
-                while time.monotonic() < deadline:
-                    multihost._write_atomic(lease1, "{}")
-                    time.sleep(0.02)
-                # a live peer is never declared inside its window...
-                self.assertNotIn(1, multihost.lost_peers())
-                # ...and the never-started peer is granted the same window
-                # from daemon start before being declared
-                self.assertTrue(_wait_for(lambda: 2 in multihost.lost_peers()))
-                self.assertNotIn(1, multihost.lost_peers())
-                # stop before peer 1's lease goes stale under OUR silence
-                multihost.stop_heartbeat()
+        with tempfile.TemporaryDirectory() as mesh, warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            daemon = self._daemon(mesh, world=3)
+            # peer 1 beats (we play it); peer 2 never starts
+            self._beat_as(mesh, 1)
+            daemon._scan()
+            # a never-started peer is granted the lease window from daemon
+            # start before being declared...
+            self.assertEqual(multihost.lost_peers(), frozenset())
+            daemon._started_at -= self.AGED_S
+            daemon._scan()
+            # ...and a live peer is never declared inside its own window
+            self.assertEqual(multihost.lost_peers(), frozenset({2}))
 
     def test_declaration_sticky_until_reset(self):
-        with tempfile.TemporaryDirectory() as mesh:
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")
-                multihost.start_heartbeat(
-                    mesh=mesh, process=0, world=2, epoch=0,
-                    interval_ms=20.0, lost_ms=60.0,
-                )
-                self.assertTrue(_wait_for(lambda: 1 in multihost.lost_peers()))
-                # a returning zombie belongs to a PREVIOUS world: fresh
-                # beats must not resurrect it inside this epoch
-                lease = multihost._lease_path(mesh, 0, 1)
-                multihost._write_atomic(lease, "{}")
-                time.sleep(0.1)
-                self.assertIn(1, multihost.lost_peers())
+        with tempfile.TemporaryDirectory() as mesh, warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            daemon = self._daemon(mesh, world=2)
+            daemon._started_at -= self.AGED_S
+            daemon._scan()
+            self.assertEqual(multihost.lost_peers(), frozenset({1}))
+            # a returning zombie belongs to a PREVIOUS world: fresh
+            # beats must not resurrect it inside this epoch
+            self._beat_as(mesh, 1)
+            daemon._scan()
+            self.assertEqual(multihost.lost_peers(), frozenset({1}))
             multihost.reset_peers()
             self.assertEqual(multihost.lost_peers(), frozenset())
+            # after the reset the peer is judged by its lease again
+            daemon._scan()
+            self.assertEqual(multihost.lost_peers(), frozenset())
+            self._beat_as(mesh, 1, age_s=self.AGED_S)
+            daemon._scan()
+            self.assertEqual(multihost.lost_peers(), frozenset({1}))
 
     def test_heartbeat_fault_site_counts_missed_beats(self):
         with tempfile.TemporaryDirectory() as mesh:
             before = multihost.report_stats()["heartbeat_errors"]
             with resilience.inject("multihost.heartbeat", times=3):
-                multihost.start_heartbeat(
-                    mesh=mesh, process=0, world=2, epoch=0,
-                    interval_ms=10.0, lost_ms=10_000.0,
+                self.assertTrue(
+                    multihost.start_heartbeat(
+                        mesh=mesh, process=0, world=2, epoch=0,
+                        interval_ms=10.0, lost_ms=10_000.0,
+                    )
                 )
                 self.assertTrue(
                     _wait_for(
@@ -340,18 +348,31 @@ class TestInitializeDistributed(MultihostCase):
 
 
 _STUB_WORKER = r"""
-import json, os, sys
+import fcntl, json, os, sys, time
 rank = int(os.environ["HEAT_TPU_PROCESS_ID"])
 epoch = int(os.environ["HEAT_TPU_MESH_EPOCH"])
 world = int(os.environ["HEAT_TPU_NUM_PROCESSES"])
 mesh = os.environ["HEAT_TPU_MESH_DIR"]
 out = os.environ["STUB_OUT"]
+casualty = epoch == 0 and world > 1 and rank == world - 1
+if casualty:
+    # held for life: the kernel drops it when this process is gone
+    life = open(os.path.join(out, "casualty.lock"), "w")
+    fcntl.flock(life, fcntl.LOCK_EX)
 with open(os.path.join(out, f"ran-{epoch}-{rank}"), "w") as fh:
     json.dump({"world": world, "epoch": epoch}, fh)
 if epoch == 0 and world > 1:
-    if rank == world - 1:
-        os._exit(9)  # the casualty
-    # survivors: play the lease daemon's detection, then drain for reform
+    if casualty:
+        os._exit(9)
+    # survivors: play the lease daemon's detection, then drain for reform.
+    # First wait for the casualty to be dead (its ran file says the lock is
+    # held; the lock falls with the process): the launcher SIGKILLs a marked
+    # peer that outlives its survivors, and its exit 9 would read -9. The
+    # launcher's generation timeout is the hang detector
+    while not os.path.exists(os.path.join(out, f"ran-0-{world - 1}")):
+        time.sleep(0.01)
+    with open(os.path.join(out, "casualty.lock")) as fh:
+        fcntl.flock(fh, fcntl.LOCK_SH)
     lost = os.path.join(mesh, "lost", f"epoch-{epoch:04d}")
     os.makedirs(lost, exist_ok=True)
     with open(os.path.join(lost, f"proc-{world - 1:05d}"), "w") as fh:

@@ -10,9 +10,9 @@ in-process and across two real subprocesses); the admission token bucket
 composes with memledger's headroom gate and the elastic ``admission_hold``
 (a refused chain stays pending, forces after release, and is never degraded
 or double-dispatched); and N=8 threaded synthetic clients on the warm mesh
-hold steady-state p99 dispatch latency within 2x of N=1 with zero
-steady-state retraces. Runs green at mesh 1/3/8, with fusion off (dispatch-
-seam tests skip), and under ``HEAT_TPU_FAULTS=ci`` (setUp suspends the
+complete every round with zero steady-state retraces (no latency is pinned
+on the CPU: a p99 is a chip number). Runs green at mesh 1/3/8, with fusion
+off (dispatch-seam tests skip), and under ``HEAT_TPU_FAULTS=ci`` (setUp suspends the
 ambient mix so exact counts stay exact).
 """
 
@@ -476,7 +476,7 @@ class TestAdmission(ServingCase):
         stalls only its own thread — a neighbour session's dispatches run
         to completion well inside the limited tenant's ~2s refill wait."""
         fast_done = threading.Event()
-        fast_elapsed = []
+        limited_still_waiting = []
         errors = []
 
         def limited():
@@ -493,10 +493,9 @@ class TestAdmission(ServingCase):
             try:
                 with serving.Session("neighbor"):
                     b = self._client_input(21)
-                    t0 = time.perf_counter()
                     for k in range(4, 9):
                         float(ht.sum(b * float(k)))
-                    fast_elapsed.append(time.perf_counter() - t0)
+                    limited_still_waiting.append(t1.is_alive())
             except Exception as exc:
                 errors.append(exc)
             finally:
@@ -507,12 +506,14 @@ class TestAdmission(ServingCase):
         t1.start()
         time.sleep(0.3)  # let the limited tenant reach its refill sleep
         t2.start()
-        self.assertTrue(fast_done.wait(timeout=10))
-        t1.join(timeout=15)
-        t2.join(timeout=15)
+        self.assertTrue(fast_done.wait(timeout=60))
+        t1.join(timeout=60)
+        t2.join(timeout=60)
         self.assertEqual(errors, [])
-        self.assertLess(
-            fast_elapsed[0], 1.5,
+        # convoyed, the neighbour's first dispatch would sit behind the force
+        # lock until the limited tenant's refill ended and it had dispatched
+        self.assertEqual(
+            limited_still_waiting, [True],
             "neighbour's dispatches convoyed behind the limited tenant's "
             "admission wait",
         )
@@ -754,7 +755,7 @@ class TestConcurrentRootRegistration(ServingCase):
 
 
 # ----------------------------------------------------------------------
-# N=8 synthetic clients: flat p99, zero steady-state retraces
+# N=8 synthetic clients: zero steady-state retraces
 # ----------------------------------------------------------------------
 class TestServingThroughput(ServingCase):
     ROUNDS = 40
@@ -766,21 +767,8 @@ class TestServingThroughput(ServingCase):
         # building the chain anywhere else yields a different signature.
         return ht.sum(arr * k + 1.0)
 
-    def _client_round(self, arr, k):
-        return float(self._client_chain(arr, k))
-
-    def _measure_single(self, rounds):
-        lats = []
-        with serving.Session("solo"):
-            arr = self._client_input(20)
-            for i in range(rounds):
-                t0 = time.perf_counter()
-                self._client_round(arr, 1.0 + i * 0.5)
-                lats.append(time.perf_counter() - t0)
-        return lats
-
     @pytest.mark.skipif(not fusion.active(), reason="fusion disabled")
-    def test_n8_p99_flat_and_zero_steady_state_retraces(self):
+    def test_n8_zero_steady_state_retraces(self):
         # pre-bake every batch-size signature 1..8: cross-session batching
         # groups k small identical-structure roots into one program whose
         # signature depends on k, so steady state must have them all cached
@@ -791,12 +779,9 @@ class TestServingThroughput(ServingCase):
             ]
             for o in outs:
                 float(o)
-        # N=1 steady state (warm cache)
-        self._measure_single(5)  # warm
-        p99_1 = float(np.percentile(self._measure_single(self.ROUNDS), 99))
         # N=8 concurrent sessions, one thread each
         barrier = threading.Barrier(8)
-        all_lats = [[] for _ in range(8)]
+        completed = [0] * 8
         errors = []
         compiles_before = fusion.cache_stats()["compiles"]
 
@@ -806,9 +791,8 @@ class TestServingThroughput(ServingCase):
                     arr = self._client_input(40 + idx)
                     barrier.wait(timeout=30)
                     for i in range(self.ROUNDS):
-                        t0 = time.perf_counter()
-                        self._client_round(arr, 1.0 + i * 0.25)
-                        all_lats[idx].append(time.perf_counter() - t0)
+                        float(self._client_chain(arr, 1.0 + i * 0.25))
+                        completed[idx] += 1
             except Exception as exc:
                 errors.append(exc)
 
@@ -816,29 +800,13 @@ class TestServingThroughput(ServingCase):
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=300)
         self.assertEqual(errors, [])
         retraces = fusion.cache_stats()["compiles"] - compiles_before
         self.assertEqual(retraces, 0, "steady-state traffic must not retrace")
-        merged = [v for lats in all_lats for v in lats]
-        self.assertEqual(len(merged), 8 * self.ROUNDS)
-        p99_8 = float(np.percentile(merged, 99))
-        # flat p99 under 8-way concurrency: within 2x of N=1, floored at 5ms.
-        # On this CPU host "device" execution runs on host threads under the
-        # GIL (default switch interval 5ms), so one batched dispatch plus one
-        # scheduler quantum is the irreducible tail; on real accelerators
-        # dispatch itself dwarfs the floor and the 2x ratio is what binds.
-        # The floor scales with thread overcommit: when 8 client threads
-        # share fewer cores, a root legitimately waits multiple scheduler
-        # quanta before its batch window even closes, so the one-quantum
-        # floor would flag the OS scheduler, not a convoy (observed p99
-        # ~14ms on a loaded 1-core host with healthy batching). On >= 8
-        # cores the factor is 1 and the pin is unchanged.
-        floor = 5e-3 * max(1.0, 8 / (os.cpu_count() or 1))
-        self.assertLessEqual(
-            p99_8, 2.0 * max(p99_1, floor),
-            f"p99 N=8 {p99_8 * 1e3:.3f}ms vs N=1 {p99_1 * 1e3:.3f}ms",
-        )
+        self.assertEqual(sum(completed), 8 * self.ROUNDS)
+        # no latency is asserted here: the p99 of a serving chain under load
+        # is `serve_bursty_1c`'s, on the chip, under a bound (ROADMAP B-II.4)
 
     @pytest.mark.skipif(not fusion.active(), reason="fusion disabled")
     def test_cross_session_batch_bills_each_tenant(self):

@@ -92,10 +92,9 @@ def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol
     2e-2); ``center_atol`` leaves that four times of room,
     ``inertia_rtol`` the objective. On more than one device it also
     establishes that the work is spread: one shard per device with equal
-    shapes, the ``sharded`` mode, an all-reduce in the Lloyd program, and
-    balanced per-device peak memory (where the backend reports it)."""
-    import jax
-
+    shapes, the ``sharded`` mode, an all-reduce and no temporary of the
+    rows' size in the compiled Lloyd program, and balanced per-device peak
+    memory (where the backend reports it)."""
     import heat_tpu as ht
     from heat_tpu.core import telemetry
     from heat_tpu.ops import lloyd
@@ -137,11 +136,16 @@ def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol
         run = lloyd._sharded_run_fn(
             comm.mesh, comm.axis_name, comm.size, k, n, min(8, iters), interpret
         )
-        hlo = run.lower(
-            x.parray, fused.cluster_centers_.larray, jax.numpy.float32(0.0)
-        ).compile().as_text()
-        out["lloyd_collectives"] = telemetry.hlo_collective_counts(hlo)
+        compiled = run.lower(x.parray, fused.cluster_centers_.larray).compile()
+        out["lloyd_collectives"] = telemetry.hlo_collective_counts(compiled.as_text())
         assert out["lloyd_collectives"].get("all-reduce", 0) >= 1, out
+        # the kernel reads each device's rows in place: a padded copy of them
+        # would be a temporary of at least their size (compiled for a described
+        # v5e:2x2 at 2.5M x 16 a device: 160.9 MB before PR 32, 0 since)
+        (rows_per_device, _), = out["shard_shapes"]
+        out["lloyd_temp_bytes"] = int(compiled.memory_analysis().temp_size_in_bytes)
+        if not interpret:  # the interpreter's emulation of the kernel holds copies of its own
+            assert out["lloyd_temp_bytes"] < rows_per_device * f * x.parray.dtype.itemsize, out
         stats = [d.memory_stats() for d in comm.devices]
         if all(s and "peak_bytes_in_use" in s for s in stats):
             peaks = [int(s["peak_bytes_in_use"]) for s in stats]

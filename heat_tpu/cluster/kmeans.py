@@ -93,6 +93,18 @@ class KMeans(_KCluster):
     ``True`` forces it (interpret mode off-TPU — the testing path), ``False``
     pins the jnp oracle path. A kernel that fails to lower or run raises:
     there is no fallback from the fused path to the oracle.
+
+    ``inertia_`` is the Σ d² of ``labels_`` to the centres that went into the
+    last iteration. The fused path takes it from that pass's float32
+    accumulators, ``Σ|x|² + Σ_k n_k·|c_k|² − 2 Σ_k c_k·s_k`` (``s_k`` the
+    cluster's sum of rows), and sums no per-sample score. For bfloat16 rows
+    it is therefore the distance to the centres as float32 holds them; before
+    PR 32 it added up the kernel's scores, whose ``−2c`` is rounded to
+    bfloat16, and read up to 7e-5 otherwise. On either path |x|² is added up
+    in float32, so ``inertia_`` is good to a few roundings of Σ|x|² (6e-8 of
+    it each): 2e-7 of itself on centred rows, 6e-5 where the rows lie 10
+    standard deviations off the origin, 4e-3 at 100 and nothing at 1 000
+    (``tests/test_lloyd_fused.py``); centre such rows before the fit.
     """
 
     def __init__(
@@ -151,11 +163,11 @@ class KMeans(_KCluster):
 
         While ``telemetry.tracing()`` the fit is a ``heat.kmeans.fit`` span
         (stats ``mode``, ``n``, ``f``, ``k``) whose children lie side by
-        side: ``.init`` (the initial centres), ``.prepare`` (dtype cast, the
-        samples-in-lanes transpose and Σ|x|² dispatch), ``.dispatch`` (each
-        Lloyd program's call), ``.sync`` (each blocking read of the shift,
-        and of the inertia), ``.wrap`` (centres and labels back into
-        ``DNDarray``s); the same intervals add to ``fusion.cache_stats()``'s
+        side: ``.init`` (the initial centres), ``.prepare`` (the dtype cast;
+        no pass over the rows: each fused program reads them in place),
+        ``.dispatch`` (each Lloyd program's call), ``.sync`` (each blocking
+        read of the shift, and of the inertia), ``.wrap`` (centres and labels
+        back into ``DNDarray``s); the same intervals add to ``fusion.cache_stats()``'s
         ``phase_kmeans_*`` keys."""
         if not isinstance(x, DNDarray):
             raise ValueError(f"input needs to be a DNDarray, but was {type(x)}")
@@ -201,13 +213,6 @@ class KMeans(_KCluster):
         else:
             data = x.larray.astype(ddtype)
         centers = jnp.asarray(centers, fdtype)
-        # the loop-invariant operands (the samples-in-lanes transpose and
-        # Σ|x|²) are full-data passes: computed ONCE here, not per chunk
-        xT = xsq = None
-        if mode == "single":
-            xT, xsq = _lloyd._prepare_run_operands(data, k)
-        elif mode == "sharded":
-            xsq = _lloyd._sharded_xsq(data, n_global=n_global)
 
         # iterations run in fused chunks of up to 8 per dispatch; convergence
         # is checked at chunk boundaries (coarser than the reference's
@@ -220,12 +225,11 @@ class KMeans(_KCluster):
             mark("dispatch")
             if mode == "single":
                 centers, labels, inertia, shift = _lloyd.fused_lloyd_run(
-                    data, centers, k, chunk, interpret=interpret, xT=xT, xsq_sum=xsq,
+                    data, centers, k, chunk, interpret=interpret
                 )
             elif mode == "sharded":
                 centers, labels, inertia, shift = _lloyd.fused_lloyd_run_sharded(
-                    data, centers, k, x.comm, n_global, chunk,
-                    interpret=interpret, xsq_sum=xsq,
+                    data, centers, k, x.comm, n_global, chunk, interpret=interpret
                 )
             else:
                 centers, labels, inertia, shift = _lloyd_run(data, centers, k, chunk)

@@ -19,7 +19,7 @@ pallas kernels where the schedule matters. Current contents:
 
 from . import flash, lloyd, pairwise
 from .flash import flash_attention_tpu
-from .lloyd import fused_lloyd_iter, fused_lloyd_iter_sharded, fused_lloyd_run
+from .lloyd import fused_lloyd_run, fused_lloyd_run_sharded
 from .pairwise import pairwise_distance
 
 __all__ = [
@@ -28,7 +28,6 @@ __all__ = [
     "pairwise",
     "pairwise_distance",
     "flash_attention_tpu",
-    "fused_lloyd_iter",
-    "fused_lloyd_iter_sharded",
     "fused_lloyd_run",
+    "fused_lloyd_run_sharded",
 ]

@@ -13,24 +13,32 @@ TRANSPOSED operand ``xT (f, n)`` — features in sublanes, samples in lanes:
 
     score   = |c|² − 2·c @ xb           (k, block)   MXU
     labels  = argmin₀(score)             (1, block)  sublane reduce
-    inertia += Σ min₀(score)             scalar accumulator
     onehot  = (labels == iota_k)         (k, block)  VMEM-only
     sumsᵀ  += xb ·ₗ onehot               (f, k)      MXU (lane contraction)
     counts += Σₗ onehot                  (k, 1)      accumulator
+    |x|²   += xb²                        (f, 128)    the LAST pass of a program only
 
 Why transposed: TPU vector memory pads the MINOR axis to 128 lanes. In the
 natural (block, f) layout a narrow f (the benchmark's f=16) pads 8x, so the
 kernel would move eight times the bytes the rows hold. With samples in lanes
 the minor axis is the long one (no padding, any f), the sublane axis is f
 (padded to 8), and every reduction in the kernel is lane-preserving. The
-one-time ``transpose`` to (f, n) costs one data pass and is hoisted out of
-the iteration loop; per-iteration HBM traffic is n·f reads and nothing per-row
-written, except in the LAST pass of a program: that one stores the ``labels``
-row it already holds, a lane-dense (1, block) int32 block (4 bytes a sample
-beside the 4·f it reads). So a program's labels are the assignment against
+kernel is the ONLY reader of the rows. The ``transpose`` to (f, n) sits in
+the program that holds the kernel, where it is a bitcast of the rows as
+XLA:TPU lays a narrow (n, f) array out (samples already in lanes); nothing
+pads the sample axis: the grid is ``cdiv(n, block)``, the last block ends
+inside the operand, and what its tail holds is masked like any column at or
+beyond ``n_valid``. Per-iteration HBM traffic is n·f reads and nothing
+per-row written, except in the LAST pass of a program: that one stores the
+``labels`` row it already holds, a lane-dense (1, block) int32 block (4 bytes
+a sample beside the 4·f it reads), and adds up the squares of the float32
+block it holds; with them the pass's inertia follows from its own sums and
+counts (:func:`_inertia`). So a program's labels are the assignment against
 the centers that went INTO its last iteration (the jnp path's exact label
-convention), and they are the very assignment that produced that iteration's
-sums, counts and inertia: no XLA pass over the rows computes labels.
+convention), they are the very assignment that produced that iteration's
+sums, counts and inertia, and no XLA pass over the rows computes labels,
+Σ|x|² or a padded copy. The passes before the last compute no inertia:
+nobody reads it.
 
 Precision follows the rows' dtype alone. The MXU multiplies bfloat16: left
 at the default, float32 operands are rounded to bfloat16 and multiplied in
@@ -49,14 +57,14 @@ This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here on
 TPU (``fused_supported`` / ``fused_sharded_supported``) and takes the jnp
 path (``cluster/kmeans.py:_lloyd_run``) for wider shapes or
 ``use_fused=False``; a kernel that fails to lower raises.
-:func:`fused_lloyd_iter` is
+:func:`fused_lloyd_run` is
 single-device (its pallas_call has no partitioning spec);
-:func:`fused_lloyd_iter_sharded` / :func:`fused_lloyd_run_sharded` are the
-multi-chip forms: a shard_map running the kernel per device and merging the
-(f, k)/(k, 1)/scalar accumulators with one psum per iteration — the exact
-collective budget of the jnp path. In the sharded run the whole fori_loop
-lives INSIDE the shard_map so the per-device transpose is paid once per
-program, not once per iteration.
+:func:`fused_lloyd_run_sharded` is the
+multi-chip form: a shard_map running the kernel per device and merging the
+(f, k)/(k, 1) accumulators with one psum per iteration (the last one's
+Σ|x|² with them) — the exact collective budget of the jnp path. In the
+sharded run the whole fori_loop lives INSIDE the shard_map. A program needs
+nothing from the one before it but the centres.
 """
 
 from __future__ import annotations
@@ -70,8 +78,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "fused_lloyd_iter",
-    "fused_lloyd_iter_sharded",
     "fused_lloyd_run",
     "fused_lloyd_run_sharded",
     "fused_sharded_supported",
@@ -159,6 +165,16 @@ def fused_sharded_supported(f: int, k: int) -> bool:
     return jax.default_backend() == "tpu" and f <= 512 and k <= 128
 
 
+def _fold_lanes(rows: jax.Array) -> jax.Array:
+    """(r, block) -> (r, 128): the lane tiles added up pairwise (a tree, so
+    no add waits on a chain of the block's 245 tiles)."""
+    parts = [rows[:, j : j + 128] for j in range(0, rows.shape[1], 128)]
+    while len(parts) > 1:
+        even = len(parts) // 2 * 2
+        parts = [a + b for a, b in zip(parts[0:even:2], parts[1:even:2])] + parts[even:]
+    return parts[0]
+
+
 def _lloyd_kernel(
     xT_ref,
     csq_ref,
@@ -166,20 +182,28 @@ def _lloyd_kernel(
     nvalid_ref,
     sums_ref,
     counts_ref,
-    inertia_ref,
+    xsq_ref=None,
     labels_ref=None,
     *,
     kp: int,
     block: int,
 ):
     """One (f, block) sample block; accumulators live across the whole grid.
-    Samples at column index >= nvalid (tail padding: ragged sizes, or a
-    device's share of the global padding under the sharded wrapper) are
-    masked out of every accumulator. n_valid is a runtime (1, 1) scalar
-    operand so each device can carry its own count. ``labels_ref``, where
-    the call has that output, takes the block's (1, block) argmin row as it
+    Samples at column index >= nvalid (the last block's tail beyond the
+    operand's end, or a device's share of the global padding under the sharded
+    wrapper) are masked out of every accumulator. n_valid is a runtime (1, 1)
+    scalar operand so each device can carry its own count.
+
+    ``xsq_ref`` and ``labels_ref`` are the two outputs of a program's last
+    pass alone. ``labels_ref`` takes the block's (1, block) argmin row as it
     is, unmasked: what it holds at columns >= nvalid is unspecified (the
     output ends at the operand's own length, beyond which nothing is kept).
+    ``xsq_ref`` is an (f, 128) accumulator of the squares of the float32
+    block in hand, one partial sum a feature and lane with no reduction
+    across sublanes: the caller folds it once into Σ|x|². (A (1, 1) scalar
+    would carry the 2^26-row cell's 1e9 through 2 140 sequential float32
+    adds; summing ``Σ_f x² + min₀(score)`` a sample, two sublane reductions
+    of the block, cost a pass 2.5 ms where this costs 1.0: PERF.md, PR 32.)
 
     ``kp`` is k padded to a sublane multiple: the centre rows beyond k are
     zero and carry ``csq = +inf``, so no sample is ever assigned to them.
@@ -199,8 +223,9 @@ def _lloyd_kernel(
     cols = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     valid = cols < nvalid_ref[0, 0]  # (1, block) bool
 
-    # Pad-region content is UNSPECIFIED (dndarray.parray contract) — inf/NaN
-    # there would poison the accumulators through 0·inf = NaN in the sums
+    # Content at columns >= nvalid is UNSPECIFIED (the dndarray.parray
+    # contract; the last block's tail beyond the operand) — inf/NaN there
+    # would poison the accumulators through 0·inf = NaN in the sums
     # contraction, so zero invalid samples rather than relying on
     # multiplicative masking downstream.
     xb = jnp.where(valid, xT_ref[:, :], 0)  # (f, block)
@@ -215,14 +240,13 @@ def _lloyd_kernel(
     kcol = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
     labels = jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)  # (1, block)
     onehot = jnp.logical_and(labels == kcol, valid).astype(jnp.bfloat16)  # (kp, block)
-    if labels_ref is not None:
-        labels_ref[:, :] = labels
 
     @pl.when(i == 0)
     def _init():
         sums_ref[:, :] = jnp.zeros_like(sums_ref)
         counts_ref[:, :] = jnp.zeros_like(counts_ref)
-        inertia_ref[:, :] = jnp.zeros_like(inertia_ref)
+        if xsq_ref is not None:
+            xsq_ref[:, :] = jnp.zeros_like(xsq_ref)
 
     # sums by piece, (kp, p·f): contract the lane (sample) axes of both
     # operands on the MXU — dot_general, so neither is transposed. The one-hot
@@ -236,17 +260,17 @@ def _lloyd_kernel(
     counts_ref[:, :] += jnp.sum(
         onehot, axis=1, keepdims=True, dtype=counts_ref.dtype
     )
-    # where, not multiply: even a finite-but-garbage pad score must not leak,
-    # and NaN·0 = NaN would defeat a multiplicative mask
-    min2d = jnp.min(score, axis=0, keepdims=True)  # (1, block)
-    masked_min = jnp.where(valid, min2d, 0.0)  # (1, block)
-    inertia_ref[:, :] += jnp.sum(masked_min, dtype=inertia_ref.dtype)[None, None]
+    if labels_ref is None:
+        return
+    labels_ref[:, :] = labels
+    x32 = xb.astype(jnp.float32)  # zero at invalid samples
+    xsq_ref[:, :] += _fold_lanes(x32 * x32)
 
 
-def _prepare(data: jax.Array, block: int) -> jax.Array:
-    """(n, f) -> (f, n_pad): transpose to samples-in-lanes and pad the
-    sample axis to a block multiple. One data pass; loop-invariant, so XLA
-    hoists it out of an enclosing fori_loop.
+def _prepare(data: jax.Array) -> jax.Array:
+    """(n, f) -> (f, n): the samples-in-lanes view the kernel streams. Inside
+    the program that holds the kernel it is a bitcast of float32 rows as
+    XLA:TPU lays them out, not a pass; nothing pads the sample axis.
 
     bfloat16 stays bfloat16 — the kernel's contractions accumulate in f32
     (``preferred_element_type``) while the streamed operand keeps half the
@@ -254,35 +278,22 @@ def _prepare(data: jax.Array, block: int) -> jax.Array:
     else (f64 included: Mosaic cannot lower it) is carried as f32 and
     multiplied in f32."""
     x = data if data.dtype == jnp.bfloat16 else data.astype(jnp.float32)
-    n = x.shape[0]
-    n_pad = -(-n // block) * block
-    xT = jnp.transpose(x)
-    if n_pad != n:
-        xT = jnp.pad(xT, ((0, 0), (0, n_pad - n)))
-    return xT
+    return jnp.transpose(x)
 
 
-def _prepare_for(data: jax.Array, k: int) -> jax.Array:
-    """:func:`_prepare` at the block the kernel takes for ``k`` clusters and
-    the dtype ``data`` is streamed in."""
-    itemsize = 2 if data.dtype == jnp.bfloat16 else 4
-    return _prepare(data, _block_cols(data.shape[1], k, itemsize))
-
-
-def _kernel_call_T(
-    xT, centers, k: int, n_valid, interpret: bool, n_labels: Optional[int] = None
-):
-    """Invoke the kernel on a prepared (f, n_pad) operand. Returns the
-    (sumsT (f, k), counts (k, 1), inertia (1, 1)) accumulators and, given
-    ``n_labels`` (a program's last pass, a kernel of its own name), the
-    (n_labels,) int32 assignment of the operand's first ``n_labels`` samples
-    against ``centers`` as a fourth. ``n_labels`` is the operand's length
-    before ``_prepare`` padded it: the output is exactly that long, Pallas
-    clips the last block's write to it, and nobody copies a padded row to
-    cut it (0.84 ms for 2^26 labels on a v5e: PERF.md, PR 30)."""
-    f, n_pad = xT.shape
+def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool, last: bool = False):
+    """Invoke the kernel on a samples-in-lanes (f, n) operand, read in place:
+    the grid is ``cdiv(n, block)`` and the last block ends inside the operand
+    (its tail is unspecified and masked, as every column >= ``n_valid``).
+    Returns the (sumsT (f, k), counts (k, 1)) accumulators and, from a
+    program's ``last`` pass (a kernel of its own name), Σ|x|² of the valid
+    samples (a scalar) and the (n,) int32 assignment of the operand's samples
+    against ``centers`` as a third and fourth. The labels output is exactly
+    n long: Pallas clips the last block's write to it, and
+    nobody copies a padded row to cut it (0.84 ms for 2^26 labels on a v5e:
+    PERF.md, PR 30)."""
+    f, n = xT.shape
     block = _block_cols(f, k, xT.dtype.itemsize)
-    assert n_pad % block == 0, (n_pad, block)
     kp = _pad8(k)
     c32 = centers.astype(jnp.float32)
     rows = ((0, kp - k), (0, 0))  # rows beyond k: zero centres under csq = +inf, never the argmin
@@ -303,17 +314,21 @@ def _kernel_call_T(
     out_shape = [
         jax.ShapeDtypeStruct((kp, p * f), jnp.float32),
         jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32),
     ]
-    out_specs = [whole((kp, p * f)), whole((kp, 1)), whole((1, 1))]
-    if n_labels is not None:
-        assert n_pad - block < n_labels <= n_pad, (n_labels, n_pad, block)
-        out_shape.append(jax.ShapeDtypeStruct((1, n_labels), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, block), lambda i: (0, i), memory_space=pltpu.VMEM))
-    sums, counts, inertia, *labels = pl.pallas_call(
+    out_specs = [whole((kp, p * f)), whole((kp, 1))]
+    if last:
+        out_shape += [
+            jax.ShapeDtypeStruct((f, 128), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+        ]
+        out_specs += [
+            whole((f, 128)),
+            pl.BlockSpec((1, block), lambda i: (0, i), memory_space=pltpu.VMEM),
+        ]
+    sums, counts, *rest = pl.pallas_call(
         functools.partial(_lloyd_kernel, kp=kp, block=block),
         out_shape=out_shape,
-        grid=(n_pad // block,),
+        grid=(pl.cdiv(n, block),),
         in_specs=[
             pl.BlockSpec((f, block), lambda i: (0, i), memory_space=pltpu.VMEM),
             whole((kp, 1)),
@@ -322,60 +337,19 @@ def _kernel_call_T(
         ],
         out_specs=out_specs,
         interpret=interpret,
-        name="lloyd_pass" if n_labels is None else "lloyd_pass_labels",
+        name="lloyd_pass_labels" if last else "lloyd_pass",
     )(xT, csq, cx, nv)
     sums = sum(sums[:k, g * f : (g + 1) * f] for g in range(p))  # fold x's pieces
-    return (sums.T, counts[:k], inertia, *(row[0] for row in labels))
+    if not last:
+        return sums.T, counts[:k]
+    xsq, labels = rest
+    return sums.T, counts[:k], jnp.sum(xsq), labels[0]
 
 
-def _kernel_call(data, centers, k: int, n_valid, interpret: bool, emit_labels: bool = False):
-    """Pad, transpose, and invoke the kernel on one device's rows — the
-    (n, f)-in convenience form (single calls and tests; iteration loops use
-    :func:`_prepare` + :func:`_kernel_call_T` so the transpose hoists).
-    ``emit_labels`` asks for the labels of the rows of ``data`` as well."""
-    xT = _prepare_for(data, k)
-    n_labels = data.shape[0] if emit_labels else None
-    return _kernel_call_T(xT, centers, k, n_valid, interpret, n_labels)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def fused_lloyd_iter(
-    data: jax.Array, centers: jax.Array, k: int, xsq_sum=None, interpret: bool = False
-):
-    """One Lloyd iteration in a single pass that writes its labels too.
-
-    Returns ``(new_centers, labels, inertia, shift)`` with the same contract
-    as ``cluster.kmeans._lloyd_iter`` (inertia includes the Σ|x|² term;
-    labels are the assignment against the INPUT centers).
-    ``xsq_sum`` is the loop-invariant Σ|x|²; pass it from outside an
-    iteration loop, or it is computed here (costing the one extra data read
-    the kernel exists to avoid).
-
-    Cost note (advisor r04#4): every call pays the samples-in-lanes copy,
-    the label store and, when ``xsq_sum`` is not supplied, the Σ|x|² pass, so
-    a Python loop over single calls moves the data ~3x per iteration.
-    Iteration loops should use :func:`fused_lloyd_run` (labels stored by the
-    last of N passes only) with :func:`prepare_run_operands` hoisting the
-    transpose/Σ|x|² across chunks — that combination is the advertised
-    one-read-per-iteration path.
-    """
-    n = data.shape[0]
-    sumsT, counts, inertia, labels = _kernel_call(
-        data, centers, k, jnp.asarray(n, jnp.int32), interpret, emit_labels=True
-    )
-    if xsq_sum is None:
-        x32 = data.astype(jnp.float32)
-        xsq_sum = jnp.sum(x32 * x32)
-    new_centers, inertia_full, shift = _finalize(
-        sumsT, counts, inertia, centers, xsq_sum
-    )
-    return new_centers, labels, inertia_full, shift
-
-
-def _finalize(sumsT, counts, inertia, centers, xsq_sum):
-    """Shared epilogue: centroid update (empty clusters keep their center),
-    inertia restoration (+Σ|x|²), and the convergence shift. One body for
-    the single-device and sharded paths so their numerics cannot drift."""
+def _finalize(sumsT, counts, centers):
+    """Shared epilogue: centroid update (empty clusters keep their center)
+    and the convergence shift. One body for the single-device and sharded
+    paths so their numerics cannot drift."""
     counts = counts[:, 0]  # (k,)
     sums = sumsT.T  # (k, f) — tiny
     new_centers = jnp.where(
@@ -383,160 +357,54 @@ def _finalize(sumsT, counts, inertia, centers, xsq_sum):
         sums / jnp.maximum(counts[:, None], 1.0),
         centers.astype(jnp.float32),
     ).astype(centers.dtype)
-    inertia_full = jnp.maximum(inertia[0, 0] + xsq_sum, 0.0)
     shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
-    return new_centers, inertia_full, shift
+    return new_centers, shift
 
 
-def prepare_run_operands(data: jax.Array, k: int):
-    """(xT, xsq_sum) for :func:`fused_lloyd_run` — callers driving MANY run
-    chunks over the same operand (KMeans.fit's convergence loop) compute
-    these ONCE and pass them in, instead of paying the transpose + Σ|x|²
-    data passes on every chunk."""
-    x32 = data.astype(jnp.float32)
-    return (
-        _prepare_for(data, k),
-        jnp.sum(x32 * x32),
-    )
+def _inertia(xsq_sum, sumsT, counts, centers):
+    """Σ d² of one assignment from that pass's own accumulators: every
+    sample's ``|x|² + min₀(score)``, the scores added up by cluster,
+    ``Σ|x|² + Σ_k n_k·|c_k|² − 2 Σ_k c_k·s_k`` (float32 products of k·f
+    numbers: no pass over the rows, and for bfloat16 rows the distance to the
+    unrounded centres). Good to a few roundings of Σ|x|² in float32, as the
+    jnp path's per-sample sum is: on rows far off the origin that is what
+    is left of it (``KMeans``' docstring)."""
+    c32 = centers.astype(jnp.float32)
+    scores = jnp.sum(counts[:, 0] * jnp.sum(c32 * c32, axis=1)) - 2.0 * jnp.sum(c32 * sumsT.T)
+    return jnp.maximum(xsq_sum + scores, 0.0)
 
 
-_prepare_run_operands = functools.partial(jax.jit, static_argnames="k")(
-    prepare_run_operands
-)
-
-
-def _steps(step, centers, n_steps: int):
-    """``n_steps`` Lloyd iterations by ``step(centers, emit_labels)`` (one
-    kernel pass and the centre update: ``(new_centers, inertia, shift)``,
-    with the pass's labels as a fourth when it emits them). The last step is
-    peeled off the loop: it is the one pass that writes labels. Returns
+def _steps(accumulate, centers, n_steps: int):
+    """``n_steps`` Lloyd iterations by ``accumulate(centers, last)`` (one
+    kernel pass, merged over devices where there are several:
+    ``(sumsT, counts)``, with the pass's Σ|x|² and labels behind them when it
+    is the ``last``). The last step is peeled off the loop: it is the one pass
+    that writes labels and whose inertia anybody reads. Returns
     ``(centers, labels, inertia, shift)``."""
-    acc = jnp.zeros((), jnp.float32)
-    centers, _, _ = jax.lax.fori_loop(
-        0, n_steps - 1, lambda i, carry: step(carry[0], False), (centers, acc, acc)
+    centers = jax.lax.fori_loop(
+        0, n_steps - 1, lambda i, c: _finalize(*accumulate(c, False), c)[0], centers
     )
-    centers, inertia, shift, labels = step(centers, True)
-    return centers, labels, inertia, shift
+    sumsT, counts, xsq_sum, labels = accumulate(centers, True)
+    new_centers, shift = _finalize(sumsT, counts, centers)
+    return new_centers, labels, _inertia(xsq_sum, sumsT, counts, centers), shift
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_steps", "interpret"))
 def fused_lloyd_run(
-    data: jax.Array,
-    centers: jax.Array,
-    k: int,
-    n_steps: int,
-    interpret: bool = False,
-    xT: Optional[jax.Array] = None,
-    xsq_sum: Optional[jax.Array] = None,
+    data: jax.Array, centers: jax.Array, k: int, n_steps: int, interpret: bool = False
 ):
     """``n_steps`` fused iterations in one XLA program (the pallas analog of
-    ``cluster.kmeans._lloyd_run``): Σ|x|² and the samples-in-lanes transpose
-    hoisted (within the program — pass ``xT``/``xsq_sum`` from
-    :func:`prepare_run_operands` to hoist them across chunked calls too),
-    one kernel pass per step, the last of which also writes its labels: the
-    assignment against the last iteration's input centers (the jnp oracle's
-    exact label convention), which that iteration's inertia is summed over."""
-    if xsq_sum is None:
-        x32 = data.astype(jnp.float32)
-        xsq_sum = jnp.sum(x32 * x32)
-    if xT is None:
-        xT = _prepare_for(data, k)
-    n = data.shape[0]
-    n_valid = jnp.asarray(n, jnp.int32)
+    ``cluster.kmeans._lloyd_run``) that reads ``data`` in place: one kernel
+    pass per step and no other pass over the rows. The last pass also writes
+    its labels: the assignment against the last iteration's input centers (the
+    jnp oracle's exact label convention), which the inertia belongs to."""
+    xT = _prepare(data)
+    n_valid = jnp.asarray(data.shape[0], jnp.int32)
 
-    def step(c, emit_labels):
-        sumsT, counts, inertia, *labels = _kernel_call_T(
-            xT, c, k, n_valid, interpret, n if emit_labels else None
-        )
-        return (*_finalize(sumsT, counts, inertia, c, xsq_sum), *labels)
+    def accumulate(c, last):
+        return _kernel_call_T(xT, c, k, n_valid, interpret, last)
 
-    return _steps(step, centers, n_steps)
-
-
-def fused_lloyd_iter_sharded(
-    data: jax.Array,
-    centers: jax.Array,
-    k: int,
-    comm,
-    n_global: int,
-    xsq_sum=None,
-    interpret: bool = False,
-):
-    """One fused Lloyd iteration over a row-sharded operand.
-
-    ``data`` is the PHYSICAL payload (``DNDarray.parray``): row count a
-    multiple of the mesh size, suffix-padded when the logical ``n_global``
-    is ragged. Each device runs the single-pass kernel on its own block —
-    masking its share of the global padding — and the (f, k)/(k, 1)/scalar
-    accumulators merge with one ``psum``. Labels are each device's kernel
-    output for its own rows (no collective), row-sharded like ``data`` and
-    sliced to the logical length ``n_global``.
-
-    Same return contract as :func:`fused_lloyd_iter`. The whole iteration is
-    jitted, cached per (mesh, k, shapes).
-    """
-    fn = _sharded_fn(comm.mesh, comm.axis_name, comm.size, k, int(n_global), bool(interpret))
-    return fn(data, centers, xsq_sum)
-
-
-def _sharded_iter_fn(mesh, axis, k, n_global, interpret):
-    """Traced (data, centers, xsq_sum) -> (new_centers, inertia, shift,
-    labels of the physical rows) over a row-sharded physical payload (single
-    iteration; the fused-run form keeps its loop inside the shard_map
-    instead — see _sharded_run_fn)."""
-    from jax.sharding import PartitionSpec as P
-
-    def device_step(xl, c):
-        local_rows = xl.shape[0]
-        idx = jax.lax.axis_index(axis)
-        local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
-        sums, counts, inertia, labels = _kernel_call(
-            xl, c, k, local_valid, interpret, emit_labels=True
-        )
-        sums = jax.lax.psum(sums, axis)
-        counts = jax.lax.psum(counts, axis)
-        inertia = jax.lax.psum(inertia, axis)
-        return sums, counts, inertia, labels
-
-    def step(data, centers, xsq_sum):
-        sums, counts, inertia, labels = jax.shard_map(
-            device_step,
-            mesh=mesh,
-            in_specs=(P(axis, None), P()),
-            out_specs=(P(), P(), P(), P(axis)),
-            check_vma=False,  # pallas_call outputs carry no vma annotation
-        )(data, centers)
-        return (*_finalize(sums, counts, inertia, centers, xsq_sum), labels)
-
-    return step
-
-
-def _logical_xsq_sum(data, n_global):
-    # Σ|x|² over the LOGICAL rows only: the physical pad region's content is
-    # unspecified (dndarray.parray contract) — never fold it into the inertia
-    x32 = data[:n_global].astype(jnp.float32)
-    return jnp.sum(x32 * x32)
-
-
-_sharded_xsq = functools.partial(jax.jit, static_argnames="n_global")(_logical_xsq_sum)
-"""Chunk-loop hoist of the sharded Σ|x|² (KMeans.fit computes it once)."""
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_fn(mesh, axis, p, k, n_global, interpret):
-    """Jitted sharded iteration, cached per static config (the
-    attention.py:_ring_attention_fn closure-cache pattern — comm objects are
-    unhashable, their mesh/axis are)."""
-    step = _sharded_iter_fn(mesh, axis, k, n_global, interpret)
-
-    @jax.jit
-    def run(data, centers, xsq_sum):
-        if xsq_sum is None:
-            xsq_sum = _logical_xsq_sum(data, n_global)
-        new_centers, inertia, shift, labels = step(data, centers, xsq_sum)
-        return new_centers, labels[:n_global], inertia, shift
-
-    return run
+    return _steps(accumulate, centers, n_steps)
 
 
 def fused_lloyd_run_sharded(
@@ -547,52 +415,56 @@ def fused_lloyd_run_sharded(
     n_global: int,
     n_steps: int,
     interpret: bool = False,
-    xsq_sum: Optional[jax.Array] = None,
 ):
     """``n_steps`` fused sharded iterations in ONE XLA program — the
-    multi-chip analog of :func:`fused_lloyd_run`: Σ|x|² hoisted once (pass
-    ``xsq_sum`` to hoist it across chunked calls too; the per-device
-    transpose lives inside the shard_map and is paid once per program), the
-    fori_loop of single-pass kernel steps INSIDE the shard_map, one psum
-    per step, and each device's last pass writing the labels of its rows."""
+    multi-chip analog of :func:`fused_lloyd_run`.
+
+    ``data`` is the PHYSICAL payload (``DNDarray.parray``): row count a
+    multiple of the mesh size, suffix-padded when the logical ``n_global``
+    is ragged. Each device runs the single-pass kernel on its own rows, read
+    in place — masking its share of the global padding — with the fori_loop
+    of kernel steps INSIDE the shard_map and one psum of the (f, k)/(k, 1)
+    accumulators per step (the last step's Σ|x|² beside them). Labels are
+    each device's last pass's output for its own rows (no collective),
+    row-sharded like ``data`` and sliced to the logical length ``n_global``.
+    Cached per (mesh, k, n_global, n_steps)."""
     fn = _sharded_run_fn(
         comm.mesh, comm.axis_name, comm.size, k, int(n_global), int(n_steps), bool(interpret)
     )
-    return fn(data, centers, xsq_sum)
+    return fn(data, centers)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_run_fn(mesh, axis, p, k, n_global, n_steps, interpret):
+    """Jitted sharded run, cached per static config (the
+    attention.py:_ring_attention_fn closure-cache pattern — comm objects are
+    unhashable, their mesh/axis are)."""
     from jax.sharding import PartitionSpec as P
 
-    def device_run(xl, c0, xsq_sum):
+    def device_run(xl, c0):
         local_rows = xl.shape[0]
         idx = jax.lax.axis_index(axis)
         local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
-        xT = _prepare_for(xl, k)  # once per program, per device
+        xT = _prepare(xl)
 
-        def step(c, emit_labels):
-            sumsT, counts, inertia, *labels = _kernel_call_T(
-                xT, c, k, local_valid, interpret, local_rows if emit_labels else None
-            )
-            sumsT = jax.lax.psum(sumsT, axis)
-            counts = jax.lax.psum(counts, axis)
-            inertia = jax.lax.psum(inertia, axis)
-            return (*_finalize(sumsT, counts, inertia, c, xsq_sum), *labels)
+        def accumulate(c, last):
+            sumsT, counts, *rest = _kernel_call_T(xT, c, k, local_valid, interpret, last)
+            if not last:
+                return jax.lax.psum((sumsT, counts), axis)
+            xsq_sum, labels = rest
+            return (*jax.lax.psum((sumsT, counts, xsq_sum), axis), labels)
 
-        return _steps(step, c0.astype(jnp.float32), n_steps)
+        return _steps(accumulate, c0.astype(jnp.float32), n_steps)
 
     @jax.jit
-    def run(data, centers, xsq_sum=None):
-        if xsq_sum is None:
-            xsq_sum = _logical_xsq_sum(data, n_global)
+    def run(data, centers):
         new_c, labels, inertia, shift = jax.shard_map(
             device_run,
             mesh=mesh,
-            in_specs=(P(axis, None), P(), P()),
+            in_specs=(P(axis, None), P()),
             out_specs=(P(), P(axis), P(), P()),
             check_vma=False,  # pallas_call outputs carry no vma annotation
-        )(data, centers, xsq_sum)
+        )(data, centers)
         return new_c.astype(centers.dtype), labels[:n_global], inertia, shift
 
     return run

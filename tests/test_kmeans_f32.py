@@ -17,8 +17,10 @@ formulations (quadratic expansion here, direct differences there) ties
 within rounding: about one fit in ten at this size has such a tie.
 """
 
+import collections
 import glob
 import os
+import re
 import tempfile
 import warnings
 
@@ -134,7 +136,7 @@ def _float32_products_only(eqn) -> bool:
 
 TRACED = {
     "fused_lloyd_run": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 2),
-    "fused_lloyd_iter": lambda x, c: lloyd.fused_lloyd_iter(x, c, K),
+    "fused_lloyd_run_of_one_step": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 1),
     "lloyd_iter": lambda x, c: _lloyd_iter(x, c, K),
 }
 
@@ -154,7 +156,7 @@ def test_no_contraction_on_float32_rows_is_left_at_the_default(name):
 def test_bfloat16_rows_keep_their_one_bfloat16_pass():
     x = jax.ShapeDtypeStruct((4096, F), jnp.bfloat16)
     c = jax.ShapeDtypeStruct((K, F), jnp.float32)
-    for name in ("fused_lloyd_run", "fused_lloyd_iter"):
+    for name in ("fused_lloyd_run", "fused_lloyd_run_of_one_step"):
         dots = _dots(jax.make_jaxpr(TRACED[name])(x, c).jaxpr, [])
         assert dots and all(v.aval.dtype == jnp.bfloat16 for eqn in dots for v in eqn.invars), name
         assert all(eqn.params["precision"] is None for eqn in dots), name
@@ -259,38 +261,113 @@ def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
 # what interpret mode cannot show (scoped VMEM, Mosaic's refusals). All in this
 # one file, behind one fixture (on-chip-measurement guide, section 2).
 @pytest.fixture(scope="module")
-def one_v5e():
+def v5e_2x2():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:  # no TPU compiler here, or its library is held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("emit_labels", [False, True], ids=["plain", "labels"])
-@pytest.mark.parametrize("dtype,f,k", [("float32", 16, 8), ("bfloat16", 16, 8), ("float32", 512, 128), ("float32", 20, 17)])
-def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k, emit_labels):
-    """The plain pass and the program's last one, which stores its (1, block)
-    labels row too (into an output that ends inside the last block, off a
-    lane-tile boundary), at the block ``_block_cols`` gives both."""
-    block = lloyd._block_cols(f, k, jnp.dtype(dtype).itemsize)
-    xT = jax.ShapeDtypeStruct((f, 4 * block), jnp.dtype(dtype), sharding=one_v5e)
-    c = jax.ShapeDtypeStruct((k, f), jnp.float32, sharding=one_v5e)
-    nv = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_v5e)
+@pytest.fixture(scope="module")
+def one_v5e(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2[0])
+
+
+def _compiled_text(fn, *shapes):
+    """``fn`` compiled for the described chip(s), as text. Such a compile cannot
+    be read back from the compilation cache without a chip, and the chip runs
+    with 64-bit mode off: under the suite's x64 ``jnp.argmin`` asks for an
+    int64 index, which Mosaic refuses (PERF.md, section 7)."""
     cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)  # such a compile cannot be read back without a chip
+    jax.config.update("jax_enable_compilation_cache", False)
     try:
-        # the chip runs with 64-bit mode off; under the suite's x64 ``jnp.argmin``
-        # asks for an int64 index, which Mosaic refuses (PERF.md, section 7)
         with jax.enable_x64(False):
-            n_labels = 4 * block - 77 if emit_labels else None
-            call = jax.jit(lambda xT, c, nv: lloyd._kernel_call_T(xT, c, k, nv, False, n_labels))
-            text = call.lower(xT, c, nv).compile().as_text()
+            return fn.lower(*shapes).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
-    assert "lloyd_pass" in text and "tpu_custom_call" in text
-    assert ("lloyd_pass_labels" in text) == emit_labels
+
+
+@pytest.mark.parametrize("last", [False, True], ids=["plain", "labels"])
+@pytest.mark.parametrize(
+    "dtype,f,k,n",
+    [("float32", 16, 8, None), ("bfloat16", 16, 8, None), ("float32", 512, 128, None), ("float32", 20, 17, None),
+     ("float32", 16, 8, 1000)],
+)
+def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k, n, last):
+    """The plain pass and the program's last one, which also stores its
+    (1, block) labels row and adds up the squares of the float32 block it
+    holds (an (f, 128) accumulator more), at the block ``_block_cols`` gives both, on an operand that ends
+    inside its last block, off a lane-tile boundary (as the labels do); and
+    on fewer rows than one block (ISSUE 32), where the (f, block) input block
+    and the (1, block) labels block are WIDER than their arrays: interpret
+    mode cannot show Mosaic refusing that."""
+    block = lloyd._block_cols(f, k, jnp.dtype(dtype).itemsize)
+    assert n is None or n < block
+    xT = jax.ShapeDtypeStruct((f, n or 4 * block - 77), jnp.dtype(dtype), sharding=one_v5e)
+    c = jax.ShapeDtypeStruct((k, f), jnp.float32, sharding=one_v5e)
+    nv = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_v5e)
+    call = jax.jit(lambda xT, c, nv: lloyd._kernel_call_T(xT, c, k, nv, False, last))
+    text = _compiled_text(call, xT, c, nv)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert ("lloyd_pass_labels" in text) == last and "lloyd_pass" in text
+
+
+def _opcodes_on_rows(text, dtype, n, f):
+    """The opcodes, with their counts, of the compiled instructions that give
+    an array of the rows' type ((n, f) or (f, n) of ``dtype``, alone or in a
+    tuple) or take the result of one that does."""
+    rows = re.compile(rf"\b{dtype}\[(?:{n},{f}|{f},{n})\]")
+    instructions = []
+    for line in text.splitlines():
+        for _ in range(3):  # layouts, attributes: nothing in braces is a shape to read here
+            line = re.sub(r"\{[^{}]*\}", "", line)
+        name, equals, instruction = line.partition(" = ")
+        opcode = re.search(r"([a-z][\w-]*)\(", instruction)
+        if equals and opcode:
+            gives = bool(rows.search(instruction[: opcode.start()]))
+            takes = set(re.findall(r"%([\w.-]+)", instruction[opcode.end():]))
+            instructions.append((name.split("%")[-1].strip(), opcode.group(1), gives, takes))
+    holders = {name for name, _, gives, _ in instructions if gives}
+    return collections.Counter(
+        opcode for _, opcode, gives, takes in instructions if gives or takes & holders
+    )
+
+
+ROWS_PLUMBING = {"parameter", "bitcast", "tuple", "get-tuple-element", "while", "custom-call"}
+
+
+@pytest.mark.parametrize("dtype,short", [("float32", "f32"), ("bfloat16", "bf16")])
+def test_nothing_but_the_kernel_reads_the_rows_of_a_run(one_v5e, dtype, short):
+    """ISSUE 32: in the compiled ``fused_lloyd_run`` at a ragged n, the rows
+    parameter meets a ``bitcast`` (the samples-in-lanes view) and the two
+    kernels, through the loop's tuple: no ``pad``, ``copy``, ``transpose``,
+    ``convert`` or fusion takes or gives an array of the rows' size."""
+    n = 3 * lloyd._block_cols(F, K, jnp.dtype(dtype).itemsize) - 77
+    x = jax.ShapeDtypeStruct((n, F), jnp.dtype(dtype), sharding=one_v5e)
+    c = jax.ShapeDtypeStruct((K, F), jnp.float32, sharding=one_v5e)
+    text = _compiled_text(jax.jit(lambda x, c: lloyd.fused_lloyd_run(x, c, K, 3)), x, c)
+    opcodes = _opcodes_on_rows(text, short, n, F)
+    assert set(opcodes) <= ROWS_PLUMBING and opcodes["bitcast"] == 1 and opcodes["custom-call"] == 2, opcodes
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_nothing_but_the_kernel_reads_the_rows_of_a_sharded_run(v5e_2x2):
+    """The same inside the sharded program's ``shard_map`` on the 2 x 2 mesh:
+    per device a ``bitcast`` and the two kernels, on a payload that is ragged
+    against the block and against the mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(v5e_2x2), ("x",))
+    local = 2 * lloyd._block_cols(F, K) + 77
+    x = jax.ShapeDtypeStruct((4 * local, F), jnp.float32, sharding=NamedSharding(mesh, P("x", None)))
+    c = jax.ShapeDtypeStruct((K, F), jnp.float32, sharding=NamedSharding(mesh, P()))
+    run = lloyd._sharded_run_fn(mesh, "x", 4, K, 4 * local - 3, 3, False)
+    text = _compiled_text(run, x, c)
+    opcodes = _opcodes_on_rows(text, "f32", local, F)
+    assert set(opcodes) <= ROWS_PLUMBING and opcodes["bitcast"] == 1 and opcodes["custom-call"] == 2, opcodes
+    assert "all-reduce" in text

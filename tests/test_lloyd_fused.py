@@ -1,5 +1,6 @@
 """Fused pallas Lloyd kernel vs the jnp reference implementation, its labels
-(the last pass's own argmin row, ISSUE 30) included.
+(the last pass's own argmin row, ISSUE 30) and the rows read in place, with
+the inertia from that same pass's accumulators (ISSUE 32), included.
 
 Runs in pallas interpret mode on CPU (the same strategy as
 tests/test_ops_pallas.py); real-TPU timing is the benchmark cell
@@ -56,17 +57,17 @@ def test_labels_bincount_is_the_kernels_counts_with_garbage_in_the_pad(dtype):
     import jax
     import jax.numpy as jnp
 
-    from heat_tpu.ops.lloyd import _kernel_call
+    from heat_tpu.ops.lloyd import _kernel_call_T, _prepare
 
     n, f, k = 3000, 16, 6
     data_np, centers = _rows(23, n, f, k)
     poisoned = np.concatenate(
         [data_np, np.full((40, f), np.inf, np.float32), np.full((8, f), np.nan, np.float32)]
     )
-    _, counts, inertia, labels = jax.jit(
-        lambda d, c: _kernel_call(d, c, k, jnp.asarray(n, jnp.int32), True, emit_labels=True)
+    _, counts, xsq_sum, labels = jax.jit(
+        lambda d, c: _kernel_call_T(_prepare(d), c, k, jnp.asarray(n, jnp.int32), True, last=True)
     )(jnp.asarray(poisoned).astype(dtype), centers)
-    assert labels.shape == (n + 48,) and np.isfinite(float(inertia[0, 0]))  # one per row handed over
+    assert labels.shape == (n + 48,) and np.isfinite(float(xsq_sum))  # one per row handed over
     valid = np.asarray(labels)[:n]
     assert valid.min() >= 0 and valid.max() < k
     np.testing.assert_array_equal(np.bincount(valid, minlength=k), np.asarray(counts)[:, 0])
@@ -77,16 +78,93 @@ def test_bfloat16_labels_are_the_argmin_of_the_streamed_scores():
     unrounded centres: the labels are that argmin, not the float32 one."""
     import jax.numpy as jnp
 
-    from heat_tpu.ops.lloyd import fused_lloyd_iter
+    from heat_tpu.ops.lloyd import fused_lloyd_run
 
     n, f, k = 4096, 16, 4
     data_np, centers = _rows(11, n, f, k)
     low = jnp.asarray(data_np).astype(jnp.bfloat16)
-    got = np.asarray(fused_lloyd_iter(low, centers, k, interpret=True)[1])
+    got = np.asarray(fused_lloyd_run(low, centers, k, 1, interpret=True)[1])
     c64 = np.asarray(centers, np.float64)
     cq = np.asarray((-2.0 * centers).astype(jnp.bfloat16).astype(jnp.float32), np.float64)
     score = (c64 * c64).sum(axis=1)[None, :] + np.asarray(low.astype(jnp.float32), np.float64) @ cq.T
     np.testing.assert_array_equal(got, score.argmin(axis=1))
+
+
+_F, _K = 8, 5  # narrow rows: the kernel's largest blocks, 44 928 samples
+
+
+@pytest.mark.parametrize("mode", ["single", "sharded"])
+@pytest.mark.parametrize("blocks,over", [(1, -1), (1, 0), (1, 1), (3, -77)])
+def test_rows_are_read_in_place_whatever_their_number(blocks, over, mode):
+    """Nothing pads the sample axis (ISSUE 32): the grid is ``cdiv(n, block)``
+    and the last block ends inside the operand, one sample short of a block,
+    whole, one sample over, and ragged after three. In sharded mode each
+    device's rows end inside its one block and the physical payload's tail,
+    whose content is unspecified, holds NaN. Against the jnp program: centres
+    to 1e-5, labels identical, inertia to 1e-5, and the inertia is that of the
+    LAST assignment step: the labels' own squared distances to the centres that
+    went into it."""
+    import jax
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+    from heat_tpu.cluster.kmeans import _lloyd_run
+    from heat_tpu.ops.lloyd import _block_cols, fused_lloyd_run, fused_lloyd_run_sharded
+
+    n, n_steps = blocks * _block_cols(_F, _K) + over, 3
+    data_np, centers = _rows(32, n, _F, _K)
+    if mode == "single":
+        got = fused_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps, interpret=True)
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        comm = ht.get_comm()
+        physical = np.full((-(-n // comm.size) * comm.size, _F), np.nan, np.float32)
+        physical[:n] = data_np
+        payload = jax.device_put(physical, NamedSharding(comm.mesh, P(comm.axis_name, None)))
+        got = fused_lloyd_run_sharded(payload, centers, _K, comm, n, n_steps, interpret=True)
+    ref = _lloyd_run(jnp.asarray(data_np), centers, _K, n_steps)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
+    assert got[1].shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=1e-5)
+    before = np.asarray(_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps - 1)[0], np.float64)
+    last = ((data_np.astype(np.float64) - before[np.asarray(got[1])]) ** 2).sum()
+    np.testing.assert_allclose(float(got[2]), last, rtol=1e-5)
+
+
+@pytest.mark.parametrize("offset", [0.0, 10.0, 100.0])
+def test_inertia_from_the_accumulators_is_the_per_sample_sum_on_uncentred_rows(offset):
+    """``_inertia`` takes Σ d² from the last pass's Σ|x|², sums and counts, and
+    sums no per-sample distance. Where the rows lie ``offset`` standard
+    deviations off the origin, Σ|x|² is (1 + offset²) times the Σ d² it is
+    cancelled down to: the worst case of the form. Against the float64 sum of
+    the labels' own squared distances to the centres that went into the last
+    step, it is held to 16 roundings of Σ|x|² in float32 (read: at most 7.9
+    over four seeds, three sizes and offsets to 1 000; the jnp program's
+    per-sample ``Σ min(score) + Σ|x|²`` reads at most 3.7 and is held to the
+    same: both forms add |x|² up in float32, and neither is of use at an
+    offset of 1 000)."""
+    import jax.numpy as jnp
+
+    from heat_tpu.cluster.kmeans import _lloyd_run
+    from heat_tpu.ops.lloyd import _block_cols, fused_lloyd_run
+
+    n, n_steps = _block_cols(_F, _K) + 1, 3
+    rng = np.random.default_rng(41)
+    data_np = (rng.standard_normal((n, _F)) + offset).astype(np.float32)
+    data = jnp.asarray(data_np)
+    centers = jnp.asarray(data_np[rng.choice(n, _K, replace=False)])
+    x64 = data_np.astype(np.float64)
+    floor = 2.0**-24 * (x64**2).sum()  # one rounding of Σ|x|²
+    for run in (lambda s: fused_lloyd_run(data, centers, _K, s, interpret=True),
+                lambda s: _lloyd_run(data, centers, _K, s)):
+        _, labels, inertia, _ = run(n_steps)
+        before = np.asarray(run(n_steps - 1)[0], np.float64)
+        per_sample = ((x64 - before[np.asarray(labels)]) ** 2).sum()
+        assert abs(float(inertia) - per_sample) <= 16 * floor, (float(inertia), per_sample, floor)
+        if offset == 0.0:
+            np.testing.assert_allclose(float(inertia), per_sample, rtol=1e-6)
 
 
 class TestFusedLloyd(TestCase):
@@ -95,7 +173,7 @@ class TestFusedLloyd(TestCase):
         import jax.numpy as jnp
 
         from heat_tpu.cluster.kmeans import _lloyd_iter
-        from heat_tpu.ops.lloyd import fused_lloyd_iter
+        from heat_tpu.ops.lloyd import fused_lloyd_run
 
         rng = np.random.default_rng(seed)
         data = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
@@ -104,8 +182,8 @@ class TestFusedLloyd(TestCase):
         ref_c, ref_lab, ref_inertia, ref_shift = jax.jit(
             _lloyd_iter, static_argnames="k"
         )(data, centers, k)
-        got_c, got_lab, got_inertia, got_shift = fused_lloyd_iter(
-            data, centers, k, interpret=True
+        got_c, got_lab, got_inertia, got_shift = fused_lloyd_run(
+            data, centers, k, 1, interpret=True
         )
 
         np.testing.assert_array_equal(np.asarray(got_lab), np.asarray(ref_lab))
@@ -144,11 +222,11 @@ class TestFusedLloyd(TestCase):
     def test_empty_cluster_keeps_center(self):
         import jax.numpy as jnp
 
-        from heat_tpu.ops.lloyd import fused_lloyd_iter
+        from heat_tpu.ops.lloyd import fused_lloyd_run
 
         data = jnp.asarray(np.zeros((128, 2), np.float32))
         centers = jnp.asarray(np.array([[0.0, 0.0], [100.0, 100.0]], np.float32))
-        new_c, labels, _, _ = fused_lloyd_iter(data, centers, 2, interpret=True)
+        new_c, labels, _, _ = fused_lloyd_run(data, centers, 2, 1, interpret=True)
         assert (np.asarray(labels) == 0).all()
         np.testing.assert_array_equal(np.asarray(new_c)[1], centers[1])  # empty keeps old
 
@@ -158,7 +236,7 @@ class TestFusedLloyd(TestCase):
 
         import heat_tpu as ht
         from heat_tpu.cluster.kmeans import _lloyd_iter
-        from heat_tpu.ops.lloyd import fused_lloyd_iter_sharded
+        from heat_tpu.ops.lloyd import fused_lloyd_run_sharded
 
         comm = ht.get_comm()
         rng = np.random.default_rng(7)
@@ -167,8 +245,8 @@ class TestFusedLloyd(TestCase):
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * 2)
 
         x = ht.array(data_np, split=0)  # physical payload padded to p blocks
-        got_c, got_lab, got_inertia, got_shift = fused_lloyd_iter_sharded(
-            x.parray, centers, k, comm, n_global=n, interpret=True
+        got_c, got_lab, got_inertia, got_shift = fused_lloyd_run_sharded(
+            x.parray, centers, k, comm, n, 1, interpret=True
         )
         ref_c, ref_lab, ref_inertia, ref_shift = jax.jit(
             _lloyd_iter, static_argnames="k"
@@ -199,13 +277,13 @@ class TestFusedLloyd(TestCase):
         poisoned = np.concatenate(
             [data_np, np.full((24, f), np.inf, np.float32), np.full((8, f), np.nan, np.float32)]
         )
-        from heat_tpu.ops.lloyd import _kernel_call
+        from heat_tpu.ops.lloyd import _inertia, _kernel_call_T, _prepare
 
-        sumsT, counts, inertia = jax.jit(
-            lambda d, c: _kernel_call(d, c, k, jnp.asarray(n, jnp.int32), True)
+        sumsT, counts, xsq_sum, _ = jax.jit(
+            lambda d, c: _kernel_call_T(_prepare(d), c, k, jnp.asarray(n, jnp.int32), True, last=True)
         )(jnp.asarray(poisoned), centers)
         assert np.isfinite(np.asarray(sumsT)).all()
-        assert np.isfinite(float(inertia[0, 0]))
+        np.testing.assert_allclose(float(xsq_sum), (data_np.astype(np.float64) ** 2).sum(), rtol=1e-6)
 
         # the accumulator VALUES must equal the clean oracle's — finiteness
         # alone would admit a finite-but-garbage pad score leaking through
@@ -219,11 +297,9 @@ class TestFusedLloyd(TestCase):
         np.testing.assert_allclose(
             np.asarray(sumsT), (onehot.T @ data_np).T, rtol=1e-5, atol=1e-4
         )
-        # kernel inertia omits the Σ|x|² term the full contract restores
+        # the last pass's Σ|x|², sums and counts give the Σ d² of the valid rows alone
         np.testing.assert_allclose(
-            float(inertia[0, 0]) + float(np.sum(data_np.astype(np.float64) ** 2)),
-            float(ref_inertia),
-            rtol=1e-4,
+            float(_inertia(xsq_sum, sumsT, counts, centers)), float(ref_inertia), rtol=1e-5
         )
 
     def test_bf16_stream_matches_f32_oracle_loosely(self):
@@ -257,7 +333,7 @@ class TestFusedLloyd(TestCase):
         # one-hot rows are built from), so bincount(labels) IS its counts.
         import jax.numpy as jnp
 
-        from heat_tpu.ops.lloyd import _kernel_call
+        from heat_tpu.ops.lloyd import _kernel_call_T, _prepare
 
         rng = np.random.default_rng(17)
         n, f, k = 4096, 16, 4
@@ -265,8 +341,8 @@ class TestFusedLloyd(TestCase):
             jnp.bfloat16
         )
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * 2)
-        _, counts, _, labels = _kernel_call(
-            data, centers, k, jnp.asarray(n, jnp.int32), True, emit_labels=True
+        _, counts, _, labels = _kernel_call_T(
+            _prepare(data), centers, k, jnp.asarray(n, jnp.int32), True, last=True
         )
         binc = np.bincount(np.asarray(labels), minlength=k).astype(np.float32)
         np.testing.assert_array_equal(binc, np.asarray(counts)[:, 0])
@@ -280,7 +356,7 @@ class TestFusedLloyd(TestCase):
 
         import heat_tpu as ht
         from heat_tpu.cluster.kmeans import _lloyd_iter
-        from heat_tpu.ops.lloyd import fused_lloyd_iter_sharded
+        from heat_tpu.ops.lloyd import fused_lloyd_run_sharded
 
         comm = ht.get_comm()
         rng = np.random.default_rng(13)
@@ -288,9 +364,7 @@ class TestFusedLloyd(TestCase):
         data_np = rng.standard_normal((n, f)).astype(np.float32)
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32))
         x = ht.array(data_np, split=0).astype(ht.bfloat16)
-        got = fused_lloyd_iter_sharded(
-            x.parray, centers, k, comm, n_global=n, interpret=True
-        )
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, interpret=True)
         ref = jax.jit(_lloyd_iter, static_argnames="k")(jnp.asarray(data_np), centers, k)
         np.testing.assert_allclose(
             np.asarray(got[0], np.float32), np.asarray(ref[0]), rtol=0.05, atol=0.05
@@ -329,24 +403,12 @@ class TestFusedLloyd(TestCase):
                 live_bytes = 4 * blk * (2 * fp + 3 * kp + 8)
                 assert live_bytes <= (12 << 20) or blk == 1024
 
-    def test_prepare_transposes_and_pads(self):
-        import jax.numpy as jnp
-
-        from heat_tpu.ops.lloyd import _block_cols, _prepare
-
-        x = jnp.arange(10 * 3, dtype=jnp.float32).reshape(10, 3)
-        block = _block_cols(3, 2)
-        xT = _prepare(x, block)
-        assert xT.shape[0] == 3 and xT.shape[1] % block == 0
-        np.testing.assert_array_equal(np.asarray(xT[:, :10]), np.asarray(x).T)
-        np.testing.assert_array_equal(np.asarray(xT[:, 10:]), 0)
-
     def test_sharded_wrapper_divisible(self):
         import jax.numpy as jnp
 
         import heat_tpu as ht
         from heat_tpu.cluster.kmeans import _lloyd_iter
-        from heat_tpu.ops.lloyd import fused_lloyd_iter_sharded
+        from heat_tpu.ops.lloyd import fused_lloyd_run_sharded
 
         comm = ht.get_comm()
         rng = np.random.default_rng(8)
@@ -354,7 +416,7 @@ class TestFusedLloyd(TestCase):
         data_np = rng.standard_normal((n, f)).astype(np.float32)
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32))
         x = ht.array(data_np, split=0)
-        got = fused_lloyd_iter_sharded(x.parray, centers, k, comm, n_global=n, interpret=True)
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, interpret=True)
         ref = _lloyd_iter(jnp.asarray(data_np), centers, k)
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))

@@ -19,9 +19,11 @@ The check functions take sizes so ``tests/test_chip_smoke.py`` can call them
 tiny on the CPU mesh (passing ``interpret=True``); ``main`` always runs the
 ``FULL`` sizes and always demands the chip.
 
-Size note: BASELINE.md's cdist config (100k x 64) writes a 40 GB result and
-does not fit one 16 GB chip; the width run here is 32768 x 64 (a 4.3 GB
-result).
+Size note: BASELINE.md's cdist config (100k x 64) writes a 40 GB result:
+no single 16 GB chip holds it, four hold 10.0 GB each, and that size runs on
+four chips as the benchmark cell ``cdist_ring_4c`` (50 000 x 64, the same
+10.0 GB, on one as ``cdist_50k_1c``). The width run here, on however many
+chips there are, is 32768 x 64 (a 4.3 GB result).
 """
 
 import functools
@@ -154,11 +156,14 @@ def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol
     return out
 
 
-def check_cdist(n, f, block, atol=5e-2):
+def check_cdist(n, f, block, atol=1e-4):
     """``spatial.cdist`` (quadratic expansion) against numpy float64 on an
-    off-diagonal (block, block) sample. ``atol``: the expansion's
-    ``x @ yᵀ`` runs at the MXU's default f32 precision, an absolute error
-    of ~|x||y|·2⁻⁸ on d² — about 1e-2 on distances of ~√(2f)."""
+    off-diagonal (block, block) sample. ``atol``: the expansion's ``x @ yᵀ``
+    multiplies float32 rows in float32 (``ops/mxu.py``'s rule), so what is
+    left is the float32 cancellation of |x|² + |y|² − 2x·y: a few roundings
+    of 2f on d², 1e-5 on distances of ~√(2f) = 11 (a v5e reads 2.5e-6 at
+    f = 64; at the MXU's default precision, before PR 33, it read 9e-3 and
+    this check allowed 5e-2)."""
     import heat_tpu as ht
 
     ht.random.seed(2)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from ..core import fusion, telemetry, types
 from ..core.dndarray import DNDarray, _ensure_split
 from ..ops import lloyd as _lloyd
+from ..ops import mxu as _mxu
 from ..spatial.distance import _sq_euclidian_fast as _sq_dist
 from ._kcluster import _KCluster
 
@@ -52,18 +53,15 @@ def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
 def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
     if xsq_sum is None:
         xsq_sum = jnp.sum(data * data)
-    # float32 (or wider) rows multiply in float32 on the MXU, as the fused
-    # kernel's do; the XLA default would round both operands to bfloat16
-    precision = _lloyd.mxu_precision(data.dtype)
-    # score = d² − |x|² (row-constant offset): same argmin, cheaper to form
-    score = jnp.sum(centers * centers, axis=1) - 2.0 * jnp.matmul(
-        data, centers.T, precision=precision
-    )  # (n, k)
+    # score = d² − |x|² (row-constant offset): same argmin, cheaper to form.
+    # Both products go by ops/mxu.py's rule (float32 rows multiply in
+    # float32), as the fused kernel's do
+    score = jnp.sum(centers * centers, axis=1) - 2.0 * _mxu.matmul(data, centers.T)  # (n, k)
     labels = jnp.argmin(score, axis=1).astype(jnp.int32)
     onehot = jax.nn.one_hot(labels, k, dtype=data.dtype)  # (n, k)
     counts = jnp.sum(onehot, axis=0)  # (k,)
     # (k, f) — MXU; psum over the sharded rows
-    sums = jnp.matmul(onehot.T, data, precision=precision)
+    sums = _mxu.matmul(onehot.T, data)
     new_centers = jnp.where(
         counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1.0), centers
     )
@@ -73,11 +71,6 @@ def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
     inertia = jnp.maximum(jnp.sum(jnp.min(score, axis=1)) + xsq_sum, 0.0)
     shift = jnp.sum((new_centers - centers) ** 2)
     return new_centers, labels, inertia, shift
-
-
-def _no_phase(name: str) -> int:
-    """``telemetry.Phases.phase`` of a fit that is not traced."""
-    return 0
 
 
 class KMeans(_KCluster):
@@ -175,7 +168,7 @@ class KMeans(_KCluster):
             raise ValueError(f"input needs to be 2D, but was {x.ndim}D")
         mode, interpret = self._fused_mode(x)
         if not telemetry.tracing():
-            self._fit(x, mode, interpret, _no_phase)
+            self._fit(x, mode, interpret, telemetry.no_phase)
             return self
         ph = telemetry.Phases(
             "heat.kmeans.fit", mode=mode or "jnp",
@@ -185,7 +178,7 @@ class KMeans(_KCluster):
             counts = self._fit(x, mode, interpret, ph.phase)
         finally:
             ph.close()
-        fusion.note_kmeans_fit(ph.ns, *counts)
+        fusion.note_phases("kmeans", ph.ns, fits=1, **counts)
         return self
 
     def _fit(self, x: DNDarray, mode, interpret: bool, mark):
@@ -257,4 +250,4 @@ class KMeans(_KCluster):
         )
         self._labels = self._wrap_labels(labels, x)
         epilogues = dispatches * _lloyd.RUN_LABEL_EPILOGUES if mode else 0
-        return dispatches, syncs, epilogues
+        return {"dispatches": dispatches, "syncs": syncs, "label_epilogues": epilogues}

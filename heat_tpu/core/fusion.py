@@ -527,17 +527,22 @@ _STATS.update({f"phase_{name}_ns": 0 for name in _FORCE_PHASES})
 _STATS.update(
     phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0
 )
-# an estimator's fit is timed the same way, under the same switch
-# (cluster/kmeans.py: the heat.kmeans.fit span and its children): fits, the
-# programs they dispatched, their blocking host reads, the XLA label passes
-# over the rows those programs ran, nanoseconds per phase
+# an estimator's fit and a distance-matrix call are timed the same way, under
+# the same switch (note_phases): ``phase_<prefix>_<phase>_ns`` per phase of
+# the heat.<prefix> span's children, and the region's own counts.
+# cluster/kmeans.py (heat.kmeans.fit): fits, the programs they dispatched,
+# their blocking host reads, the XLA label passes over the rows those programs
+# ran. spatial/distance.py (heat.cdist): calls, and the operand rotations
+# (collective-permutes of one operand shard) their tile programs made
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
+_CDIST_PHASES = ("prepare", "dispatch", "place")
 _STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
+_STATS.update({f"phase_cdist_{name}_ns": 0 for name in _CDIST_PHASES})
 _STATS.update(
     phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
-    phase_kmeans_label_epilogues=0,
+    phase_kmeans_label_epilogues=0, phase_cdist_calls=0, phase_cdist_rotations=0,
 )
-# place, read and a fit are timed outside _FORCE_LOCK, from any serving thread:
+# place, read, a fit and a cdist are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches
 _PHASE_LOCK = threading.Lock()
 
@@ -551,18 +556,17 @@ def note_phase(name: str, ns: int) -> None:
         _STATS[f"phase_{name}_ns"] += ns
 
 
-def note_kmeans_fit(ns: dict, dispatches: int, syncs: int, label_epilogues: int) -> None:
-    """Count one ``heat.kmeans.fit``: the nanoseconds of each phase it went
-    through (``telemetry.Phases.ns``), the Lloyd programs it dispatched, the
-    blocking host reads it made and the XLA label passes over the rows that
-    its programs ran (while ``telemetry.tracing()``)."""
+def note_phases(prefix: str, ns: dict, **counts: int) -> None:
+    """Count one traced region of the library above the engine (a
+    ``heat.kmeans.fit``, a ``heat.cdist``; while ``telemetry.tracing()``): the
+    nanoseconds of each phase it went through (``telemetry.Phases.ns``) onto
+    ``phase_<prefix>_<phase>_ns`` and each of ``counts`` onto
+    ``phase_<prefix>_<name>``."""
     with _PHASE_LOCK:
-        _STATS["phase_kmeans_fits"] += 1
-        _STATS["phase_kmeans_dispatches"] += dispatches
-        _STATS["phase_kmeans_syncs"] += syncs
-        _STATS["phase_kmeans_label_epilogues"] += label_epilogues
+        for name, add in counts.items():
+            _STATS[f"phase_{prefix}_{name}"] += add
         for name, took in ns.items():
-            _STATS[f"phase_kmeans_{name}_ns"] += took
+            _STATS[f"phase_{prefix}_{name}_ns"] += took
 
 # serving seams (core/serving.py installs these as module attributes — the
 # telemetry ``_MEM_HOOK`` set-attribute pattern; each costs one ``is None``

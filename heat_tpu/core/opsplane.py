@@ -172,6 +172,9 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (shift, inertia) made by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_label_epilogues_total", (_C, "XLA label passes over the rows run by the Lloyd programs of timed KMeans fits.", [])),
         ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/wrap).", ["phase"])),
+        ("heat_tpu_cdist_calls_total", (_C, "Distance-matrix calls (cdist/rbf/manhattan) whose phases were timed (telemetry on or a profiler session recording).", [])),
+        ("heat_tpu_cdist_rotations_total", (_C, "Operand-shard rotations (collective-permutes) made by the tile programs of timed distance-matrix calls.", [])),
+        ("heat_tpu_cdist_phase_seconds_total", (_C, "Host time of timed distance-matrix calls, by phase (prepare/dispatch/place).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -299,11 +302,14 @@ def _collect_fusion(out: List[Sample]) -> None:
         ))
     for count in ("fits", "dispatches", "syncs", "label_epilogues"):
         out.append((f"heat_tpu_kmeans_{count}_total", {}, float(stats[f"phase_kmeans_{count}"])))
-    for phase in fusion._KMEANS_PHASES:
-        out.append((
-            "heat_tpu_kmeans_phase_seconds_total", {"phase": phase},
-            stats[f"phase_kmeans_{phase}_ns"] * 1e-9,
-        ))
+    for count in ("calls", "rotations"):
+        out.append((f"heat_tpu_cdist_{count}_total", {}, float(stats[f"phase_cdist_{count}"])))
+    for prefix, phases in (("kmeans", fusion._KMEANS_PHASES), ("cdist", fusion._CDIST_PHASES)):
+        for phase in phases:
+            out.append((
+                f"heat_tpu_{prefix}_phase_seconds_total", {"phase": phase},
+                stats[f"phase_{prefix}_{phase}_ns"] * 1e-9,
+            ))
     out.append(("heat_tpu_fusion_cache_size", {}, float(stats["size"])))
     out.append(("heat_tpu_fusion_quarantined", {}, float(stats["quarantined"])))
 

@@ -154,6 +154,7 @@ __all__ = [
     "hlo_collectives",
     "io_retries",
     "merge_traces",
+    "no_phase",
     "nonfinite_counts",
     "on_timer",
     "operand_bytes",
@@ -407,6 +408,12 @@ class Phases:
             self._span = None
             self._t = time.perf_counter_ns()
         return self._t - self._t0
+
+
+def no_phase(name: str) -> int:
+    """:meth:`Phases.phase` of a region that is not traced: what a function
+    that marks its phases is handed when :func:`tracing` is off."""
+    return 0
 
 
 # ----------------------------------------------------------------------

@@ -45,13 +45,13 @@ at the default, float32 operands are rounded to bfloat16 and multiplied in
 one pass, which put ``KMeans.fit``'s float32 centres 1.1-1.4 % off a plain
 float32 Lloyd (PERF.md, PR 26). float32 rows (and wider, carried as float32)
 therefore multiply in float32: both contractions take their float32 operand
-as three bfloat16 pieces that add up to it exactly (:func:`_bf16_pieces`),
+as three bfloat16 pieces that add up to it exactly (``ops/mxu.py::bf16_pieces``),
 and because (k, f) fills a corner of a 128 x 128 MXU tile, the pieces are
 stacked along the contracted and the output axes of ONE bfloat16 pass, so
 every piece product is exact in the float32 accumulator, at the MXU cost of
 the single rounded pass. bfloat16 rows are one piece: they keep their
 bfloat16 multiplication with float32 accumulation and half the HBM stream.
-The jnp path asks XLA for the same (:func:`mxu_precision`).
+The jnp path asks XLA for the same (``ops/mxu.py::matmul``).
 
 This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here on
 TPU (``fused_supported`` / ``fused_sharded_supported``) and takes the jnp
@@ -70,12 +70,13 @@ nothing from the one before it but the centres.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .mxu import bf16_pieces as _bf16_pieces, mxu_precision  # noqa: F401  (the rule lives in ops/mxu.py)
 
 __all__ = [
     "fused_lloyd_run",
@@ -83,38 +84,6 @@ __all__ = [
     "fused_sharded_supported",
     "fused_supported",
 ]
-
-
-def mxu_precision(dtype) -> Optional[jax.lax.Precision]:
-    """What a contraction on rows of ``dtype`` asks of XLA (the jnp Lloyd
-    path): float32 and wider multiply in float32
-    (``HIGHEST``; the default rounds both operands to bfloat16 and multiplies
-    once), bfloat16 rows keep their one bfloat16 pass."""
-    return None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
-
-
-def _bf16_pieces(x: jax.Array) -> tuple:
-    """``x`` as bfloat16 arrays that add up to it exactly: itself if it is
-    bfloat16, else a float32's significand cut into 8 + 8 + 8 bits. The
-    product of two pieces is exact in the MXU's float32 accumulator, so one
-    bfloat16 pass over all pairs of pieces is the float32 product.
-
-    The cuts are made on the bits (the low half of the word masked off), not
-    by converting to bfloat16 and back: XLA takes a float32 -> bfloat16 ->
-    float32 round trip for the identity (``xla_allow_excess_precision``), and
-    the remainder it was taken for would be zero (seen on a v5e: centres cut
-    this way outside the kernel scored as bfloat16)."""
-    if x.dtype == jnp.bfloat16:
-        return (x,)
-
-    def head(v):  # the leading 8 bits of the significand: a bfloat16's worth, as float32
-        bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
-        return jax.lax.bitcast_convert_type(bits, jnp.float32)
-
-    hi = head(x)
-    rest = x - hi  # exact: at most 16 bits are left
-    mid = head(rest)
-    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
 
 
 RUN_LABEL_EPILOGUES = 0

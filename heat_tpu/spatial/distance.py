@@ -1,17 +1,34 @@
 """Pairwise distance computation.
 
 TPU-native re-design of reference heat/spatial/distance.py. The reference's
-``_dist`` engine rotates the smaller operand's shards around an MPI ring —
+``_dist`` engine rotates the smaller operand's shards around an MPI ring:
 each iteration sends the stationary shard to ``(rank+i) % size``, computes one
-tile, and exploits symmetry to halve the iteration count
-(distance.py:265-369 symmetric, :429-487 general). That systolic schedule is
-exactly ring attention's; here it is written once as a ``shard_map`` kernel
-whose rotation is ``lax.ppermute`` over the mesh axis and whose tile compute
-is an MXU-shaped quadratic-expansion matmul.
+tile, and, for Y = X, exploits symmetry to halve the iteration count by
+sending finished tiles to their transpose owners (distance.py:265-369
+symmetric, :429-487 general). Here there is ONE tile program
+(:func:`_tile_program`) for every row-split pair of operands on every mesh,
+one device included: the stationary row block computes all p column tiles of
+its rows, and only operand shards travel (``lax.ppermute``, shift 1, p - 1
+times; none at p = 1). No collective carries anything of a result tile's
+size. Upstream's symmetry halving pays on MPI + CPU, where a tile is dear and
+a message cheap; on a TPU mesh a (n/p, n/p) float32 tile costs tens of
+milliseconds over ICI and well under a millisecond to compute again for every
+f below several thousand, so Y = X takes the same program.
 
-For the common benchmark case (one operand replicated, reference
-distance.py:422-427) no ring is needed: a single sharded jnp expression
-compiles to the local metric kernel.
+Memory on a device is its rows of the result plus at most one column chunk of
+a tile: the result buffer is born uninitialised (``lax.empty``) and every tile
+is written into it in place, in column chunks of at most ``_CHUNK_BYTES``
+(:func:`_write_tile`).
+
+When one operand is replicated (reference distance.py:422-427) no ring is
+needed: a single sharded jnp expression compiles to the local metric kernel.
+
+While ``telemetry.tracing()`` a call is a ``heat.cdist`` span (stats ``mode``
+= ``ring`` | ``replicated``, ``n``, ``m``, ``f``, ``p``, ``metric``) whose
+children lie side by side: ``.prepare`` (promotion, padding, placing the
+operands), ``.dispatch`` (the program's call), ``.place`` (slice,
+``_ensure_split``, the ``DNDarray``); the same intervals add to
+``fusion.cache_stats()``'s ``phase_cdist_*`` keys.
 """
 
 from __future__ import annotations
@@ -23,9 +40,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..core import factories, sanitation, types
+from ..core import fusion, sanitation, telemetry, types
 from ..core.communication import ppermute as _ppermute
 from ..core.dndarray import DNDarray, _ensure_split
+from ..ops.mxu import matmul as _matmul, mxu_precision  # noqa: F401  (mxu_precision: the rule this module multiplies by, for whoever asks)
 
 __all__ = ["cdist", "manhattan", "rbf"]
 
@@ -42,10 +60,13 @@ def _euclidian(x: jax.Array, y: jax.Array) -> jax.Array:
 def _sq_euclidian_fast(x: jax.Array, y: jax.Array) -> jax.Array:
     """Squared pairwise distance via quadratic expansion: |x|² + |y|² − 2x·yᵀ
     — one MXU matmul instead of an O(nmf) broadcast, the TPU fast path.
-    Shared by cdist and the k-clustering assignment kernels."""
+    float32 (or wider) rows multiply in float32 (``ops/mxu.py``'s rule, from
+    the dtype alone): at the MXU's default the product is taken on
+    bfloat16-rounded operands and the cancellation leaves distances 1e-2 off.
+    Shared by cdist, rbf and the k-clustering assignment kernels."""
     xn = jnp.sum(x * x, axis=1, keepdims=True)
     yn = jnp.sum(y * y, axis=1, keepdims=True)
-    return jnp.maximum(xn + yn.T - 2.0 * (x @ y.T), 0.0)
+    return jnp.maximum(xn + yn.T - 2.0 * _matmul(x, y.T), 0.0)
 
 
 def _euclidian_fast(x: jax.Array, y: jax.Array) -> jax.Array:
@@ -82,11 +103,15 @@ def manhattan(X: DNDarray, Y: Optional[DNDarray] = None, expand: bool = False) -
 
 @functools.lru_cache(maxsize=32)
 def _gaussian_metric(sigma: float, fast: bool) -> Callable:
-    """One stable metric closure per (sigma, fast) — a fresh lambda per rbf
-    call would defeat the ring-program caches keyed on the metric object."""
-    if fast:
-        return lambda x, y: _gaussian_fast(x, y, sigma)
-    return lambda x, y: _gaussian(x, y, sigma)
+    """One stable metric closure per (sigma, fast) — a fresh closure per rbf
+    call would defeat the tile-program cache keyed on the metric object."""
+    kernel = _gaussian_fast if fast else _gaussian
+
+    def rbf_metric(x, y):
+        return kernel(x, y, sigma)
+
+    rbf_metric.__name__ = kernel.__name__
+    return rbf_metric
 
 
 def rbf(
@@ -100,204 +125,174 @@ def rbf(
 
 
 def _dist(X: DNDarray, Y: Optional[DNDarray], metric: Callable) -> DNDarray:
-    """Distance engine (reference distance.py:209-487)."""
+    """Distance engine (reference distance.py:209-487): the checks, then
+    :func:`_dist_checked`, timed by phase while ``telemetry.tracing()``."""
     sanitation.sanitize_in(X)
     if X.ndim != 2:
         raise NotImplementedError(f"X should be 2D, but was {X.ndim}D")
-    promoted = types.promote_types(X.dtype, types.float32)
-    xl = X.larray.astype(promoted.jax_type())
-
-    if Y is None or Y is X:
-        yl, y_split, y_obj = xl, X.split, X
-    else:
+    if Y is None:
+        Y = X
+    elif Y is not X:
         sanitation.sanitize_in(Y)
         if Y.ndim != 2:
             raise NotImplementedError(f"Y should be 2D, but was {Y.ndim}D")
         if X.shape[1] != Y.shape[1]:
             raise ValueError("inputs must have the same number of features")
+    ring = X.split == 0 and Y.split == 0
+    if not telemetry.tracing():
+        return _dist_checked(X, Y, metric, ring, telemetry.no_phase)
+    p = X.comm.size
+    ph = telemetry.Phases(
+        "heat.cdist", mode="ring" if ring else "replicated", n=int(X.shape[0]),
+        m=int(Y.shape[0]), f=int(X.shape[1]), p=p, metric=metric.__name__.lstrip("_"),
+    )
+    try:
+        out = _dist_checked(X, Y, metric, ring, ph.phase)
+    finally:
+        ph.close()
+    fusion.note_phases("cdist", ph.ns, calls=1, rotations=p - 1 if ring else 0)
+    return out
+
+
+def _dist_checked(X: DNDarray, Y: DNDarray, metric: Callable, ring: bool, mark) -> DNDarray:
+    """:func:`_dist` past its checks. ``mark(name)`` opens the call's next
+    phase (``telemetry.Phases.phase``; nothing when the call is not traced)."""
+    mark("prepare")
+    comm, p = X.comm, X.comm.size
+    symmetric = Y is X
+    promoted = types.promote_types(X.dtype, types.float32)
+    if not symmetric:
         promoted = types.promote_types(promoted, Y.dtype)
-        xl = xl.astype(promoted.jax_type())
-        yl = Y.larray.astype(promoted.jax_type())
-        y_split, y_obj = Y.split, Y
-
-    comm = X.comm
+    xl = X.larray.astype(promoted.jax_type())
+    yl = xl if symmetric else Y.larray.astype(promoted.jax_type())
     n, m = xl.shape[0], yl.shape[0]
-    p = comm.size
 
-    use_ring = X.split == 0 and y_split == 0 and p > 1
-    if use_ring:
-        symmetric = Y is None or Y is X
+    if ring:
         # ragged row counts: pad to the next multiple of p and slice the
         # result — the reference's *v collectives have no XLA analog
         # (SURVEY.md §7), pad+mask is the balanced-only rendering
         n_pad, m_pad = (-n) % p, (-m) % p
-        if symmetric and n_pad:
-            xl = yl = jnp.pad(xl, ((0, n_pad), (0, 0)))
-        elif not symmetric:
-            if n_pad:
-                xl = jnp.pad(xl, ((0, n_pad), (0, 0)))
-            if m_pad:
-                yl = jnp.pad(yl, ((0, m_pad), (0, 0)))
+        if n_pad:
+            xl = jnp.pad(xl, ((0, n_pad), (0, 0)))
+        if not symmetric and m_pad:
+            yl = jnp.pad(yl, ((0, m_pad), (0, 0)))
         xl = _ensure_split(xl, 0, comm)
         yl = xl if symmetric else _ensure_split(yl, 0, comm)
-        if symmetric:
-            result = _ring_dist_sym(xl, metric, comm)
-        else:
-            result = _ring_dist(xl, yl, metric, comm)
+        mark("dispatch")
+        result = _tile_program(comm.mesh, comm.axis_name, p, metric)(xl, yl)
+        mark("place")
         if n_pad or m_pad:
             result = result[:n, :m]
     else:
         # one operand replicated (reference distance.py:422-427) — or a layout
         # the ring does not cover: a single sharded expression, XLA schedules it
+        mark("dispatch")
         result = metric(xl, yl)
+        mark("place")
 
     split = 0 if X.split == 0 else None
+    # a no-op for the tile program's result, which is born row-sharded
     result = _ensure_split(result, split, comm)
     return DNDarray(
         result, tuple(result.shape), types.canonical_heat_type(result.dtype), split, X.device, comm
     )
 
 
-def _sym_schedule(p: int):
-    """Rotation schedule of the symmetric ring: step offsets whose tiles are
-    computed directly; offsets p-i for i in the first half arrive as
-    transposes. ``(paired, self_paired)`` — ``len(paired) (+1 if
-    self_paired)`` rotations instead of the general ring's p-1 (the
-    reference's symmetry halving, distance.py:272-327)."""
-    paired = list(range(1, (p - 1) // 2 + 1))
-    self_paired = p % 2 == 0 and p > 1
-    return paired, self_paired
+_CHUNK_BYTES = 1 << 29
+"""The most one column chunk of a result tile may take (512 MiB): what a
+device holds besides its rows of the result is a chunk's product and epilogue,
+not a tile's (2.5 GB at 100 000 rows over four devices, 10 GB at 50 000 rows
+on one)."""
 
 
-def _ring_dist_sym(xl: jax.Array, metric: Callable, comm) -> jax.Array:
-    """Symmetric systolic ring (Y ≡ X): compute only the upper half of the
-    tile offsets and mirror each tile to its transpose owner — ⌈p/2⌉
-    rotations of the stationary operand instead of p−1, recovering the
-    reference's symmetry optimization (reference distance.py:272-327) with
-    the mirrored tile travelling over the same ICI ring."""
-    return _sym_program(comm.mesh, comm.axis_name, comm.size, metric)(xl)
+def _column_chunks(rows: int, cols: int, itemsize: int):
+    """``(starts, width)`` of the column chunks of ``cols`` columns (a
+    multiple of 128) of ``rows`` rows: as few as keep a chunk within
+    ``_CHUNK_BYTES``, all of one lane-aligned width, so the last one starts
+    early and computes a few columns again (2.4 % at 100 000 rows) rather
+    than be a ragged shape of its own."""
+    most = max(128, _CHUNK_BYTES // (rows * itemsize) // 128 * 128)
+    if cols <= most:
+        return [0], cols
+    count = -(-cols // most)
+    width = -(-cols // (count * 128)) * 128
+    return [min(k * width, cols - width) for k in range(-(-cols // width))], width
 
 
-@functools.lru_cache(maxsize=64)
-def _sym_program(mesh, axis: str, p: int, metric: Callable):
-    """Cached jitted symmetric-ring program (one trace per (mesh, metric);
-    jit re-specializes per operand shape internally). Exposed so tests can
-    ``.lower()`` it for HLO collective-budget assertions."""
-    from jax.sharding import PartitionSpec as P
+def _write_tile(metric: Callable, xs, ys_cur, out, lo: int):
+    """``out`` with the tile of ``xs`` against ``ys_cur`` in its columns
+    ``[lo, lo + len(ys_cur))``, every offset a constant. What XLA:TPU does
+    with a store depends on where it lands (timed on a v5e, PERF.md PR 33): a
+    chunk that starts on a lane tile (a multiple of 128) is stored where it is
+    computed, product, epilogue and ``dynamic-update-slice`` one fusion;
+    anywhere else, or at an offset known only at run time, the chunk is
+    computed, then copied into place at a third of the speed, and a piece
+    narrower than a lane tile turns the whole result column-major. So the
+    columns between the first and the last multiple of 128 go in lane-aligned
+    chunks (:func:`_column_chunks`), and what is left at either end, under
+    128 columns, is merged into the lane tile it shares with the neighbouring
+    tile: read, selected, written back. Tiles under three lane tiles (tests,
+    wide meshes on small data) are one plain store."""
+    rows, cols, total = xs.shape[0], ys_cur.shape[0], out.shape[1]
+    hi = lo + cols
 
-    paired, self_paired = _sym_schedule(p)
+    def store(out, ys_cur, part, at):
+        out = jax.lax.dynamic_update_slice(out, part, (0, at))
+        # one piece at a time: without the barrier the scheduler may compute
+        # every chunk of a tile before it stores the first
+        return jax.lax.optimization_barrier((out, ys_cur))
 
-    h = len(paired)  # offsets 1..h computed directly; their mirrors arrive
-
-    def kernel(xs):
-        m_block = xs.shape[0]  # per-device row block
-        rank = jax.lax.axis_index(axis)
-
-        def write(out, tile, col_block):
-            col = (col_block % p) * m_block
-            return jax.lax.dynamic_update_slice(
-                out, tile, (jnp.zeros((), col.dtype), col)
-            )
-
-        out = jnp.zeros((xs.shape[0], m_block * p), dtype=xs.dtype)
-        try:
-            out = jax.lax.pcast(out, (axis,), to="varying")
-        except (AttributeError, TypeError):  # pragma: no cover - older jax
-            pass
-        # diagonal tile: local compute, no communication
-        out = write(out, metric(xs, xs), rank)
-
-        # ⌈p/2⌉ uniform shift-1 rotations in a fori_loop (program size O(1)
-        # in p — tests/test_mesh64_compile); each step stashes its tile at
-        # slot (rank+i) % p so ONE all_to_all afterwards hands every device
-        # exactly the mirror tiles of its row, replacing the per-step
-        # variable-shift ppermute the unrolled schedule needed
-        buf0 = jnp.zeros((p, m_block, m_block), dtype=xs.dtype)
-
-        def step(i, carry):
-            ys_cur, out, buf = carry
-            ys_cur = _ppermute(ys_cur, axis, p, shift=1)  # now holds shard rank+i
-            tile = metric(xs, ys_cur)  # tile (rank, rank+i)
-            out = write(out, tile, rank + i)
-            slot = (rank + i) % p
-            buf = jax.lax.dynamic_update_slice(
-                buf, tile[None], (slot, jnp.zeros((), slot.dtype), jnp.zeros((), slot.dtype))
-            )
-            return ys_cur, out, buf
-
-        ys_cur, out, buf = jax.lax.fori_loop(1, h + 1, step, (xs, out, buf0))
-
-        if h:
-            # slot j of device d holds tile (d, j) iff (j - d) % p in 1..h;
-            # all_to_all delivers slot j to device j — device r receives
-            # tile (d, r) from every d, i.e. its whole mirror column
-            mirror = jax.lax.all_to_all(buf, axis, split_axis=0, concat_axis=0)
-
-            def fold_mirror(d, out):
-                valid = ((rank - d) % p >= 1) & ((rank - d) % p <= h)
-                col = (d % p) * m_block
-                cur = jax.lax.dynamic_slice(
-                    out, (jnp.zeros((), col.dtype), col), (m_block, m_block)
-                )
-                tile_t = mirror[d].T
-                return jax.lax.dynamic_update_slice(
-                    out, jnp.where(valid, tile_t, cur), (jnp.zeros((), col.dtype), col)
-                )
-
-            out = jax.lax.fori_loop(0, p, fold_mirror, out)
-
-        if self_paired:
-            # p even: offset p/2 is its own mirror — every device computes it
-            ys_cur = _ppermute(ys_cur, axis, p, shift=1)
-            out = write(out, metric(xs, ys_cur), rank + p // 2)
-        return out
-
-    return jax.jit(
-        jax.shard_map(
-            kernel,
-            mesh=mesh,
-            in_specs=P(axis, None),
-            out_specs=P(axis, None),
-            check_vma=False,
-        )
-    )
-
-
-def _ring_dist(xl: jax.Array, yl: jax.Array, metric: Callable, comm) -> jax.Array:
-    """Systolic ring: the stationary X shard computes one tile per step while
-    Y shards rotate via ppermute (the reference's Send-to-(rank+i) schedule,
-    distance.py:272-327, re-expressed as a collective-permute ring)."""
-    return _ring_program(comm.mesh, comm.axis_name, comm.size, metric)(xl, yl)
+    if cols < 3 * 128:
+        return store(out, ys_cur, metric(xs, ys_cur), lo)[0]
+    first, last = -(-lo // 128) * 128, hi // 128 * 128
+    starts, width = _column_chunks(rows, last - first, jnp.dtype(out.dtype).itemsize)
+    for start in starts:
+        at = first + start
+        out, ys_cur = store(out, ys_cur, metric(xs, ys_cur[at - lo : at - lo + width]), at)
+    ends = ([first - 128] if lo < first else []) + ([min(last, total - 128)] if last < hi else [])
+    for at in ends:
+        col = at + jnp.arange(128)
+        rows_of_y = jnp.take(ys_cur, jnp.clip(col - lo, 0, cols - 1), axis=0)
+        mine = ((col >= lo) & (col < hi))[None, :]
+        old = jax.lax.slice(out, (0, at), (rows, at + 128))
+        out, ys_cur = store(out, ys_cur, jnp.where(mine, metric(xs, rows_of_y), old), at)
+    return out
 
 
 @functools.lru_cache(maxsize=64)
-def _ring_program(mesh, axis: str, p: int, metric: Callable):
-    """Cached jitted general-ring program (one trace per (mesh, metric))."""
+def _tile_program(mesh, axis: str, p: int, metric: Callable):
+    """The cached jitted tile program of ``(mesh, metric)`` for X and Y split
+    on rows (jit re-specializes per operand shape; tests ``.lower()`` it for
+    collective-budget and memory assertions). On each device the stationary
+    X shard computes its tile against the visiting Y shard, writes it into
+    its rows of the result at the visiting shard's columns
+    (:func:`_write_tile`), and passes the Y shard on (the reference's
+    Send-to-(rank+i) schedule, distance.py:272-327, as a collective-permute
+    ring): p tiles, p - 1 rotations in a ``fori_loop``, none at p = 1. Which
+    shard visits is known only on the device; where its tile lands is not
+    left to run time: one branch per visiting shard, each with its columns as
+    constants (the collectives stay O(1) in p, the branches are p)."""
     from jax.sharding import PartitionSpec as P
 
     def kernel(xs, ys):
-        m_block = ys.shape[0]  # per-device row block of the rotating operand
+        rows, cols = xs.shape[0], ys.shape[0]  # one tile is (rows, cols)
+        out = jax.lax.empty((rows, cols * p), jax.eval_shape(metric, xs[:1], ys[:1]).dtype)
+        if p == 1:
+            return _write_tile(metric, xs, ys, out, 0)
+        out = jax.lax.pcast(out, (axis,), to="varying")
+        tiles = [
+            (lambda ys_cur, out, lo=j * cols: _write_tile(metric, xs, ys_cur, out, lo)) for j in range(p)
+        ]
         rank = jax.lax.axis_index(axis)
 
-        def fold(i, ys_cur, out):
-            # ys_cur currently holds the shard of device (rank + i) % p
-            tile = metric(xs, ys_cur)
-            col = ((rank + i.astype(rank.dtype)) % p) * m_block
-            return jax.lax.dynamic_update_slice(out, tile, (jnp.zeros((), col.dtype), col))
+        def step(i, carry):
+            ys_cur, out = carry  # ys_cur holds the shard of device (rank + i) % p
+            out = jax.lax.switch((rank + jnp.asarray(i, rank.dtype)) % p, tiles, ys_cur, out)
+            return _ppermute(ys_cur, axis, p, shift=1), out
 
-        def body(i, carry):
-            ys_cur, out = carry
-            out = fold(i, ys_cur, out)
-            # rotate: receive the next shard from the right neighbor
-            ys_next = _ppermute(ys_cur, axis, p, shift=1)
-            return ys_next, out
-
-        out0 = jax.lax.pcast(
-            jnp.zeros((xs.shape[0], m_block * p), dtype=xs.dtype), (axis,), to="varying"
-        )
-        # p-1 rotations; the last visiting shard is folded without re-sending it
-        ys_last, out = jax.lax.fori_loop(0, p - 1, body, (ys, out0))
-        return fold(jnp.asarray(p - 1), ys_last, out)
+        # p-1 rotations; the last visiting shard's tile is written without re-sending it
+        ys, out = jax.lax.fori_loop(0, p - 1, step, (ys, out))
+        return jax.lax.switch((rank + p - 1) % p, tiles, ys, out)
 
     return jax.jit(
         jax.shard_map(

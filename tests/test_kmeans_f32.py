@@ -278,18 +278,22 @@ def one_v5e(v5e_2x2):
     return SingleDeviceSharding(v5e_2x2[0])
 
 
-def _compiled_text(fn, *shapes):
-    """``fn`` compiled for the described chip(s), as text. Such a compile cannot
-    be read back from the compilation cache without a chip, and the chip runs
-    with 64-bit mode off: under the suite's x64 ``jnp.argmin`` asks for an
-    int64 index, which Mosaic refuses (PERF.md, section 7)."""
+def _compiled(fn, *shapes):
+    """``fn`` compiled for the described chip(s). Such a compile cannot be read
+    back from the compilation cache without a chip, and the chip runs with
+    64-bit mode off: under the suite's x64 ``jnp.argmin`` asks for an int64
+    index, which Mosaic refuses (PERF.md, section 7)."""
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         with jax.enable_x64(False):
-            return fn.lower(*shapes).compile().as_text()
+            return fn.lower(*shapes).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _compiled_text(fn, *shapes):
+    return _compiled(fn, *shapes).as_text()
 
 
 @pytest.mark.parametrize("last", [False, True], ids=["plain", "labels"])
@@ -371,3 +375,67 @@ def test_nothing_but_the_kernel_reads_the_rows_of_a_sharded_run(v5e_2x2):
     opcodes = _opcodes_on_rows(text, "f32", local, F)
     assert set(opcodes) <= ROWS_PLUMBING and opcodes["bitcast"] == 1 and opcodes["custom-call"] == 2, opcodes
     assert "all-reduce" in text
+
+
+# -- ISSUE 33: the distance engine's tile program at the cdist_f32 configuration's
+# sizes, compiled for the described chips (here because this file holds the fixture)
+@pytest.fixture(scope="module", params=["cdist_ring_4c", "cdist_50k_1c"])
+def cdist_program(request, v5e_2x2):
+    """(chips, rows of the operand, the compiled tile program) of one cell."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from heat_tpu.spatial import distance
+
+    cell = spec.Cell(request.param)
+    chips, f = cell.chips, cell.config["features"]
+    n = cell.config["rows"][str(chips)]
+    mesh = Mesh(np.array(v5e_2x2[:chips]), ("x",))
+    x = jax.ShapeDtypeStruct((n, f), jnp.float32, sharding=NamedSharding(mesh, P("x", None)))
+    build = distance._tile_program.__wrapped__  # the cache keeps no program of a described mesh
+    return chips, n, _compiled(build(mesh, "x", chips, distance._euclidian_fast), x, x)
+
+
+def test_cdist_program_holds_its_rows_of_the_result_and_one_chunk(cdist_program):
+    """10.0 GB of result a device, born uninitialised and written in place;
+    beside it at most one column chunk of a tile (0.5 GB), never a tile
+    (2.5 GB on four chips, 10 GB on one), and the operand."""
+    from heat_tpu.spatial import distance
+
+    chips, n, compiled = cdist_program
+    memory = compiled.memory_analysis()
+    assert abs(memory.output_size_in_bytes - n // chips * n * 4) < 1 << 24  # the rows held here, and tile padding
+    assert memory.temp_size_in_bytes <= 1.1 * distance._CHUNK_BYTES < 2.5e9, memory
+    assert memory.output_size_in_bytes + memory.temp_size_in_bytes + memory.argument_size_in_bytes < 10.7e9
+    text = compiled.as_text()
+    assert 'custom_call_target="AllocateBuffer"' in text, "the result buffer is filled before it is written"
+    assert not re.search(rf"f32\[{n // chips},{n}\][^ ]* broadcast\(", text)
+
+
+def test_cdist_program_moves_operand_shards_only(cdist_program):
+    """The ring's collectives on the 2 x 2 mesh are collective-permutes of one
+    (25 000, 64) operand shard; one chip has none."""
+    chips, n, compiled = cdist_program
+    lines = re.findall(r"(?:all-gather|all-reduce|all-to-all|collective-permute)[^\n]*", compiled.as_text())
+    if chips == 1:
+        assert not lines
+        return
+    assert lines and all(line.startswith("collective-permute") for line in lines), lines
+    for line in lines:
+        for shape in re.findall(r"f32\[([\d,]+)\]", line):
+            assert int(np.prod([int(d) for d in shape.split(",")])) <= n // chips * 64, line[:200]
+
+
+def test_cdist_program_multiplies_in_float32(cdist_program):
+    """No product of the compiled program is left to the MXU's default on
+    float32 operands: each takes bfloat16 pieces stacked six deep (K = 6 x 64,
+    ``ops/mxu.py``) or asks for ``HIGHEST``."""
+    _, _, compiled = cdist_program
+    text = compiled.as_text()
+    products = re.findall(r"convolution\(([^)]*)\)([^\n]*)", text)
+    assert products
+    for operands, rest in products:
+        if "operand_precision={highest,highest}" in rest:
+            continue
+        for name in re.findall(r"%[\w.\-]+", operands):
+            (defined,) = set(re.findall(rf"{re.escape(name)} = (\w+)\[([\d,]+)\]", text))
+            assert defined[0] == "bf16" and "384" in defined[1].split(","), (name, defined)

@@ -1,6 +1,6 @@
 """Compile-time scaling to a 64-device mesh (BASELINE.md's 1→64-chip north
 star). The big distributed programs — panel QR, merge-exchange sort, exscan,
-the symmetric ring, the fused triangular solve and det — are built around
+the distance ring, the fused triangular solve and det — are built around
 ``fori_loop``/``lax.cond``/one-shot collectives precisely so program size
 and compile time stay bounded as the mesh grows (the reference CI scales by
 adding MPI *processes*, reference Jenkinsfile:24-28; a single-controller
@@ -82,17 +82,15 @@ def build_exscan():
 
 timed("exscan", build_exscan)
 
-# --- symmetric systolic ring (fori rotations + one all_to_all mirror) ----
-from heat_tpu.spatial.distance import _ring_dist_sym, _sq_euclidian_fast
+# --- the distance engine's tile program (fori rotations of the operand shard) ----
+from heat_tpu.spatial.distance import _sq_euclidian_fast, _tile_program
 
 def build_ring():
-    x = jax.device_put(
-        jnp.zeros((2 * p, 4), jnp.float32), comm.sharding(2, 0)
-    )
-    _ring_dist_sym(x, _sq_euclidian_fast, comm)  # jit+compile inside
-    return None  # timing only; HLO not exposed by the helper
+    fn = _tile_program(comm.mesh, comm.axis_name, p, _sq_euclidian_fast)
+    x = jnp.zeros((2 * p, 4), jnp.float32)
+    return fn.lower(x, x).compile().as_text()
 
-timed("ring_sym", build_ring)
+timed("ring", build_ring)
 
 # --- fused distributed triangular solve ----------------------------------
 from heat_tpu.core.linalg.solver import _tri_solve_program
@@ -182,7 +180,7 @@ class TestMesh64Compile(unittest.TestCase):
         cls.out = json.loads(proc.stdout.strip().splitlines()[-1])
 
     NAMES = (
-        "panel_qr", "sort", "exscan", "ring_sym", "tri_solve", "det", "cholesky",
+        "panel_qr", "sort", "exscan", "ring", "tri_solve", "det", "cholesky",
         "lloyd10", "lasso_gram_pre", "lasso_gram_sweep",
     )
 
@@ -206,6 +204,9 @@ class TestMesh64Compile(unittest.TestCase):
             ("panel_qr", 8),
             ("sort", 12),
             ("exscan", 6),
+            # one collective-permute of the operand shard in the loop body
+            # (a start/done pair at most), whatever p
+            ("ring", 2),
             ("tri_solve", 6),
             ("det", 8),
             ("cholesky", 8),

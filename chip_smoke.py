@@ -135,10 +135,8 @@ def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol
         out["shard_shapes"] = sorted({tuple(s.data.shape) for s in shards})
         assert len(set(out["shard_devices"])) == comm.size, out
         assert len(out["shard_shapes"]) == 1, out
-        run = lloyd._sharded_run_fn(
-            comm.mesh, comm.axis_name, comm.size, k, n, min(8, iters), interpret
-        )
-        compiled = run.lower(x.parray, fused.cluster_centers_.larray).compile()
+        run = lloyd._sharded_run_fn(comm.mesh, comm.axis_name, comm.size, k, n, interpret)
+        compiled = run.lower(x.parray, fused.cluster_centers_.larray, iters, 0.0).compile()
         out["lloyd_collectives"] = telemetry.hlo_collective_counts(compiled.as_text())
         assert out["lloyd_collectives"].get("all-reduce", 0) >= 1, out
         # the kernel reads each device's rows in place: a padded copy of them

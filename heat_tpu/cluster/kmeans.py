@@ -26,12 +26,14 @@ from ._kcluster import _KCluster
 __all__ = ["KMeans"]
 
 
-@partial(jax.jit, static_argnames=("k", "n_steps"))
-def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
-    """``n_steps`` fused Lloyd iterations in ONE XLA program — amortizes the
-    per-dispatch latency (the reference pays an MPI round per iteration; here
-    the host pays one dispatch per *program*, so fusing the loop is the
-    TPU-side analog of batching the collectives).
+@partial(jax.jit, static_argnames=("k",))
+def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, max_iter, tol):
+    """One fit's Lloyd iterations in ONE XLA program, convergence check
+    included (``ops/lloyd.py::_steps``, the loop and the rule the fused
+    programs run by; ``max_iter`` and ``tol`` are traced scalars) — the
+    reference pays an MPI round per iteration, here the host pays one dispatch
+    and one read per *fit*. Returns ``(centers, labels, inertia, shift,
+    n_iter)``, labels and inertia the last iteration's.
 
     The |x|² term of the quadratic-expansion distance is loop-invariant: the
     argmin over centers only sees −2x·cᵀ + |c|², and the inertia needs just
@@ -39,15 +41,11 @@ def _lloyd_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
     bandwidth — per iteration."""
     xsq_sum = jnp.sum(data * data)
 
-    def body(i, carry):
-        centers, _, _, _ = carry
-        return _lloyd_iter(data, centers, k, xsq_sum)
+    def step(c, last):
+        new_c, labels, inertia, shift = _lloyd_iter(data, c, k, xsq_sum)
+        return (new_c, shift, labels, inertia) if last else (new_c, shift)
 
-    acc = jnp.zeros((), data.dtype)
-    out = jax.lax.fori_loop(
-        0, n_steps, body, (centers, jnp.zeros(data.shape[0], jnp.int32), acc, acc)
-    )
-    return out
+    return _lloyd._steps(step, centers, max_iter, tol)
 
 
 def _lloyd_iter(data: jax.Array, centers: jax.Array, k: int, xsq_sum=None):
@@ -81,11 +79,22 @@ class KMeans(_KCluster):
     reference) selects the single-pass samples-in-lanes pallas Lloyd kernel
     (ops/lloyd.py): ``None`` auto-selects it on TPU backends, where it reads
     the operand once per iteration where the jnp path reads it twice, and
-    the last pass of each program writes the labels it assigned (``labels_``
-    is the assignment ``inertia_`` is summed over; no separate label pass);
+    the last pass of a fit writes the labels it assigned (``labels_`` is the
+    assignment ``inertia_`` is summed over; no separate label pass);
     ``True`` forces it (interpret mode off-TPU — the testing path), ``False``
     pins the jnp oracle path. A kernel that fails to lower or run raises:
     there is no fallback from the fused path to the oracle.
+
+    When a fit stops, on every path: iterations run until the shift of an
+    iteration (the squared distance its centres moved, all clusters added
+    up) is at most ``tol`` or ``max_iter - 1`` have run, and one more
+    iteration then assigns ``labels_``, sums ``inertia_`` and moves the
+    centres a last time; ``n_iter_`` counts it. That is the reference's
+    per-iteration check plus one iteration, the same fixed point; a ``tol``
+    the shift never reaches (a negative one) runs exactly ``max_iter``. The
+    check runs on the device, inside the one program a fit dispatches
+    (``ops/lloyd.py::_steps``): the host reads ``n_iter_`` and ``inertia_``
+    once, when the fit is done.
 
     ``inertia_`` is the Σ d² of ``labels_`` to the centres that went into the
     last iteration. The fused path takes it from that pass's float32
@@ -157,9 +166,10 @@ class KMeans(_KCluster):
         While ``telemetry.tracing()`` the fit is a ``heat.kmeans.fit`` span
         (stats ``mode``, ``n``, ``f``, ``k``) whose children lie side by
         side: ``.init`` (the initial centres), ``.prepare`` (the dtype cast;
-        no pass over the rows: each fused program reads them in place),
-        ``.dispatch`` (each Lloyd program's call), ``.sync`` (each blocking
-        read of the shift, and of the inertia), ``.wrap`` (centres and labels
+        no pass over the rows: the fused program reads them in place),
+        ``.dispatch`` (the call of the fit's one Lloyd program, which stops
+        by the class docstring's rule), ``.sync`` (the one blocking read, of
+        ``n_iter_`` and ``inertia_`` together), ``.wrap`` (centres and labels
         back into ``DNDarray``s); the same intervals add to ``fusion.cache_stats()``'s
         ``phase_kmeans_*`` keys."""
         if not isinstance(x, DNDarray):
@@ -185,10 +195,11 @@ class KMeans(_KCluster):
         """:meth:`fit` past its checks, on the dispatch ``_fused_mode``
         resolved. ``mark(name)`` opens the fit's next phase
         (``telemetry.Phases.phase``; nothing when the fit is not traced).
-        Returns the Lloyd programs dispatched, the blocking host reads made
-        and the XLA label passes over the rows that those programs ran (the
-        fused programs' labels are their last kernel pass's, the jnp
-        program's ride in its loop carry: none)."""
+        Returns the Lloyd programs dispatched and the blocking host reads
+        made (one each: the fit is one program, which checks convergence
+        itself) and the XLA label passes over the rows that the program ran
+        (a fused program's labels are its last kernel pass's, the jnp
+        program's its last iteration's: none)."""
         k, n_global = self.n_clusters, int(x.shape[0])
         mark("init")
         centers = self._initialize_cluster_centers(x)
@@ -207,38 +218,20 @@ class KMeans(_KCluster):
             data = x.larray.astype(ddtype)
         centers = jnp.asarray(centers, fdtype)
 
-        # iterations run in fused chunks of up to 8 per dispatch; convergence
-        # is checked at chunk boundaries (coarser than the reference's
-        # per-iteration check, identical fixed point)
-        labels = None
-        inertia = None
-        done = dispatches = syncs = 0
-        while done < self.max_iter:
-            chunk = min(8, self.max_iter - done)
-            mark("dispatch")
-            if mode == "single":
-                centers, labels, inertia, shift = _lloyd.fused_lloyd_run(
-                    data, centers, k, chunk, interpret=interpret
-                )
-            elif mode == "sharded":
-                centers, labels, inertia, shift = _lloyd.fused_lloyd_run_sharded(
-                    data, centers, k, x.comm, n_global, chunk, interpret=interpret
-                )
-            else:
-                centers, labels, inertia, shift = _lloyd_run(data, centers, k, chunk)
-            dispatches += 1
-            done += chunk
-            mark("sync")
-            syncs += 1
-            if float(shift) <= self.tol:
-                break
-
-        self._n_iter = done
-        if inertia is not None:
-            mark("sync")
-            syncs += 1
-            inertia = float(inertia)
-        self._inertia = inertia
+        mark("dispatch")
+        max_iter, tol = int(self.max_iter), float(self.tol)
+        if mode == "single":
+            out = _lloyd.fused_lloyd_run(data, centers, k, max_iter, tol, interpret=interpret)
+        elif mode == "sharded":
+            out = _lloyd.fused_lloyd_run_sharded(
+                data, centers, k, x.comm, n_global, max_iter, tol, interpret=interpret
+            )
+        else:
+            out = _lloyd_run(data, centers, k, max_iter, tol)
+        centers, labels, inertia, _, n_iter = out
+        mark("sync")
+        n_iter, inertia = jax.device_get((n_iter, inertia))
+        self._n_iter, self._inertia = int(n_iter), float(inertia)
         mark("wrap")
         self._cluster_centers = DNDarray(
             _ensure_split(centers, None, x.comm),
@@ -249,5 +242,5 @@ class KMeans(_KCluster):
             x.comm,
         )
         self._labels = self._wrap_labels(labels, x)
-        epilogues = dispatches * _lloyd.RUN_LABEL_EPILOGUES if mode else 0
-        return {"dispatches": dispatches, "syncs": syncs, "label_epilogues": epilogues}
+        epilogues = _lloyd.RUN_LABEL_EPILOGUES if mode else 0
+        return {"dispatches": 1, "syncs": 1, "label_epilogues": epilogues}

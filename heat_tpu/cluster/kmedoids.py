@@ -44,8 +44,9 @@ def _medoid_step(data: jax.Array, centers: jax.Array, k: int):
 
 @partial(jax.jit, static_argnames=("k", "n_steps"))
 def _medoid_run(data: jax.Array, centers: jax.Array, k: int, n_steps: int):
-    """``n_steps`` fused iterations in ONE XLA program (the kmeans
-    ``_lloyd_run`` pattern: one dispatch per chunk instead of per step)."""
+    """``n_steps`` fused iterations in ONE XLA program: one dispatch per
+    chunk instead of per step (``KMeans`` runs a whole fit as one
+    program that checks convergence itself; no cell runs this estimator)."""
 
     def body(i, carry):
         centers, _, _, _ = carry
@@ -90,7 +91,7 @@ class KMedoids(_KCluster):
         done = 0
         while done < self.max_iter:
             # fused chunks of up to 8 iterations per dispatch; convergence
-            # checked at chunk boundaries (the kmeans pattern). Medoids snap
+            # checked at chunk boundaries. Medoids snap
             # to data points, so exact-zero shift is the fixed point.
             chunk = min(8, self.max_iter - done)
             centers, labels, inertia, shift = _medoid_run(data, centers, self.n_clusters, chunk)

@@ -169,7 +169,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_fusion_phase_seconds_total", (_C, "Host time of timed forced results, by phase (admit/walk/lookup/dispatch/install/place/read).", ["phase"])),
         ("heat_tpu_kmeans_fits_total", (_C, "KMeans fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_kmeans_dispatches_total", (_C, "Lloyd programs dispatched by timed KMeans fits.", [])),
-        ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (shift, inertia) made by timed KMeans fits.", [])),
+        ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (n_iter and inertia together) made by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_label_epilogues_total", (_C, "XLA label passes over the rows run by the Lloyd programs of timed KMeans fits.", [])),
         ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/wrap).", ["phase"])),
         ("heat_tpu_cdist_calls_total", (_C, "Distance-matrix calls (cdist/rbf/manhattan) whose phases were timed (telemetry on or a profiler session recording).", [])),

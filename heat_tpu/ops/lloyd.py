@@ -63,8 +63,18 @@ single-device (its pallas_call has no partitioning spec);
 multi-chip form: a shard_map running the kernel per device and merging the
 (f, k)/(k, 1) accumulators with one psum per iteration (the last one's
 Σ|x|² with them) — the exact collective budget of the jnp path. In the
-sharded run the whole fori_loop lives INSIDE the shard_map. A program needs
-nothing from the one before it but the centres.
+sharded run the whole loop lives INSIDE the shard_map.
+
+One program is one fit, and convergence is checked on the device
+(:func:`_steps`, the one loop of all three modes, the jnp path's too).
+The rule: iterations run until the shift of an iteration (the squared
+distance its centres moved, all clusters added up) is at most ``tol`` or
+``max_iter - 1`` have run, and one more iteration then assigns the labels,
+sums the inertia and moves the centres a last time; ``n_iter`` counts it.
+That is the reference's per-iteration check plus one iteration; a ``tol`` the
+shift never reaches (a negative one) runs exactly ``max_iter``. ``max_iter``
+and ``tol`` are traced scalars: the host dispatches once and reads once, and
+one compiled program per (shape, k) serves every value of them.
 """
 
 from __future__ import annotations
@@ -343,37 +353,68 @@ def _inertia(xsq_sum, sumsT, counts, centers):
     return jnp.maximum(xsq_sum + scores, 0.0)
 
 
-def _steps(accumulate, centers, n_steps: int):
-    """``n_steps`` Lloyd iterations by ``accumulate(centers, last)`` (one
-    kernel pass, merged over devices where there are several:
+def _kernel_step(accumulate):
+    """The ``step`` of :func:`_steps` on the kernel: ``accumulate(centers,
+    last)`` is one kernel pass, merged over devices where there are several:
     ``(sumsT, counts)``, with the pass's Σ|x|² and labels behind them when it
-    is the ``last``). The last step is peeled off the loop: it is the one pass
-    that writes labels and whose inertia anybody reads. Returns
-    ``(centers, labels, inertia, shift)``."""
-    centers = jax.lax.fori_loop(
-        0, n_steps - 1, lambda i, c: _finalize(*accumulate(c, False), c)[0], centers
+    is the ``last``."""
+
+    def step(centers, last):
+        sumsT, counts, *rest = accumulate(centers, last)
+        moved = _finalize(sumsT, counts, centers)
+        if not last:
+            return moved
+        xsq_sum, labels = rest
+        return (*moved, labels, _inertia(xsq_sum, sumsT, counts, centers))
+
+    return step
+
+
+def _steps(step, centers, max_iter, tol):
+    """One fit's iterations, the one loop of all three Lloyd modes (the module
+    docstring's rule). ``step(centers, last)`` is one iteration: ``(new
+    centres, shift)``, with the assignment it made and its inertia behind them
+    when it is the ``last``. Plain steps run under a ``while_loop`` until one's
+    shift is at most ``tol`` or ``max_iter - 1`` have run; the carry holds the
+    outcome of ``shift <= tol``, not the shift, so a NaN shift keeps iterating
+    as a host's ``float(shift) <= tol`` would. The last step is peeled off the
+    loop: it is the one pass that writes labels and whose inertia anybody
+    reads. ``max_iter`` and ``tol`` are traced scalars. Returns ``(centers,
+    labels, inertia, shift, n_iter)``, the shift the last step's."""
+
+    def body(carry):
+        done, c, _ = carry
+        new_c, shift = step(c, False)
+        return done + 1, new_c, jnp.logical_not(shift <= tol)
+
+    done, centers, _ = jax.lax.while_loop(
+        lambda carry: jnp.logical_and(carry[0] < max_iter - 1, carry[2]),
+        body,
+        (jnp.zeros((), jnp.int32), centers, jnp.ones((), bool)),
     )
-    sumsT, counts, xsq_sum, labels = accumulate(centers, True)
-    new_centers, shift = _finalize(sumsT, counts, centers)
-    return new_centers, labels, _inertia(xsq_sum, sumsT, counts, centers), shift
+    new_centers, shift, labels, inertia = step(centers, True)
+    return new_centers, labels, inertia, shift, done + 1
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n_steps", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def fused_lloyd_run(
-    data: jax.Array, centers: jax.Array, k: int, n_steps: int, interpret: bool = False
+    data: jax.Array, centers: jax.Array, k: int, max_iter, tol, interpret: bool = False
 ):
-    """``n_steps`` fused iterations in one XLA program (the pallas analog of
+    """One fit's fused iterations in one XLA program (the pallas analog of
     ``cluster.kmeans._lloyd_run``) that reads ``data`` in place: one kernel
-    pass per step and no other pass over the rows. The last pass also writes
-    its labels: the assignment against the last iteration's input centers (the
-    jnp oracle's exact label convention), which the inertia belongs to."""
+    pass per iteration and no other pass over the rows, as many iterations as
+    the traced scalars ``max_iter`` and ``tol`` let run (:func:`_steps`): one
+    compiled program per (shape, k) serves every value of them. The last pass
+    also writes its labels: the assignment against the last iteration's input
+    centers (the jnp oracle's exact label convention), which the inertia
+    belongs to. Returns ``(centers, labels, inertia, shift, n_iter)``."""
     xT = _prepare(data)
     n_valid = jnp.asarray(data.shape[0], jnp.int32)
 
     def accumulate(c, last):
         return _kernel_call_T(xT, c, k, n_valid, interpret, last)
 
-    return _steps(accumulate, centers, n_steps)
+    return _steps(_kernel_step(accumulate), centers, max_iter, tol)
 
 
 def fused_lloyd_run_sharded(
@@ -382,35 +423,36 @@ def fused_lloyd_run_sharded(
     k: int,
     comm,
     n_global: int,
-    n_steps: int,
+    max_iter,
+    tol,
     interpret: bool = False,
 ):
-    """``n_steps`` fused sharded iterations in ONE XLA program — the
-    multi-chip analog of :func:`fused_lloyd_run`.
+    """One fit's fused sharded iterations in ONE XLA program — the multi-chip
+    analog of :func:`fused_lloyd_run`.
 
     ``data`` is the PHYSICAL payload (``DNDarray.parray``): row count a
     multiple of the mesh size, suffix-padded when the logical ``n_global``
     is ragged. Each device runs the single-pass kernel on its own rows, read
-    in place — masking its share of the global padding — with the fori_loop
+    in place — masking its share of the global padding — with the while_loop
     of kernel steps INSIDE the shard_map and one psum of the (f, k)/(k, 1)
-    accumulators per step (the last step's Σ|x|² beside them). Labels are
-    each device's last pass's output for its own rows (no collective),
-    row-sharded like ``data`` and sliced to the logical length ``n_global``.
-    Cached per (mesh, k, n_global, n_steps)."""
-    fn = _sharded_run_fn(
-        comm.mesh, comm.axis_name, comm.size, k, int(n_global), int(n_steps), bool(interpret)
-    )
-    return fn(data, centers)
+    accumulators per step (the last step's Σ|x|² beside them). The shift the
+    loop's condition reads is made from the merged accumulators, so every
+    device takes the same number of trips; ``max_iter`` and ``tol`` go in
+    replicated. Labels are each device's last pass's output for its own rows
+    (no collective), row-sharded like ``data`` and sliced to the logical
+    length ``n_global``. Cached per (mesh, k, n_global)."""
+    fn = _sharded_run_fn(comm.mesh, comm.axis_name, comm.size, k, int(n_global), bool(interpret))
+    return fn(data, centers, max_iter, tol)
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_run_fn(mesh, axis, p, k, n_global, n_steps, interpret):
+def _sharded_run_fn(mesh, axis, p, k, n_global, interpret):
     """Jitted sharded run, cached per static config (the
     attention.py:_ring_attention_fn closure-cache pattern — comm objects are
     unhashable, their mesh/axis are)."""
     from jax.sharding import PartitionSpec as P
 
-    def device_run(xl, c0):
+    def device_run(xl, c0, max_iter, tol):
         local_rows = xl.shape[0]
         idx = jax.lax.axis_index(axis)
         local_valid = jnp.clip(n_global - idx * local_rows, 0, local_rows)
@@ -423,17 +465,17 @@ def _sharded_run_fn(mesh, axis, p, k, n_global, n_steps, interpret):
             xsq_sum, labels = rest
             return (*jax.lax.psum((sumsT, counts, xsq_sum), axis), labels)
 
-        return _steps(accumulate, c0.astype(jnp.float32), n_steps)
+        return _steps(_kernel_step(accumulate), c0.astype(jnp.float32), max_iter, tol)
 
     @jax.jit
-    def run(data, centers):
-        new_c, labels, inertia, shift = jax.shard_map(
+    def run(data, centers, max_iter, tol):
+        new_c, labels, inertia, shift, n_iter = jax.shard_map(
             device_run,
             mesh=mesh,
-            in_specs=(P(axis, None), P()),
-            out_specs=(P(), P(axis), P(), P()),
+            in_specs=(P(axis, None), P(), P(), P()),
+            out_specs=(P(), P(axis), P(), P(), P()),
             check_vma=False,  # pallas_call outputs carry no vma annotation
-        )(data, centers)
-        return new_c.astype(centers.dtype), labels[:n_global], inertia, shift
+        )(data, centers, max_iter, tol)
+        return new_c.astype(centers.dtype), labels[:n_global], inertia, shift, n_iter
 
     return run

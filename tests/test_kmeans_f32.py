@@ -31,7 +31,7 @@ import pytest
 
 import heat_tpu as ht
 from chipbench import spec
-from heat_tpu.cluster import KMeans
+from heat_tpu.cluster import KMeans, kmeans
 from heat_tpu.cluster.kmeans import _lloyd_iter
 from heat_tpu.core import fusion, telemetry
 from heat_tpu.ops import lloyd
@@ -56,15 +56,16 @@ def rows():
     return data, data[np.sort(rng.choice(N, size=K, replace=False))]
 
 
-def fit(mode, data, init, monkeypatch, dtype=None, iters=ITERS):
+def fit(mode, data, init, monkeypatch, dtype=None, iters=ITERS, tol=-1.0):
     """One ``KMeans.fit`` through the public API on the path ``mode``: the
     sharded kernel in interpret mode on the suite's virtual devices, the
     single-device kernel likewise (steered here: off the chip ``_fused_mode``
-    takes it on one device only), or the jnp path."""
+    takes it on one device only), or the jnp path. ``tol`` is the cell's: one
+    that no shift reaches, so that all ``iters`` iterations run."""
     x = ht.array(data, split=None if mode == "single" else 0)
     if dtype is not None:
         x = x.astype(dtype)
-    km = KMeans(n_clusters=K, init=ht.array(init), max_iter=iters, tol=0.0,
+    km = KMeans(n_clusters=K, init=ht.array(init), max_iter=iters, tol=tol,
                 use_fused=False if mode == "jnp" else True)
     if mode == "single":
         monkeypatch.setattr(KMeans, "_fused_mode", lambda self, x: ("single", True))
@@ -109,6 +110,110 @@ def test_bfloat16_cast_fit_falls_outside_the_limits(mode, rows, want, monkeypatc
     assert labels_differ > 0
 
 
+# -- ISSUE 34: one program a fit, the convergence check on the device. The rule
+# (``KMeans``' docstring) against a plain NumPy Lloyd, on every path a fit can take.
+TOL, BLOBS_N = 1e-4, 2048
+
+
+@pytest.fixture(scope="module")
+def blobs():
+    """Eight well-separated clusters and eight seeded rows to start from, some
+    of them of one cluster, so that the centres travel for several iterations
+    before the assignment stands still and the shift reads exactly 0."""
+    rng = np.random.default_rng(34)
+    means = 8.0 * rng.standard_normal((K, F))
+    data = (means[rng.integers(0, K, BLOBS_N)] + rng.standard_normal((BLOBS_N, F))).astype(np.float32)
+    return data, data[np.sort(rng.choice(BLOBS_N, size=K, replace=False))]
+
+
+def numpy_lloyd(data, init, iters):
+    """Plain Lloyd in float64: per iteration the centres that went in, the
+    assignment against them, its sum of squared distances, and the shift."""
+    x, c = data.astype(np.float64), init.astype(np.float64)
+    steps = []
+    for _ in range(iters):
+        d = ((x[:, None, :] - c[None, :, :]) ** 2).sum(axis=2)
+        labels = d.argmin(axis=1)
+        new = np.stack([x[labels == j].mean(axis=0) if (labels == j).any() else c[j] for j in range(len(c))])
+        steps.append((c, labels, d.min(axis=1).sum(), ((new - c) ** 2).sum()))
+        c = new
+    return steps, c
+
+
+@pytest.fixture(scope="module")
+def blobs_want(blobs):
+    """(n_iter, labels, inertia, centres) the rule gives on ``blobs``: one
+    iteration more than the first whose shift is at most ``TOL``."""
+    steps, _ = numpy_lloyd(*blobs, 40)
+    shifts = [shift for *_, shift in steps]
+    first = next(i for i, shift in enumerate(shifts, 1) if shift <= TOL)
+    assert first >= 3 and first % 8 != 7, shifts  # the centres travel, and no multiple of 8 would do
+    assert not any(TOL / 10 < shift < TOL * 10 for shift in shifts), shifts  # float32 decides as float64 does
+    _, labels, inertia, _ = steps[first]
+    return first + 1, labels, inertia, steps[first + 1][0]
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_fit_stops_one_iteration_after_the_first_shift_within_tol(mode, blobs, blobs_want, monkeypatch):
+    """The same ``n_iter_`` in all three modes (each against the one NumPy
+    run), no multiple of 8, and ``labels_`` / ``inertia_`` those of the last
+    iteration's input centres."""
+    n_iter, labels, inertia, centers = blobs_want
+    km = fit(mode, *blobs, monkeypatch, iters=300, tol=TOL)
+    assert km.n_iter_ == n_iter
+    np.testing.assert_array_equal(km.labels_.numpy(), labels)
+    np.testing.assert_allclose(km.inertia_, inertia, rtol=1e-5)
+    np.testing.assert_allclose(km.cluster_centers_.numpy(), centers, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_max_iter_of_one_runs_the_labelled_pass_alone(mode, blobs, monkeypatch):
+    data, init = blobs
+    ((_, labels, inertia, _),), centers = numpy_lloyd(data, init, 1)
+    km = fit(mode, data, init, monkeypatch, iters=1, tol=TOL)
+    assert km.n_iter_ == 1
+    np.testing.assert_array_equal(km.labels_.numpy(), labels)
+    np.testing.assert_allclose(km.inertia_, inertia, rtol=1e-5)
+    np.testing.assert_allclose(km.cluster_centers_.numpy(), centers, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_a_nan_shift_does_not_stop_the_fit(mode, blobs, monkeypatch):
+    """A NaN row makes a NaN centre and a NaN shift, which is not "at most
+    ``tol``": the fit runs out its ``max_iter``, as the host's
+    ``float(shift) <= tol`` had it."""
+    data, init = blobs
+    poisoned = data.copy()
+    poisoned[7] = np.nan
+    km = fit(mode, poisoned, init, monkeypatch, iters=9, tol=TOL)
+    assert km.n_iter_ == 9 and np.isnan(km.inertia_)
+
+
+def _programs(mode):
+    """How many programs the path's jitted function holds (after a fit: the
+    sharded path's function is found in its cache, not made here)."""
+    if mode == "jnp":
+        return kmeans._lloyd_run._cache_size()
+    if mode == "single":
+        return lloyd.fused_lloyd_run._cache_size()
+    comm = ht.get_comm()
+    run = lloyd._sharded_run_fn(comm.mesh, comm.axis_name, comm.size, K, BLOBS_N, True)
+    return lloyd._sharded_run_fn.cache_info().currsize, run._cache_size()
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_max_iter_and_tol_are_operands_and_not_programs(mode, blobs, monkeypatch):
+    """``max_iter`` and ``tol`` are traced scalars: fits with other values of
+    them add no entry to the jitted program's cache (ROADMAP A4: a 30-iteration
+    fit compiled an 8-step and a 6-step program)."""
+    data, init = blobs
+    fit(mode, data, init, monkeypatch, iters=30, tol=TOL)
+    before = _programs(mode)
+    assert fit(mode, data, init, monkeypatch, iters=12, tol=-1.0).n_iter_ == 12
+    assert fit(mode, data, init, monkeypatch, iters=30, tol=1e-2).n_iter_ < 12
+    assert _programs(mode) == before
+
+
 def _dots(jaxpr, out):
     """Every ``dot_general`` equation of ``jaxpr``, descending into the
     jaxprs its equations carry (``pallas_call``, ``jit``, ``while`` ...)."""
@@ -135,8 +240,8 @@ def _float32_products_only(eqn) -> bool:
 
 
 TRACED = {
-    "fused_lloyd_run": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 2),
-    "fused_lloyd_run_of_one_step": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 1),
+    "fused_lloyd_run": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 2, -1.0),
+    "fused_lloyd_run_of_one_step": lambda x, c: lloyd.fused_lloyd_run(x, c, K, 1, -1.0),
     "lloyd_iter": lambda x, c: _lloyd_iter(x, c, K),
 }
 
@@ -189,11 +294,11 @@ def test_counters_of_one_fit_with_telemetry_on(mode, rows, monkeypatch):
         km = fit(mode, data[:1024], init, monkeypatch)
     got = _kmeans_delta(before)
     assert km.n_iter_ == ITERS
-    # four programs of up to 8 iterations, a blocking read of the shift after
-    # each, and one of the inertia
-    assert (got["phase_kmeans_fits"], got["phase_kmeans_dispatches"], got["phase_kmeans_syncs"]) == (1, 4, 5)
-    # no program runs an XLA label pass over the rows: the fused programs' last
-    # kernel pass writes the labels, the jnp program carries them
+    # one program a fit, which checks convergence itself, and one blocking read
+    # of n_iter_ and inertia_ together (ISSUE 34; four and five before)
+    assert (got["phase_kmeans_fits"], got["phase_kmeans_dispatches"], got["phase_kmeans_syncs"]) == (1, 1, 1)
+    # the program runs no XLA label pass over the rows: the fused program's last
+    # kernel pass writes the labels, the jnp program's last iteration gives them
     assert got["phase_kmeans_label_epilogues"] == 0
     for name in fusion._KMEANS_PHASES:
         assert got[f"phase_kmeans_{name}_ns"] > 0, name
@@ -252,7 +357,7 @@ def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
     assert (parent[3]["mode"], int(parent[3]["n"]), int(parent[3]["f"]), int(parent[3]["k"])) == ("jnp", 1024, F, K)
     children = sorted((sp for sp in spans if sp is not parent), key=lambda sp: sp[1])
     names = [sp[0].rsplit(".", 1)[1] for sp in children]
-    assert names == ["init", "prepare"] + ["dispatch", "sync"] * 4 + ["wrap"]
+    assert names == ["init", "prepare", "dispatch", "sync", "wrap"]
     assert all(parent[1] <= sp[1] and sp[2] <= parent[2] for sp in children)
     assert all(a[2] <= b[1] for a, b in zip(children, children[1:])), "children overlap"
 
@@ -354,7 +459,9 @@ def test_nothing_but_the_kernel_reads_the_rows_of_a_run(one_v5e, dtype, short):
     n = 3 * lloyd._block_cols(F, K, jnp.dtype(dtype).itemsize) - 77
     x = jax.ShapeDtypeStruct((n, F), jnp.dtype(dtype), sharding=one_v5e)
     c = jax.ShapeDtypeStruct((K, F), jnp.float32, sharding=one_v5e)
-    text = _compiled_text(jax.jit(lambda x, c: lloyd.fused_lloyd_run(x, c, K, 3)), x, c)
+    max_iter = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_v5e)  # traced operands, as a fit's are
+    tol = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_v5e)
+    text = _compiled_text(jax.jit(lambda x, c, m, t: lloyd.fused_lloyd_run(x, c, K, m, t)), x, c, max_iter, tol)
     opcodes = _opcodes_on_rows(text, short, n, F)
     assert set(opcodes) <= ROWS_PLUMBING and opcodes["bitcast"] == 1 and opcodes["custom-call"] == 2, opcodes
     assert text.count('custom_call_target="tpu_custom_call"') == 2
@@ -370,8 +477,10 @@ def test_nothing_but_the_kernel_reads_the_rows_of_a_sharded_run(v5e_2x2):
     local = 2 * lloyd._block_cols(F, K) + 77
     x = jax.ShapeDtypeStruct((4 * local, F), jnp.float32, sharding=NamedSharding(mesh, P("x", None)))
     c = jax.ShapeDtypeStruct((K, F), jnp.float32, sharding=NamedSharding(mesh, P()))
-    run = lloyd._sharded_run_fn(mesh, "x", 4, K, 4 * local - 3, 3, False)
-    text = _compiled_text(run, x, c)
+    max_iter = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
+    tol = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, P()))
+    run = lloyd._sharded_run_fn(mesh, "x", 4, K, 4 * local - 3, False)
+    text = _compiled_text(run, x, c, max_iter, tol)
     opcodes = _opcodes_on_rows(text, "f32", local, F)
     assert set(opcodes) <= ROWS_PLUMBING and opcodes["bitcast"] == 1 and opcodes["custom-call"] == 2, opcodes
     assert "all-reduce" in text

@@ -36,17 +36,80 @@ def test_run_labels_are_the_assignment_to_the_last_steps_input_centres(path, n_s
     n, f, k = 512 * comm.size + 5, 8, 5  # ragged: a masked tail, a physical pad when sharded
     data_np, centers = _rows(21, n, f, k)
     if path == "single":
-        got = fused_lloyd_run(jnp.asarray(data_np), centers, k, n_steps, interpret=True)
+        got = fused_lloyd_run(jnp.asarray(data_np), centers, k, n_steps, -1.0, interpret=True)
     else:
         x = ht.array(data_np, split=0)
-        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, n_steps, interpret=True)
-    ref = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps)
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, n_steps, -1.0, interpret=True)
+    ref = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps, -1.0)
     assert got[1].shape == (n,) and got[1].dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-4, atol=1e-4)
     # and spelled out: one more oracle step from the centres of n_steps - 1
-    before = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps - 1)[0] if n_steps > 1 else centers
+    before = _lloyd_run(jnp.asarray(data_np), centers, k, n_steps - 1, -1.0)[0] if n_steps > 1 else centers
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(_lloyd_iter(jnp.asarray(data_np), before, k)[1]))
+
+
+@pytest.mark.parametrize("path", ["single", "sharded", "jnp"])
+def test_unreachable_tol_runs_max_iter_steps_bit_equal_to_as_many_programs_of_one(path):
+    """With a ``tol`` no shift reaches the one program runs exactly
+    ``max_iter`` iterations, through the same passes from the same centres as
+    ``max_iter`` programs of one iteration each: centres bit-equal, the labels
+    and the inertia the last of those programs'."""
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+    from heat_tpu.cluster.kmeans import _lloyd_run
+    from heat_tpu.ops.lloyd import fused_lloyd_run, fused_lloyd_run_sharded
+
+    comm = ht.get_comm()
+    n, f, k, max_iter = 512 * comm.size + 5, 8, 5, 5
+    data_np, centers = _rows(34, n, f, k)
+    if path == "single":
+        run = lambda c, m: fused_lloyd_run(jnp.asarray(data_np), c, k, m, -1.0, interpret=True)
+    elif path == "sharded":
+        payload = ht.array(data_np, split=0).parray
+        run = lambda c, m: fused_lloyd_run_sharded(payload, c, k, comm, n, m, -1.0, interpret=True)
+    else:
+        run = lambda c, m: _lloyd_run(jnp.asarray(data_np), c, k, m, -1.0)
+    got = run(centers, max_iter)
+    assert int(got[4]) == max_iter
+    one = (centers,)
+    for _ in range(max_iter):
+        one = run(one[0], 1)
+        assert int(one[4]) == 1
+    for a, b in zip(got[:4], one[:4]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "shifts,max_iter,tol,n_iter",
+    [
+        ([np.nan] * 9, 7, 1e-4, 7),  # a NaN shift is not "at most tol": the loop runs out
+        ([np.nan] * 9, 7, np.inf, 7),
+        ([3.0, 2.0, 1e-5, 0.0, 0.0], 7, 1e-4, 4),  # one iteration more than the first within tol
+        ([3.0, 2.0, 1e-4, 0.0, 0.0], 7, 1e-4, 4),  # "at most": equal stops
+        ([0.0] * 9, 7, 1e-4, 2),
+        ([0.0] * 9, 1, 1e-4, 1),  # the labelled step alone
+        ([0.0] * 9, 7, -1.0, 7),  # a tol no shift reaches
+    ],
+)
+def test_the_loop_rule_on_made_up_shifts(shifts, max_iter, tol, n_iter):
+    """``_steps`` alone, on a step that counts itself and reads its shift from
+    a list: how many steps run, and that only the last is asked for labels."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.ops.lloyd import _steps
+
+    table = jnp.asarray(shifts, jnp.float32)
+
+    def step(c, last):
+        moved = (c + 1, table[c])
+        return (*moved, c, 10.0 * c) if last else moved
+
+    centers, labels, inertia, shift, got = jax.jit(lambda m, t: _steps(step, jnp.zeros((), jnp.int32), m, t))(max_iter, tol)
+    assert (int(got), int(centers), int(labels), float(inertia)) == (n_iter, n_iter, n_iter - 1, 10.0 * (n_iter - 1))
+    np.testing.assert_array_equal(np.asarray(shift), np.float32(shifts[n_iter - 1]))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -83,7 +146,7 @@ def test_bfloat16_labels_are_the_argmin_of_the_streamed_scores():
     n, f, k = 4096, 16, 4
     data_np, centers = _rows(11, n, f, k)
     low = jnp.asarray(data_np).astype(jnp.bfloat16)
-    got = np.asarray(fused_lloyd_run(low, centers, k, 1, interpret=True)[1])
+    got = np.asarray(fused_lloyd_run(low, centers, k, 1, -1.0, interpret=True)[1])
     c64 = np.asarray(centers, np.float64)
     cq = np.asarray((-2.0 * centers).astype(jnp.bfloat16).astype(jnp.float32), np.float64)
     score = (c64 * c64).sum(axis=1)[None, :] + np.asarray(low.astype(jnp.float32), np.float64) @ cq.T
@@ -114,7 +177,7 @@ def test_rows_are_read_in_place_whatever_their_number(blocks, over, mode):
     n, n_steps = blocks * _block_cols(_F, _K) + over, 3
     data_np, centers = _rows(32, n, _F, _K)
     if mode == "single":
-        got = fused_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps, interpret=True)
+        got = fused_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps, -1.0, interpret=True)
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -122,13 +185,13 @@ def test_rows_are_read_in_place_whatever_their_number(blocks, over, mode):
         physical = np.full((-(-n // comm.size) * comm.size, _F), np.nan, np.float32)
         physical[:n] = data_np
         payload = jax.device_put(physical, NamedSharding(comm.mesh, P(comm.axis_name, None)))
-        got = fused_lloyd_run_sharded(payload, centers, _K, comm, n, n_steps, interpret=True)
-    ref = _lloyd_run(jnp.asarray(data_np), centers, _K, n_steps)
+        got = fused_lloyd_run_sharded(payload, centers, _K, comm, n, n_steps, -1.0, interpret=True)
+    ref = _lloyd_run(jnp.asarray(data_np), centers, _K, n_steps, -1.0)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
     assert got[1].shape == (n,)
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
     np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=1e-5)
-    before = np.asarray(_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps - 1)[0], np.float64)
+    before = np.asarray(_lloyd_run(jnp.asarray(data_np), centers, _K, n_steps - 1, -1.0)[0], np.float64)
     last = ((data_np.astype(np.float64) - before[np.asarray(got[1])]) ** 2).sum()
     np.testing.assert_allclose(float(got[2]), last, rtol=1e-5)
 
@@ -157,9 +220,9 @@ def test_inertia_from_the_accumulators_is_the_per_sample_sum_on_uncentred_rows(o
     centers = jnp.asarray(data_np[rng.choice(n, _K, replace=False)])
     x64 = data_np.astype(np.float64)
     floor = 2.0**-24 * (x64**2).sum()  # one rounding of Σ|x|²
-    for run in (lambda s: fused_lloyd_run(data, centers, _K, s, interpret=True),
-                lambda s: _lloyd_run(data, centers, _K, s)):
-        _, labels, inertia, _ = run(n_steps)
+    for run in (lambda s: fused_lloyd_run(data, centers, _K, s, -1.0, interpret=True),
+                lambda s: _lloyd_run(data, centers, _K, s, -1.0)):
+        _, labels, inertia, _, _ = run(n_steps)
         before = np.asarray(run(n_steps - 1)[0], np.float64)
         per_sample = ((x64 - before[np.asarray(labels)]) ** 2).sum()
         assert abs(float(inertia) - per_sample) <= 16 * floor, (float(inertia), per_sample, floor)
@@ -182,8 +245,8 @@ class TestFusedLloyd(TestCase):
         ref_c, ref_lab, ref_inertia, ref_shift = jax.jit(
             _lloyd_iter, static_argnames="k"
         )(data, centers, k)
-        got_c, got_lab, got_inertia, got_shift = fused_lloyd_run(
-            data, centers, k, 1, interpret=True
+        got_c, got_lab, got_inertia, got_shift, _ = fused_lloyd_run(
+            data, centers, k, 1, -1.0, interpret=True
         )
 
         np.testing.assert_array_equal(np.asarray(got_lab), np.asarray(ref_lab))
@@ -213,8 +276,8 @@ class TestFusedLloyd(TestCase):
         rng = np.random.default_rng(4)
         data = jnp.asarray(rng.standard_normal((4096, 8)).astype(np.float32))
         centers = jnp.asarray(rng.standard_normal((5, 8)).astype(np.float32) * 2)
-        ref = _lloyd_run(data, centers, 5, 4)
-        got = fused_lloyd_run(data, centers, 5, 4, interpret=True)
+        ref = _lloyd_run(data, centers, 5, 4, -1.0)
+        got = fused_lloyd_run(data, centers, 5, 4, -1.0, interpret=True)
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
         np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=1e-3)
@@ -226,7 +289,7 @@ class TestFusedLloyd(TestCase):
 
         data = jnp.asarray(np.zeros((128, 2), np.float32))
         centers = jnp.asarray(np.array([[0.0, 0.0], [100.0, 100.0]], np.float32))
-        new_c, labels, _, _ = fused_lloyd_run(data, centers, 2, 1, interpret=True)
+        new_c, labels, _, _, _ = fused_lloyd_run(data, centers, 2, 1, -1.0, interpret=True)
         assert (np.asarray(labels) == 0).all()
         np.testing.assert_array_equal(np.asarray(new_c)[1], centers[1])  # empty keeps old
 
@@ -245,8 +308,8 @@ class TestFusedLloyd(TestCase):
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * 2)
 
         x = ht.array(data_np, split=0)  # physical payload padded to p blocks
-        got_c, got_lab, got_inertia, got_shift = fused_lloyd_run_sharded(
-            x.parray, centers, k, comm, n, 1, interpret=True
+        got_c, got_lab, got_inertia, got_shift, _ = fused_lloyd_run_sharded(
+            x.parray, centers, k, comm, n, 1, -1.0, interpret=True
         )
         ref_c, ref_lab, ref_inertia, ref_shift = jax.jit(
             _lloyd_iter, static_argnames="k"
@@ -316,7 +379,7 @@ class TestFusedLloyd(TestCase):
         data_np = rng.standard_normal((n, f)).astype(np.float32)
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * 2)
         got = fused_lloyd_run(
-            jnp.asarray(data_np).astype(jnp.bfloat16), centers, k, 1, interpret=True
+            jnp.asarray(data_np).astype(jnp.bfloat16), centers, k, 1, -1.0, interpret=True
         )
         ref = jax.jit(_lloyd_iter, static_argnames="k")(jnp.asarray(data_np), centers, k)
         np.testing.assert_allclose(
@@ -364,7 +427,7 @@ class TestFusedLloyd(TestCase):
         data_np = rng.standard_normal((n, f)).astype(np.float32)
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32))
         x = ht.array(data_np, split=0).astype(ht.bfloat16)
-        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, interpret=True)
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, -1.0, interpret=True)
         ref = jax.jit(_lloyd_iter, static_argnames="k")(jnp.asarray(data_np), centers, k)
         np.testing.assert_allclose(
             np.asarray(got[0], np.float32), np.asarray(ref[0]), rtol=0.05, atol=0.05
@@ -416,7 +479,7 @@ class TestFusedLloyd(TestCase):
         data_np = rng.standard_normal((n, f)).astype(np.float32)
         centers = jnp.asarray(rng.standard_normal((k, f)).astype(np.float32))
         x = ht.array(data_np, split=0)
-        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, interpret=True)
+        got = fused_lloyd_run_sharded(x.parray, centers, k, comm, n, 1, -1.0, interpret=True)
         ref = _lloyd_iter(jnp.asarray(data_np), centers, k)
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))

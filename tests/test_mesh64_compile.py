@@ -133,7 +133,7 @@ from heat_tpu.cluster.kmeans import _lloyd_run
 def build_lloyd():
     data = jax.device_put(jnp.zeros((4 * p, 4), jnp.float32), comm.sharding(2, 0))
     c0 = jnp.zeros((2, 4), jnp.float32)
-    return jax.jit(lambda d, c: _lloyd_run(d, c, 2, 10)).lower(data, c0).compile().as_text()
+    return jax.jit(lambda d, c: _lloyd_run(d, c, 2, 10, -1.0)).lower(data, c0).compile().as_text()
 
 timed("lloyd10", build_lloyd)
 

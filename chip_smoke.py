@@ -42,7 +42,7 @@ FULL = {
     "kmeans": dict(n=10_000_000, f=16, k=8, iters=10),
     "cdist": dict(n=32768, f=64, block=256),
     "moments": dict(n=1_000_000),
-    "qr": dict(m=1 << 21, n=256, rows=1024),
+    "qr": dict(m=1_250_000, n=512, rows=1024),  # the qr_tall_1c cell's shape: one chip's rows of BASELINE config 4
     "eager": dict(rows=100_003, cols=7),
     "server": dict(clients=4, requests=20, n=4096),
     "trainer": dict(batch=256, steps=5),

@@ -527,22 +527,28 @@ _STATS.update({f"phase_{name}_ns": 0 for name in _FORCE_PHASES})
 _STATS.update(
     phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0
 )
-# an estimator's fit and a distance-matrix call are timed the same way, under
-# the same switch (note_phases): ``phase_<prefix>_<phase>_ns`` per phase of
-# the heat.<prefix> span's children, and the region's own counts.
+# an estimator's fit, a distance-matrix call and a QR factorisation are timed
+# the same way, under the same switch (note_phases): ``phase_<prefix>_<phase>_ns``
+# per phase of the heat.<prefix> span's children, and the region's own counts.
 # cluster/kmeans.py (heat.kmeans.fit): fits, the programs they dispatched,
 # their blocking host reads, the XLA label passes over the rows those programs
 # ran. spatial/distance.py (heat.cdist): calls, and the operand rotations
-# (collective-permutes of one operand shard) their tile programs made
+# (collective-permutes of one operand shard) their tile programs made.
+# core/linalg/qr.py (heat.qr): calls, their blocking host reads (the
+# CholeskyQR2 probe's one), and the CholeskyQR2 attempts whose probe failed and
+# fell to Householder
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
+_QR_PHASES = ("prepare", "dispatch", "sync", "wrap")
 _STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
 _STATS.update({f"phase_cdist_{name}_ns": 0 for name in _CDIST_PHASES})
+_STATS.update({f"phase_qr_{name}_ns": 0 for name in _QR_PHASES})
 _STATS.update(
     phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
     phase_kmeans_label_epilogues=0, phase_cdist_calls=0, phase_cdist_rotations=0,
+    phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0,
 )
-# place, read, a fit and a cdist are timed outside _FORCE_LOCK, from any serving thread:
+# place, read, a fit, a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches
 _PHASE_LOCK = threading.Lock()
 
@@ -558,7 +564,7 @@ def note_phase(name: str, ns: int) -> None:
 
 def note_phases(prefix: str, ns: dict, **counts: int) -> None:
     """Count one traced region of the library above the engine (a
-    ``heat.kmeans.fit``, a ``heat.cdist``; while ``telemetry.tracing()``): the
+    ``heat.kmeans.fit``, a ``heat.cdist``, a ``heat.qr``; while ``telemetry.tracing()``): the
     nanoseconds of each phase it went through (``telemetry.Phases.ns``) onto
     ``phase_<prefix>_<phase>_ns`` and each of ``counts`` onto
     ``phase_<prefix>_<name>``."""

@@ -84,8 +84,41 @@ def qr(
     raising — the all-matmul speed when conditioning allows, Householder
     stability when it does not. ``"auto"`` is the default because
     CholeskyQR2's tall work is all GEMMs (MXU), where Householder TSQR is
-    mostly vector work.
+    mostly vector work: on one v5e chip, 1 250 000 x 512 float32, a call
+    takes 87 ms against 1.66 s for ``method="tsqr"`` (there one replicated
+    Householder ``jnp.linalg.qr``; PERF.md, PR 35).
+
+    While ``telemetry.tracing()`` a call is a ``heat.qr`` span (stats
+    ``mode=cholqr2|tsqr|panel|replicated``, ``m``, ``n``, ``p``, ``calc_q``)
+    whose children lie side by side: ``.prepare`` (sanitation, promotion),
+    ``.dispatch`` (recording the multi-output node, or the eager jitted
+    call), ``.sync`` (the probe's one blocking read; the engine's own
+    ``heat.force`` spans nest under it), ``.wrap`` (the ``DNDarray``s); the
+    same intervals add to ``fusion.cache_stats()``'s ``phase_qr_*`` keys,
+    with the calls, their blocking reads and the probes that fell back.
     """
+    if not telemetry.tracing():
+        return _qr(a, calc_q, method, telemetry.no_phase)[0]
+    from .. import fusion
+
+    ph = telemetry.Phases("heat.qr", calc_q=int(bool(calc_q)))
+    try:
+        out, mode, syncs, fallbacks = _qr(a, calc_q, method, ph.phase)
+        ph.note(mode=mode, m=int(a.shape[0]), n=int(a.shape[1]), p=a.comm.size)
+    finally:
+        ph.close()
+    fusion.note_phases("qr", ph.ns, calls=1, syncs=syncs, fallbacks=fallbacks)
+    return out
+
+
+def _qr(a: DNDarray, calc_q: bool, method: str, mark) -> Tuple[QR, str, int, int]:
+    """:func:`qr` itself. ``mark(name)`` opens the call's next phase
+    (``telemetry.Phases.phase``; nothing when the call is not traced).
+    Returns the factors, the path that made them (``cholqr2``, ``tsqr``,
+    ``panel`` or ``replicated``), the blocking host reads made (the
+    CholeskyQR2 probe's one) and the CholeskyQR2 attempts whose probe failed
+    and fell to Householder (``auto`` only: ``method="cholqr2"`` raises)."""
+    mark("prepare")
     sanitation.sanitize_in(a)
     if a.ndim != 2:
         raise ValueError(f"qr requires a 2-D array, got {a.ndim}-D")
@@ -108,6 +141,7 @@ def qr(
     q_split = a.split
     r_split: Optional[int] = None
     q_arr = r_arr = None
+    mode, syncs, fallbacks = "cholqr2", 0, 0
     if (
         method == "auto"
         # genuinely tall-skinny only: the probe factors a REPLICATED (n, n)
@@ -127,55 +161,61 @@ def qr(
         # Deferred-first: the passes record as a multi-output collective
         # node, the probe read forces Q/R/ok in ONE dispatch, and a pending
         # operand chain compiles into the same program.
-        deferred = _cholqr2_deferred(a, calc_q)
+        mark("dispatch")
+        deferred = _cholqr2_deferred(a, calc_q, mark)
+        syncs = 1
         if deferred is not None:
             q_d, r_d, ok_d = deferred
             if ok_d:
-                if not calc_q:
-                    return QR(None, r_d)
-                return QR(q_d, r_d)
+                return QR(q_d, r_d), mode, syncs, fallbacks
         else:
             with _T_COLLECTIVE:
                 q_try, r_try, ok = _cholqr2_kernel(a.larray, calc_q)
             _record_cholqr2_collectives(a)  # the Gram psums ran either way
+            mark("sync")
             if bool(ok):
                 q_arr, r_arr = q_try, r_try
+        fallbacks = int(r_arr is None)
     elif method == "cholqr2":
         if m < n:
             raise ValueError(f"cholqr2 requires a tall operand (m >= n), got {a.shape}")
-        deferred = _cholqr2_deferred(a, calc_q)
+        mark("dispatch")
+        deferred = _cholqr2_deferred(a, calc_q, mark)
+        syncs = 1
         if deferred is not None:
             q_d, r_d, ok_d = deferred
             if not ok_d:
                 raise ValueError(_CHOLQR2_BREAKDOWN_MSG)
-            if not calc_q:
-                return QR(None, r_d)
-            return QR(q_d, r_d)
+            return QR(q_d, r_d), mode, syncs, fallbacks
         with _T_COLLECTIVE:
             q_arr, r_arr, ok = _cholqr2_kernel(a.larray, calc_q)
         _record_cholqr2_collectives(a)
+        mark("sync")
         if not bool(ok):
             raise ValueError(_CHOLQR2_BREAKDOWN_MSG)
 
     if r_arr is None:  # no CholeskyQR2 result: Householder dispatch
+        mark("dispatch")
         # TSQR needs a full (n, n) R per block: block = ceil(m/p) >= n,
         # otherwise the R-tile all-gather would move p*block*n = the FULL
         # operand volume — exactly the silent gather the explicit fallback
         # policy exists to avoid
         if a.split == 0 and p > 1 and m >= n and -(-m // p) >= n:
+            mode = "tsqr"
             deferred = _tsqr_deferred(a, comm)
             if deferred is not None:
+                mark("wrap")
                 q_d, r_d = deferred
-                if not calc_q:
-                    # the unused Q pick is never walked into a program, so
-                    # XLA dead-code-eliminates the formation matmul
-                    return QR(None, r_d)
-                return QR(q_d, r_d)
+                # calc_q=False: the unused Q pick is never walked into a
+                # program, so XLA dead-code-eliminates the formation matmul
+                return QR(q_d if calc_q else None, r_d), mode, syncs, fallbacks
             q_arr, r_arr = _tsqr(a, comm)
         elif a.split == 1 and p > 1 and m >= n:
+            mode = "panel"
             q_arr, r_arr = _panel_qr_split1(a, comm)
             r_split = 1
         else:
+            mode = "replicated"
             # replicated or short-wide: one XLA QR kernel over the gathered
             # operand — explicit policy with a size guard, never silent (the
             # shared warn_replicated helper so callers can filter one class)
@@ -189,6 +229,7 @@ def qr(
             q_arr, r_arr = jnp.linalg.qr(a.larray, mode="reduced")
             r_split = 1 if a.split == 1 else None
 
+    mark("wrap")
     r = DNDarray(
         _ensure_split(r_arr, r_split, comm),
         tuple(r_arr.shape),
@@ -198,7 +239,7 @@ def qr(
         comm,
     )
     if not calc_q or q_arr is None:
-        return QR(None, r)
+        return QR(None, r), mode, syncs, fallbacks
     q = DNDarray(
         _ensure_split(q_arr, q_split, comm),
         tuple(q_arr.shape),
@@ -207,7 +248,7 @@ def qr(
         a.device,
         comm,
     )
-    return QR(q, r)
+    return QR(q, r), mode, syncs, fallbacks
 
 
 def _record_cholqr2_collectives(a: DNDarray) -> None:
@@ -237,13 +278,14 @@ _CHOLQR2_BREAKDOWN_MSG = (
 )
 
 
-def _cholqr2_deferred(a: DNDarray, calc_q: bool):
+def _cholqr2_deferred(a: DNDarray, calc_q: bool, mark):
     """Record the CholeskyQR2 passes as a multi-output collective node: the
     Gram psums compile into the producing chain's program, and the breakdown
     probe's ONE host read forces Q/R/ok together (sibling batching — one
     dispatch, one blocking sync). Returns ``(q, r, ok)`` with Q/R as DNDarray
     wrappers (Q None when ``calc_q=False``), or None to decline (collectives
-    off, tracer payloads, record failures → the eager jitted kernel)."""
+    off, tracer payloads, record failures → the eager jitted kernel).
+    ``mark`` is the caller's (:func:`_qr`): the read is its ``sync`` phase."""
     from .. import fusion
 
     if not fusion.collectives_active():
@@ -260,9 +302,12 @@ def _cholqr2_deferred(a: DNDarray, calc_q: bool):
         rn, okn = nodes
         q = None
     r = fusion.wrap_node(rn, (n, n), None, a)
+    mark("sync")
     with _T_COLLECTIVE:
         ok = fusion.force(okn)
-    return q, r, bool(ok)
+    ok = bool(ok)  # the call's one blocking read
+    mark("wrap")
+    return q, r, ok
 
 
 @functools.lru_cache(maxsize=None)
@@ -524,8 +569,17 @@ def _cholqr2_body(x, calc_q: bool = True):
     Full-width operands contract at ``Precision.HIGHEST``: the MXU's default
     f32 matmul rounds its operands to bf16 (~4e-3 relative), which caps Q's
     orthogonality at that level whatever the algorithm does — measured on a
-    v5e at 2M x 256 f32: ``‖QᵀQ − I‖_max`` 3.3e-3 at the default — and
-    shrinks the safe conditioning range from ``1/√ε_f32`` to a few tens."""
+    v5e at 1 250 000 x 512 f32, columns a decade apart (PERF.md, PR 35):
+    ``‖QᵀQ − I‖_max`` 7.3e-3 at the default against 3.8e-6, and the probe's
+    ``‖Q1ᵀQ1 − I‖_F`` 7.5e-2 against 1.0e-4 — and shrinks the safe
+    conditioning range from ``1/√ε_f32`` to a few tens. At that shape each of
+    the four tall products takes 21 ms at ``HIGHEST`` (95 % of the MXU's
+    bfloat16 rate over six passes) and a call 87 ms. What ``HIGHEST`` does not
+    mend is the product's float32 accumulator: over 1 250 000 rows the Gram's
+    diagonal comes out 2.5e-5 low, so R's diagonal is 1.25e-5 low and Q's
+    columns 1.25e-5 long (against a float64 Gram on the host: max
+    ``|QᵀQ − I|`` 3.4e-5, off the diagonal 2e-8); a Gram taken the same way
+    has the same bias and reads 3.8e-6."""
     half = x.dtype in (jnp.bfloat16, jnp.float16)
     acc_t = jnp.float32 if half else x.dtype
     prec = None if half else jax.lax.Precision.HIGHEST

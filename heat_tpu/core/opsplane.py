@@ -175,6 +175,10 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_cdist_calls_total", (_C, "Distance-matrix calls (cdist/rbf/manhattan) whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_cdist_rotations_total", (_C, "Operand-shard rotations (collective-permutes) made by the tile programs of timed distance-matrix calls.", [])),
         ("heat_tpu_cdist_phase_seconds_total", (_C, "Host time of timed distance-matrix calls, by phase (prepare/dispatch/place).", ["phase"])),
+        ("heat_tpu_qr_calls_total", (_C, "QR factorisations (linalg.qr) whose phases were timed (telemetry on or a profiler session recording).", [])),
+        ("heat_tpu_qr_syncs_total", (_C, "Blocking host reads (the CholeskyQR2 probe) made by timed QR factorisations.", [])),
+        ("heat_tpu_qr_fallbacks_total", (_C, "CholeskyQR2 attempts of timed QR factorisations whose probe failed and fell to Householder.", [])),
+        ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -300,11 +304,13 @@ def _collect_fusion(out: List[Sample]) -> None:
             "heat_tpu_fusion_phase_seconds_total", {"phase": phase},
             stats[f"phase_{phase}_ns"] * 1e-9,
         ))
-    for count in ("fits", "dispatches", "syncs", "label_epilogues"):
-        out.append((f"heat_tpu_kmeans_{count}_total", {}, float(stats[f"phase_kmeans_{count}"])))
-    for count in ("calls", "rotations"):
-        out.append((f"heat_tpu_cdist_{count}_total", {}, float(stats[f"phase_cdist_{count}"])))
-    for prefix, phases in (("kmeans", fusion._KMEANS_PHASES), ("cdist", fusion._CDIST_PHASES)):
+    for prefix, counts, phases in (
+        ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues"), fusion._KMEANS_PHASES),
+        ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
+        ("qr", ("calls", "syncs", "fallbacks"), fusion._QR_PHASES),
+    ):
+        for count in counts:
+            out.append((f"heat_tpu_{prefix}_{count}_total", {}, float(stats[f"phase_{prefix}_{count}"])))
         for phase in phases:
             out.append((
                 f"heat_tpu_{prefix}_phase_seconds_total", {"phase": phase},

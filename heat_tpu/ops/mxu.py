@@ -22,6 +22,15 @@ pieces of each operand stacked along the contraction (six pairs, K = 6 f) are
 ONE bfloat16 pass with every piece product exact in the float32 accumulator:
 50 000 x 50 000 x 64 in 19.5 ms against 30.5 at ``HIGHEST`` and 18.9 at the
 rounded default.
+
+``core/linalg/qr.py`` (CholeskyQR2) keeps to the rule and does not come
+through :func:`matmul`: it asks ``HIGHEST`` itself. Its four tall products
+(two Grams, Q1 and Q of m x 512 rows) have 128 rows and columns and more, so
+:func:`matmul` would stack pieces for them, yet none is larger than its
+operand: the pieces of the m x 512 rows are six copies of them written and
+read back, 15 GB at the benchmark's 1 250 000 rows, for a product the size of
+one. The stack pays where the product dwarfs its operands, and that is not a
+question of the product's rows and columns alone.
 """
 
 from __future__ import annotations

@@ -548,3 +548,43 @@ def test_cdist_program_multiplies_in_float32(cdist_program):
         for name in re.findall(r"%[\w.\-]+", operands):
             (defined,) = set(re.findall(rf"{re.escape(name)} = (\w+)\[([\d,]+)\]", text))
             assert defined[0] == "bf16" and "384" in defined[1].split(","), (name, defined)
+
+
+# -- ISSUE 35: CholeskyQR2 at the qr_tall_f32 configuration's size, the program the
+# fusion engine compiles for ``ht.linalg.qr(a)`` on one chip (here because this file
+# holds the fixture)
+@pytest.fixture(scope="module")
+def qr_program(one_v5e):
+    """(rows, columns, the compiled ``(Q, R, ok)`` program) of ``qr_tall_1c``."""
+    import functools
+    import importlib
+
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    cfg = spec.Cell("qr_tall_1c").config
+    m, n = cfg["rows"]["1"], cfg["columns"]
+    x = jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=one_v5e)
+    return m, n, _compiled(jax.jit(functools.partial(qr_mod._cholqr2_op, calc_q=True)), x)
+
+
+def test_qr_program_holds_three_operand_sized_buffers_on_one_chip(qr_program):
+    """A, the first pass's Q1 (alive until its Gram and the product that forms
+    Q have read it) and Q: 7.68 GB of the chip's 16e9 B, and nothing else of
+    the operand's size."""
+    m, n, compiled = qr_program
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == m * n * 4 and memory.alias_size_in_bytes == 0
+    assert abs(memory.output_size_in_bytes - (m * n + n * n) * 4) < 1 << 16
+    assert m * n * 4 <= memory.temp_size_in_bytes < 1.01 * m * n * 4  # Q1
+    assert memory.peak_memory_in_bytes < 7.7e9 < 16e9
+
+
+def test_qr_program_leaves_no_product_at_the_mxu_default(qr_program):
+    """Every product of the compiled program (the two Grams, Q1, Q, R2 R1 and
+    the blocks of the Cholesky factorisations and triangular solves) asks for
+    ``HIGHEST``; none is a plain ``dot``."""
+    _, _, compiled = qr_program
+    text = compiled.as_text()
+    products = re.findall(r" convolution\([^\n]*", text)
+    assert len(products) >= 5 and not re.search(r" dot\(", text)
+    assert all("operand_precision={highest,highest}" in line for line in products)
+    assert text.count('custom_call_target="Cholesky"') >= 2

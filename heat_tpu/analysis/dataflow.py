@@ -302,6 +302,17 @@ def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _cholqr2_gram_entries(n: int) -> int:
+    """Mirror of ``core/linalg/qr.py::_gram_entries``: the entries of one
+    pass's Gram that CholeskyQR2 all-reduces. A column count that is a
+    multiple of 128 with at least two lane tiles runs by column blocks (at
+    most eight) and moves the upper block triangle only."""
+    if n % 128 or n < 256:
+        return n * n
+    width = 128 * _ceil_div(n, 128 * 8)
+    return sum(min(width, n - lo) * (n - lo) for lo in range(0, n, width))
+
+
 # ----------------------------------------------------------------------
 # interpretation state
 # ----------------------------------------------------------------------
@@ -1635,8 +1646,9 @@ class Analyzer:
                 or (m >= 2 * n and n * n <= (1 << 22) and a.split != 1)
             ):
                 if a.split == 0 and p > 1:
-                    # CholeskyQR2: two passes psum one (n, n) Gram partial
-                    fr.add_cost("allreduce", 2 * n * n * acc)
+                    # CholeskyQR2: two passes psum one Gram partial, (n, n)
+                    # or its upper block triangle
+                    fr.add_cost("allreduce", 2 * _cholqr2_gram_entries(n) * acc)
                     fr.collective = True
                 took_cholqr2 = True
             if not took_cholqr2:
@@ -1912,11 +1924,19 @@ def parse_budget_arg(spec: str) -> Tuple[str, int]:
 #: analyzable source so the SAME text feeds the abstract interpreter and a
 #: live run. Shapes are baked per mesh size by :func:`workload_source`.
 DRIFT_WORKLOADS: Dict[str, str] = {
-    # CholeskyQR2's two Gram psums: allreduce 2 * n^2 * 4 bytes
+    # CholeskyQR2's two Gram psums: allreduce 2 * n^2 * 4 bytes (n = 16: whole products)
     "qr_cholqr2": """
 import heat_tpu as ht
 ht.random.seed(7)
 a = ht.random.randn({m}, {n}, split=0)
+q, r = ht.linalg.qr(a, method="cholqr2")
+""",
+    # the same at a blocked width (256 columns: two column blocks): each psum
+    # moves the Gram's upper block triangle, 2 * 3 * 128^2 * 4 bytes
+    "qr_cholqr2_blocked": """
+import heat_tpu as ht
+ht.random.seed(9)
+a = ht.random.randn({mb}, 256, split=0)
 q, r = ht.linalg.qr(a, method="cholqr2")
 """,
     # TSQR's R-factor gather: allgather p * min(m/p, n) * n * 4 bytes
@@ -1937,7 +1957,7 @@ x = ht.linalg.solve_triangular(A, b, lower=True)
 
 
 def _workload_params(p: int) -> Dict[str, int]:
-    return {"m": 64 * p, "n": 16, "n2": 12, "ns": 40 * p}
+    return {"m": 64 * p, "mb": 512 * p, "n": 16, "n2": 12, "ns": 40 * p}
 
 
 def workload_source(name: str, mesh_size: int) -> str:

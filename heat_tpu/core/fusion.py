@@ -535,8 +535,9 @@ _STATS.update(
 # ran. spatial/distance.py (heat.cdist): calls, and the operand rotations
 # (collective-permutes of one operand shard) their tile programs made.
 # core/linalg/qr.py (heat.qr): calls, their blocking host reads (the
-# CholeskyQR2 probe's one), and the CholeskyQR2 attempts whose probe failed and
-# fell to Householder
+# CholeskyQR2 probe's one), the CholeskyQR2 attempts whose probe failed and
+# fell to Householder, and the calls whose CholeskyQR2 program took its tall
+# products by column blocks (a multiple of 128 columns, at least 256)
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
 _QR_PHASES = ("prepare", "dispatch", "sync", "wrap")
@@ -546,7 +547,7 @@ _STATS.update({f"phase_qr_{name}_ns": 0 for name in _QR_PHASES})
 _STATS.update(
     phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
     phase_kmeans_label_epilogues=0, phase_cdist_calls=0, phase_cdist_rotations=0,
-    phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0,
+    phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0, phase_qr_blocked=0,
 )
 # place, read, a fit, a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches

@@ -85,17 +85,21 @@ def qr(
     stability when it does not. ``"auto"`` is the default because
     CholeskyQR2's tall work is all GEMMs (MXU), where Householder TSQR is
     mostly vector work: on one v5e chip, 1 250 000 x 512 float32, a call
-    takes 87 ms against 1.66 s for ``method="tsqr"`` (there one replicated
-    Householder ``jnp.linalg.qr``; PERF.md, PR 35).
+    takes 62 ms (87 before its tall products left out the blocks that are
+    a mirror or zero) against 1.66 s for ``method="tsqr"`` (there one
+    replicated Householder ``jnp.linalg.qr``; PERF.md, PRs 35 and 36).
 
     While ``telemetry.tracing()`` a call is a ``heat.qr`` span (stats
-    ``mode=cholqr2|tsqr|panel|replicated``, ``m``, ``n``, ``p``, ``calc_q``)
+    ``mode=cholqr2|tsqr|panel|replicated``, ``m``, ``n``, ``p``, ``calc_q``,
+    ``blocks``: the column blocks the CholeskyQR2 program took its tall
+    products in, 1 where they ran whole or no such program ran)
     whose children lie side by side: ``.prepare`` (sanitation, promotion),
     ``.dispatch`` (recording the multi-output node, or the eager jitted
     call), ``.sync`` (the probe's one blocking read; the engine's own
     ``heat.force`` spans nest under it), ``.wrap`` (the ``DNDarray``s); the
     same intervals add to ``fusion.cache_stats()``'s ``phase_qr_*`` keys,
-    with the calls, their blocking reads and the probes that fell back.
+    with the calls, their blocking reads, the probes that fell back and the
+    calls whose CholeskyQR2 program took the blocked products.
     """
     if not telemetry.tracing():
         return _qr(a, calc_q, method, telemetry.no_phase)[0]
@@ -104,10 +108,14 @@ def qr(
     ph = telemetry.Phases("heat.qr", calc_q=int(bool(calc_q)))
     try:
         out, mode, syncs, fallbacks = _qr(a, calc_q, method, ph.phase)
-        ph.note(mode=mode, m=int(a.shape[0]), n=int(a.shape[1]), p=a.comm.size)
+        # the CholeskyQR2 program ran exactly where its probe was read
+        blocks = _block_count(int(a.shape[1])) if syncs else 1
+        ph.note(mode=mode, m=int(a.shape[0]), n=int(a.shape[1]), p=a.comm.size, blocks=blocks)
     finally:
         ph.close()
-    fusion.note_phases("qr", ph.ns, calls=1, syncs=syncs, fallbacks=fallbacks)
+    fusion.note_phases(
+        "qr", ph.ns, calls=1, syncs=syncs, fallbacks=fallbacks, blocked=int(blocks > 1)
+    )
     return out
 
 
@@ -253,8 +261,10 @@ def _qr(a: DNDarray, calc_q: bool, method: str, mark) -> Tuple[QR, str, int, int
 
 def _record_cholqr2_collectives(a: DNDarray) -> None:
     """Declared CholeskyQR2 schedule: each of the two passes' Gram
-    contractions psums one (n, n) partial over the split axis (GSPMD inserts
-    it when the operand rows are sharded; replicated operands move nothing)."""
+    contractions psums its partial over the split axis, ONE all-reduce a pass
+    (GSPMD inserts it when the operand rows are sharded; replicated operands
+    move nothing): the (n, n) partial where the products run whole, the block
+    rows of its upper block triangle (:func:`_gram_entries`) where blocked."""
     if a.split != 0 or not a.comm.is_distributed():
         return  # replicated operand: the Gram contractions move nothing
     if resilience._ARMED:
@@ -266,7 +276,7 @@ def _record_cholqr2_collectives(a: DNDarray) -> None:
     n = int(a.shape[1])
     acc = jnp.result_type(a.larray.dtype, jnp.float32)
     telemetry.record_collective(
-        "allreduce", a.comm.axis_name, n * n * jnp.dtype(acc).itemsize, str(acc), count=2
+        "allreduce", a.comm.axis_name, _gram_entries(n) * jnp.dtype(acc).itemsize, str(acc), count=2
     )
 
 
@@ -526,6 +536,37 @@ def _panel_qr_split1(a: DNDarray, comm) -> Tuple[jax.Array, jax.Array]:
     return q_pad, r_pad
 
 
+_LANES = 128  # a lane tile: where XLA:TPU stores a column block in place
+_MAX_BLOCKS = 8  # products per tall product: what a wider n adds is program, not work saved
+
+
+def _block_width(n: int) -> int:
+    """Width of the column blocks in which :func:`_cholqr2_body` takes its
+    four tall products, read off the column count alone: ``n`` itself (whole
+    products) unless ``n`` is a multiple of 128 with at least two lane tiles."""
+    if n % _LANES or n < 2 * _LANES:
+        return n
+    return _LANES * -(-n // (_LANES * _MAX_BLOCKS))
+
+
+def _col_blocks(n: int) -> list:
+    """The ``(lo, hi)`` column blocks of an ``n``-column CholeskyQR2 (one
+    block: whole products)."""
+    width = _block_width(n)
+    return [(lo, min(lo + width, n)) for lo in range(0, n, width)]
+
+
+def _block_count(n: int) -> int:
+    return len(_col_blocks(n))
+
+
+def _gram_entries(n: int) -> int:
+    """Entries of one pass's Gram that are computed, and all-reduced where the
+    rows are sharded: the upper block triangle where blocked, all ``n * n``
+    where whole (``analysis/dataflow.py`` prices the same figure)."""
+    return sum((hi - lo) * (n - lo) for lo, hi in _col_blocks(n))
+
+
 def _cholqr2_body(x, calc_q: bool = True):
     """Two CholeskyQR passes returning ``(q, r, ok)`` — the UNJITTED body
     shared by the eager jitted wrapper (:func:`_cholqr2_kernel`) and the
@@ -572,35 +613,72 @@ def _cholqr2_body(x, calc_q: bool = True):
     v5e at 1 250 000 x 512 f32, columns a decade apart (PERF.md, PR 35):
     ``‖QᵀQ − I‖_max`` 7.3e-3 at the default against 3.8e-6, and the probe's
     ``‖Q1ᵀQ1 − I‖_F`` 7.5e-2 against 1.0e-4 — and shrinks the safe
-    conditioning range from ``1/√ε_f32`` to a few tens. At that shape each of
-    the four tall products takes 21 ms at ``HIGHEST`` (95 % of the MXU's
-    bfloat16 rate over six passes) and a call 87 ms. What ``HIGHEST`` does not
-    mend is the product's float32 accumulator: over 1 250 000 rows the Gram's
-    diagonal comes out 2.5e-5 low, so R's diagonal is 1.25e-5 low and Q's
-    columns 1.25e-5 long (against a float64 Gram on the host: max
-    ``|QᵀQ − I|`` 3.4e-5, off the diagonal 2e-8); a Gram taken the same way
-    has the same bias and reads 3.8e-6."""
+    conditioning range from ``1/√ε_f32`` to a few tens. At that shape a whole
+    tall product takes 21 ms at ``HIGHEST`` (95 % of the MXU's bfloat16 rate
+    over six passes), so the four tall products do only the blocks a triangle
+    holds (below). What ``HIGHEST`` does not mend is the product's float32
+    accumulator: over 1 250 000 rows the Gram's diagonal comes out 2.5e-5 low,
+    so R's diagonal is 1.25e-5 low and Q's columns 1.25e-5 long (against a
+    float64 Gram on the host: max ``|QᵀQ − I|`` 3.4e-5, off the diagonal
+    2e-8); a Gram taken the same way has the same bias and reads 3.8e-6.
+
+    **The four tall products run by column blocks** of :func:`_block_width`
+    columns (read off ``n`` alone; ``n`` ragged or under 256 takes them whole,
+    as one block). A Gram ``xᴴx`` is Hermitian: block row ``i`` is ONE product
+    ``x[:, block i]ᴴ @ x[:, block i:]``, the diagonal block and what lies
+    right of it, and the strictly lower block triangle is the conjugate mirror
+    (so ``g`` is Hermitian to the bit; every entry still contracts all rows in
+    one product, and on sharded rows the block rows are one all-reduce a
+    pass). ``R⁻¹`` is upper triangular: column block ``j`` of ``x @ R⁻¹`` is
+    ONE product that contracts down to the diagonal block and no further, the
+    blocks below being zeros, stored at its constant column offset into a
+    buffer born uninitialised, where XLA:TPU writes it in place (no list of
+    column arrays to concatenate, no partial products to add: either costs a
+    copy of the rows or re-reads an accumulator). Four blocks of 128 are ten
+    block products of sixteen, 62.5 % of the FLOP: on a v5e at
+    1 250 000 x 512 a Gram takes 15.2 ms and Q1 or Q 15.0 instead of 21 and
+    a call 62 ms instead of 87; two blocks of 256 (75 %) take 16.3 and
+    17.0 (PERF.md, PR 36)."""
     half = x.dtype in (jnp.bfloat16, jnp.float16)
     acc_t = jnp.float32 if half else x.dtype
     prec = None if half else jax.lax.Precision.HIGHEST
-    eye = jnp.eye(x.shape[1], dtype=acc_t)
+    n = x.shape[1]
+    eye = jnp.eye(n, dtype=acc_t)
+    blocks = _col_blocks(n)
+
+    def product(a, b, contract):
+        return jax.lax.dot_general(
+            a, b, (contract, ((), ())), precision=prec, preferred_element_type=acc_t
+        )
 
     def gram_chol(x):
         # (n, n) — contracts the (sharded) row axis; psum under GSPMD
-        g = jax.lax.dot_general(
-            jnp.conjugate(x), x, (((0,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=acc_t,
-        )
+        if len(blocks) == 1:
+            g = product(jnp.conjugate(x), x, ((0,), (0,)))
+        else:
+            # block row i: the diagonal block and what lies right of it, one
+            # product; what lies left of it is the mirror of a block above
+            u = jnp.zeros((n, n), acc_t)
+            for lo, hi in blocks:
+                row = product(jnp.conjugate(x[:, lo:hi]), x[:, lo:], ((0,), (0,)))
+                u = jax.lax.dynamic_update_slice(u, row, (lo, lo))
+            above = jnp.triu(u, 1)
+            g = above + jnp.conjugate(above).mT + jnp.diag(jnp.diagonal(u).real.astype(acc_t))
         return jnp.conjugate(jnp.linalg.cholesky(g)).mT, g  # upper factor
 
     def inv_upper(r):  # (n, n) solve against I: small, exact, off the hot path
         return jax.lax.linalg.triangular_solve(r, eye, left_side=False, lower=False)
 
     def form_q(x, r_inv):  # big GEMM; operands in the streamed dtype
-        return jax.lax.dot_general(
-            x, r_inv.astype(x.dtype), (((1,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=acc_t,
-        ).astype(x.dtype)
+        r_inv = r_inv.astype(x.dtype)
+        # column block j contracts down to its diagonal block of R⁻¹ (below it
+        # are zeros) and is stored where it stays: at a constant multiple of
+        # 128 XLA:TPU writes it in place (spatial/distance.py::_write_tile)
+        q = jax.lax.empty(x.shape, x.dtype)
+        for lo, hi in blocks:
+            cols = product(x[:, :hi], r_inv[:hi, lo:hi], ((1,), (0,))).astype(x.dtype)
+            q = jax.lax.dynamic_update_slice(q, cols, (0, lo))
+        return q
 
     r1, _ = gram_chol(x)
     q1 = form_q(x, inv_upper(r1))

@@ -178,6 +178,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_qr_calls_total", (_C, "QR factorisations (linalg.qr) whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_qr_syncs_total", (_C, "Blocking host reads (the CholeskyQR2 probe) made by timed QR factorisations.", [])),
         ("heat_tpu_qr_fallbacks_total", (_C, "CholeskyQR2 attempts of timed QR factorisations whose probe failed and fell to Householder.", [])),
+        ("heat_tpu_qr_blocked_total", (_C, "Timed QR factorisations whose CholeskyQR2 program took its tall products by column blocks (only the blocks a triangle holds).", [])),
         ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
@@ -307,7 +308,7 @@ def _collect_fusion(out: List[Sample]) -> None:
     for prefix, counts, phases in (
         ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues"), fusion._KMEANS_PHASES),
         ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
-        ("qr", ("calls", "syncs", "fallbacks"), fusion._QR_PHASES),
+        ("qr", ("calls", "syncs", "fallbacks", "blocked"), fusion._QR_PHASES),
     ):
         for count in counts:
             out.append((f"heat_tpu_{prefix}_{count}_total", {}, float(stats[f"phase_{prefix}_{count}"])))

@@ -25,12 +25,14 @@ rounded default.
 
 ``core/linalg/qr.py`` (CholeskyQR2) keeps to the rule and does not come
 through :func:`matmul`: it asks ``HIGHEST`` itself. Its four tall products
-(two Grams, Q1 and Q of m x 512 rows) have 128 rows and columns and more, so
-:func:`matmul` would stack pieces for them, yet none is larger than its
-operand: the pieces of the m x 512 rows are six copies of them written and
-read back, 15 GB at the benchmark's 1 250 000 rows, for a product the size of
-one. The stack pays where the product dwarfs its operands, and that is not a
-question of the product's rows and columns alone.
+(two Grams, Q1 and Q of m x 512 rows; since PR 36 each runs as four block
+products of 128 columns that leave out the blocks a triangle does not hold)
+have 128 rows and columns and more, so :func:`matmul` would stack pieces for
+them, yet none is larger than its operand: the pieces of the m x 512 rows are
+six copies of them written and read back, 15 GB at the benchmark's 1 250 000
+rows, for a product the size of one. The stack pays where the product dwarfs
+its operands, and that is not a question of the product's rows and columns
+alone.
 """
 
 from __future__ import annotations

@@ -7,6 +7,7 @@ the static-vs-observed byte drift check at the live mesh."""
 
 from __future__ import annotations
 
+import importlib
 import io
 import json
 import os
@@ -16,6 +17,7 @@ import tempfile
 import unittest
 
 import numpy as np
+import pytest
 
 import heat_tpu as ht
 from heat_tpu.analysis import callgraph, dataflow, engine, lattice
@@ -23,6 +25,16 @@ from heat_tpu.analysis.lattice import TOP, UNKNOWN, AbstractArray, Const, Scalar
 from heat_tpu.core import fusion
 
 from harness import TestCase
+
+
+@pytest.mark.parametrize("n", [16, 128, 200, 256, 512, 640, 1152, 1664, 2048])
+def test_cholqr2_gram_entries_mirror_the_runtime(n):
+    """What the verifier prices for a CholeskyQR2 Gram psum is what
+    ``core/linalg/qr.py`` records: all of it where the products run whole,
+    the upper block triangle where they run by column blocks."""
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    assert dataflow._cholqr2_gram_entries(n) == qr_mod._gram_entries(n) <= n * n
+    assert (qr_mod._gram_entries(n) < n * n) == (qr_mod._block_count(n) > 1)
 
 
 def rules_of(findings, *, active_only: bool = True):
@@ -502,6 +514,10 @@ class TestCostModelAndBudgets(TestCase):
     def test_static_workload_formulas_at_mesh_8(self):
         self.assertEqual(
             dataflow.static_workload_bytes("qr_cholqr2", 8), {"allreduce": 2048}
+        )
+        self.assertEqual(
+            dataflow.static_workload_bytes("qr_cholqr2_blocked", 8),
+            {"allreduce": 2 * 3 * 128 * 128 * 4},  # the upper block triangle, twice
         )
         self.assertEqual(
             dataflow.static_workload_bytes("qr_tsqr", 8), {"allgather": 4608}

@@ -588,3 +588,47 @@ def test_qr_program_leaves_no_product_at_the_mxu_default(qr_program):
     assert len(products) >= 5 and not re.search(r" dot\(", text)
     assert all("operand_precision={highest,highest}" in line for line in products)
     assert text.count('custom_call_target="Cholesky"') >= 2
+
+
+# -- ISSUE 36: the tall products by column blocks, the blocks a triangle holds
+def test_qr_program_multiplies_only_the_blocks_a_triangle_holds(qr_program):
+    """Four whole products are ``4 * 2 m n^2`` FLOP; by column blocks of 128
+    the Grams stop at their upper block triangle and Q1, Q skip the zero
+    blocks of R^-1: ten blocks of sixteen, and at most 0.80 with the rest."""
+    m, n, compiled = qr_program
+    assert compiled.cost_analysis()["flops"] <= 0.80 * 4 * 2 * m * n * n
+
+
+def test_qr_program_copies_no_rows(qr_program):
+    """Each column block is stored where it stays (a constant offset that is a
+    multiple of 128): nothing of the operand's rows is copied, concatenated or
+    padded on the way."""
+    m, _, compiled = qr_program
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(rf"= \w+\[{m},[^\n]*? (?:copy|concatenate|pad)\([^\n]*", entry)
+    assert len(re.findall(rf"= f32\[{m},\d+\][^ ]* fusion\(", entry)) == 8  # four column blocks of Q1, four of Q
+
+
+def test_qr_program_on_sharded_rows_reduces_block_rows_once_a_pass(v5e_2x2):
+    """Rows sharded over the four described chips: ONE all-reduce a pass, of
+    the Gram's four block rows together (655 360 B against the whole Gram's
+    1 048 576), none of the operand's size, and no all-gather."""
+    import functools
+    import importlib
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    cfg = spec.Cell("qr_tall_1c").config
+    m, n = cfg["rows"]["1"], cfg["columns"]
+    mesh = Mesh(np.array(v5e_2x2), ("x",))
+    x = jax.ShapeDtypeStruct((4 * m, n), jnp.float32, sharding=NamedSharding(mesh, P("x", None)))
+    text = _compiled_text(jax.jit(functools.partial(qr_mod._cholqr2_op, calc_q=True)), x)
+    assert not re.search(r" all-gather(-start)?\(| all-to-all\(| collective-permute(-start)?\(", text)
+    reduces = re.findall(r"= (\([^=]*\)|\S+) all-reduce(?:-start)?\(", text)
+    assert 1 <= len(reduces) <= 2, reduces
+    for shapes in reduces:
+        entries = sum(int(np.prod([int(d) for d in dims.split(",")])) for dims in re.findall(r"f32\[([\d,]+)\]", shapes))
+        assert entries == qr_mod._gram_entries(n) == 128 * 1280, shapes
+

@@ -6,7 +6,9 @@ ragged m, ``calc_q`` both ways and every ``method``, by the three numbers the
 benchmark's op kind compares; the same call on bfloat16-rounded rows, and
 with the tall products rounded as the MXU's default precision rounds them,
 falls outside the limits; the blocked reference is the float64 QR of the whole
-operand; the spans and counters of a call.
+operand; the spans and counters of a call; and (ISSUE 36) CholeskyQR2's tall
+products by column blocks, which leave out the blocks that are a mirror or
+zero, against the same products taken whole.
 
 What only the chip shows (the program at 1 250 000 x 512: its memory, and that
 no product is left at the MXU's default) is compiled for a described v5e in
@@ -65,12 +67,12 @@ def scaled_rows(m, n=N, seed=35):
     return (np.random.default_rng(seed + m).standard_normal((m, n)) * scales).astype(np.float32)
 
 
-def gaps(reference, x, q, r):
+def gaps(reference, x, q, r, block=200):
     """``chipbench/ops/qr_trial.py``'s three numbers, over every row: R's
     columns against the reference's (signs turned on both sides), the rows of
     Q times sqrt(m) against the rows of A solved against the reference's R,
     and the rows of Q R against the rows of A."""
-    want = np.asarray(reference.r_factor(jnp.asarray(x), 200), np.float64)
+    want = np.asarray(reference.r_factor(jnp.asarray(x), block), np.float64)
     got = np.asarray(r, np.float64)
     sign = np.where(np.diagonal(got) < 0, -1.0, 1.0)
     r_gap = (np.sqrt(((sign[:, None] * got - want) ** 2).sum(axis=0)) / np.sqrt((want * want).sum(axis=0))).max()
@@ -258,7 +260,7 @@ def test_spans_of_a_call_in_a_profiler_session(method, mode):
     assert after["phase_qr_calls"] - before["phase_qr_calls"] == 1  # a profiler session is the switch too
     (parent,) = [s for s in spans if s[0] == "heat.qr"]
     assert {k: str(v) for k, v in parent[3].items()}.items() >= {
-        "mode": mode, "m": str(96 * p), "n": str(N), "p": str(p), "calc_q": "1"
+        "mode": mode, "m": str(96 * p), "n": str(N), "p": str(p), "calc_q": "1", "blocks": "1"
     }.items()
     children = sorted((s for s in spans if s[0].startswith("heat.qr.")), key=lambda s: s[1])
     names = [s[0].rsplit(".", 1)[1] for s in children]
@@ -278,5 +280,159 @@ def test_opsplane_exports_the_qr_counters():
         ht.linalg.qr(ht.array(scaled_rows(256), split=0))
     text = opsplane.render()
     assert not opsplane.validate_exposition(text)
-    assert all(f"heat_tpu_qr_{c}_total" in text for c in ("calls", "syncs", "fallbacks"))
+    assert all(f"heat_tpu_qr_{c}_total" in text for c in ("calls", "syncs", "fallbacks", "blocked"))
     assert all(f'heat_tpu_qr_phase_seconds_total{{phase="{ph}"}}' in text for ph in fusion._QR_PHASES)
+
+
+# -- ISSUE 36: the tall products by column blocks ---------------------------
+BLOCKS = {128: 1, 200: 1, 256: 2, 384: 3, 512: 4, 640: 5}  # columns: blocks (1 = whole products)
+
+
+def spectrum_rows(m, n, cond, dtype=np.float32, seed=36):
+    """Orthonormal columns times singular values from 1 down to 1 / cond,
+    turned by a random rotation: conditioning a column scale does not undo."""
+    rng = np.random.default_rng(seed + n)
+    draw = rng.standard_normal if dtype != np.complex64 else (lambda size: rng.standard_normal(size) + 1j * rng.standard_normal(size))
+    u, _ = np.linalg.qr(draw((m, n)))
+    v, _ = np.linalg.qr(draw((n, n)))
+    return ((u * np.logspace(0, -np.log10(cond), n)) @ v.conj().T).astype(dtype)
+
+
+def body(x, monkeypatch, whole=False, calc_q=True, seen=None):
+    """``_cholqr2_body`` on ``x``, run eagerly (a jitted wrapper would keep the
+    program of the other form); ``whole`` takes the four products whole as
+    before ISSUE 36; ``seen`` receives what the probe was handed."""
+    with monkeypatch.context() as patch:
+        if whole:
+            patch.setattr(qr_mod, "_block_width", lambda n: n)
+        if seen is not None:
+            probe = qr_mod._cholqr2_probe_ok
+
+            def watched(r1, r2, g2, eye):
+                seen.update(r1=np.asarray(r1), r2=np.asarray(r2), g2=np.asarray(g2))
+                return probe(r1, r2, g2, eye)
+
+            patch.setattr(qr_mod, "_cholqr2_probe_ok", watched)
+        q, r, ok = qr_mod._cholqr2_body(jnp.asarray(x), calc_q)
+    return (None if q is None else np.asarray(q)), np.asarray(r), bool(ok)
+
+
+@pytest.mark.parametrize("n", sorted(BLOCKS))
+def test_blocked_products_are_the_whole_products(n, monkeypatch):
+    """Q, R and the probe of the blocked products against the same products
+    taken whole: what is left out is a block of zeros of R^-1 or the mirror
+    of a block that is computed, so the factors agree to float32's rounding
+    (and to the bit where ``n`` is ragged or under 256 and nothing is
+    blocked); R is upper triangular with zeros below."""
+    assert qr_mod._block_count(n) == BLOCKS[n]
+    assert qr_mod._gram_entries(n) == (n * n if BLOCKS[n] == 1 else 128 * 128 * BLOCKS[n] * (BLOCKS[n] + 1) // 2)
+    x = scaled_rows(3 * n, n)
+    q, r, ok = body(x, monkeypatch)
+    q0, r0, ok0 = body(x, monkeypatch, whole=True)
+    assert ok and ok0 and q.dtype == np.float32 and r.dtype == np.float32
+    assert np.array_equal(r, np.triu(r)) and (np.diagonal(r) > 0).all()
+    if BLOCKS[n] == 1:
+        assert np.array_equal(q, q0) and np.array_equal(r, r0)
+    scale = np.sqrt(3 * n)  # a row of Q times sqrt(m) has entries of order 1
+    assert np.abs(q - q0).max() * scale < 2e-6 and np.abs(r - r0).max() / np.abs(r0).max() < 1e-6
+    assert np.abs(q.T @ q - np.eye(n)).max() < 5e-6
+
+
+@pytest.mark.parametrize("dtype,n", [(np.float32, 256), (np.float32, 512), (np.complex64, 256)], ids=["f32-256", "f32-512", "c64-256"])
+def test_blocked_gram_is_exactly_hermitian(dtype, n, monkeypatch):
+    """The strictly lower block triangle is the conjugate mirror of the
+    upper, entry for entry, and the diagonal is real: what ``cholesky`` and
+    the probe's norm are handed is Hermitian to the bit."""
+    seen = {}
+    _, _, ok = body(spectrum_rows(4 * n, n, 10.0, dtype), monkeypatch, seen=seen)
+    g2 = seen["g2"]
+    assert ok and g2.shape == (n, n) and np.array_equal(g2, g2.conj().T)
+    assert np.linalg.norm(g2 - np.eye(n)) < 1e-3
+
+
+def test_blocked_complex_operand_is_unitary():
+    """``test_qr_depth.py::test_complex_operand_unitary`` at a blocked width:
+    the mirror conjugates."""
+    a_np = spectrum_rows(1024, 256, 10.0, np.complex64)
+    q, r = ht.linalg.qr(ht.array(a_np, split=0), method="cholqr2")
+    q_np, r_np = q.numpy(), r.numpy()
+    assert np.array_equal(r_np, np.triu(r_np)) and (np.diagonal(r_np).real > 0).all()
+    np.testing.assert_allclose(q_np.conj().T @ q_np, np.eye(256), atol=3e-5)
+    np.testing.assert_allclose(q_np @ r_np, a_np, atol=3e-6)
+
+
+def test_blocked_bfloat16_stream(monkeypatch):
+    """A half-precision operand streams at its own width through the blocked
+    products too: Q comes back bfloat16, R float32, and both are the whole
+    products' to bfloat16's rounding."""
+    x = jnp.asarray(scaled_rows(1024, 256)).astype(jnp.bfloat16)
+    q, r, ok = body(x, monkeypatch)
+    q0, r0, ok0 = body(x, monkeypatch, whole=True)
+    assert ok and ok0 and q.dtype == jnp.bfloat16 and r.dtype == np.float32
+    assert np.array_equal(r, np.triu(r))
+    assert np.abs(q.astype(np.float32) - q0.astype(np.float32)).max() * 32 < 0.1
+    assert np.abs(r - r0).max() / np.abs(r0).max() < 2e-2
+
+
+def test_blocked_r_only_is_the_r_of_the_full_call(comm, monkeypatch):
+    x = scaled_rows(1024, 256)
+    _, r_full, _ = body(x, monkeypatch)
+    q, r, ok = body(x, monkeypatch, calc_q=False)
+    assert q is None and ok and np.array_equal(r, r_full)
+    q_ht, r_ht = ht.linalg.qr(ht.array(x, split=0, comm=comm), calc_q=False)
+    assert q_ht is None and np.abs(r_ht.numpy() - r_full).max() / np.abs(r_full).max() < 1e-6
+
+
+@pytest.mark.parametrize("m", [1024, 1021], ids=["even", "ragged"])
+def test_blocked_products_on_sharded_rows(reference, comm, m):
+    """Rows split over the suite's mesh (and one device): the block rows of
+    the Gram are all-reduced, Q is born row-sharded, and both are the plain
+    reference's."""
+    x = scaled_rows(m, 256)
+    q, r = ht.linalg.qr(ht.array(x, split=0, comm=comm))
+    assert q.split == 0 and q.shape == (m, 256) and r.split is None
+    r_np = r.numpy()
+    assert np.array_equal(r_np, np.triu(r_np)) and (np.diagonal(r_np) > 0).all()
+    r_gap, q_gap, recon_gap = gaps(reference, x, q.numpy(), r_np, block=1024)
+    assert r_gap <= R_LIMIT and q_gap <= Q_LIMIT and recon_gap <= RECON_LIMIT, (r_gap, q_gap, recon_gap)
+
+
+def test_probe_refuses_a_degraded_first_pass_at_a_blocked_width(comm, monkeypatch):
+    """``test_qr_depth.py::test_probe_rejects_finite_but_degraded_orthogonality``
+    with an operand: cond 7e3, past 1 / sqrt(eps), keeps both Cholesky factors
+    finite while Q1 drifts from orthonormal; the probe reads that off the
+    mirrored Gram as it does off the whole one."""
+    x = spectrum_rows(1024, 256, 7e3)
+    for whole in (False, True):
+        seen = {}
+        _, _, ok = body(x, monkeypatch, whole=whole, seen=seen)
+        assert np.isfinite(seen["r1"]).all() and np.isfinite(seen["r2"]).all()
+        assert np.linalg.norm(seen["g2"] - np.eye(256)) >= 0.5 and not ok
+    a = ht.array(x, split=0, comm=comm)
+    with pytest.raises(ValueError, match="cholqr2 broke down"):
+        ht.linalg.qr(a, method="cholqr2")
+    q, r = ht.linalg.qr(a)  # Householder's answer
+    np.testing.assert_allclose(q.numpy() @ r.numpy(), x, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [48, 256, 384], ids=["whole", "two", "three"])
+def test_blocked_counter_and_the_span_stat(n):
+    """``phase_qr_blocked`` counts the calls whose CholeskyQR2 program took the
+    blocked products and the span's ``blocks`` says how many; a Householder
+    call has no such products; with telemetry off nothing moves."""
+    p = ht.get_comm().size
+    blocks = qr_mod._block_count(n)
+    a = ht.array(scaled_rows(4 * n * p, n), split=0)
+    before = fusion.cache_stats()["phase_qr_blocked"]
+    ht.linalg.qr(a)
+    assert fusion.cache_stats()["phase_qr_blocked"] == before
+    with telemetry.enabled(1):
+        ht.linalg.qr(a)
+    assert fusion.cache_stats()["phase_qr_blocked"] - before == int(blocks > 1)
+    with telemetry.enabled(1):
+        ht.linalg.qr(a, method="tsqr")
+    assert fusion.cache_stats()["phase_qr_blocked"] - before == int(blocks > 1)
+    (parent,) = [s for s in _spans_of_a_call(a) if s[0] == "heat.qr"]
+    assert str(parent[3]["blocks"]) == str(blocks) and str(parent[3]["mode"]) == "cholqr2"
+    (parent,) = [s for s in _spans_of_a_call(a, method="tsqr") if s[0] == "heat.qr"]
+    assert str(parent[3]["blocks"]) == "1"

@@ -168,8 +168,9 @@ class KMeans(_KCluster):
         side: ``.init`` (the initial centres), ``.prepare`` (the dtype cast;
         no pass over the rows: the fused program reads them in place),
         ``.dispatch`` (the call of the fit's one Lloyd program, which stops
-        by the class docstring's rule), ``.sync`` (the one blocking read, of
-        ``n_iter_`` and ``inertia_`` together), ``.wrap`` (centres and labels
+        by the class docstring's rule), ``.sync`` (the wait until the device
+        has made ``n_iter_`` and ``inertia_``), ``.copy`` (the one read of the
+        two together, ``telemetry.ready_then``), ``.wrap`` (centres and labels
         back into ``DNDarray``s); the same intervals add to ``fusion.cache_stats()``'s
         ``phase_kmeans_*`` keys."""
         if not isinstance(x, DNDarray):
@@ -230,7 +231,7 @@ class KMeans(_KCluster):
             out = _lloyd_run(data, centers, k, max_iter, tol)
         centers, labels, inertia, _, n_iter = out
         mark("sync")
-        n_iter, inertia = jax.device_get((n_iter, inertia))
+        n_iter, inertia = telemetry.ready_then(mark, (n_iter, inertia), jax.device_get, "sync")
         self._n_iter, self._inertia = int(n_iter), float(inertia)
         mark("wrap")
         self._cluster_centers = DNDarray(

@@ -291,16 +291,19 @@ class DNDarray:
         """``fetch(self.larray)``, the blocking device-to-host read of
         ``item()``/``numpy()``. The payload is forced first, so that while
         tracing the read is a ``heat.read`` span (and ``phase_read_ns``) of
-        its own, after and never around ``heat.force``."""
+        its own, after and never around ``heat.force``, with two children
+        side by side: ``.ready`` (the wait until the device has made the
+        payload) and ``.copy`` (the fetch of what is ready), which add to
+        ``phase_read_ready_ns`` and ``phase_read_copy_ns``."""
         cid = getattr(self.__array, "cid", 0)  # the pending chain's, else 0
         arr = self.larray
         if not telemetry.tracing():
             return fetch(arr)
         span = telemetry.Phases("heat.read", cid=cid, kind=kind)
         try:
-            return fetch(arr)
+            return telemetry.ready_then(span.phase, arr, fetch)
         finally:
-            fusion.note_phase("read", span.close())
+            fusion.note_phase("read", span.close(), span.ns)
 
     @property
     def larray(self) -> jax.Array:

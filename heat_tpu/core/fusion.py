@@ -525,7 +525,8 @@ _STATS = {
 _FORCE_PHASES = ("admit", "walk", "lookup", "dispatch", "install")
 _STATS.update({f"phase_{name}_ns": 0 for name in _FORCE_PHASES})
 _STATS.update(
-    phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0
+    phase_forces=0, phase_places=0, phase_place_ns=0, phase_reads=0, phase_read_ns=0,
+    phase_read_ready_ns=0, phase_read_copy_ns=0,  # heat.read's two children: the wait, the copy
 )
 # an estimator's fit, a distance-matrix call and a QR factorisation are timed
 # the same way, under the same switch (note_phases): ``phase_<prefix>_<phase>_ns``
@@ -538,9 +539,9 @@ _STATS.update(
 # CholeskyQR2 probe's one), the CholeskyQR2 attempts whose probe failed and
 # fell to Householder, and the calls whose CholeskyQR2 program took its tall
 # products by column blocks (a multiple of 128 columns, at least 256)
-_KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "wrap")
+_KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "copy", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
-_QR_PHASES = ("prepare", "dispatch", "sync", "wrap")
+_QR_PHASES = ("prepare", "dispatch", "sync", "copy", "wrap")
 _STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
 _STATS.update({f"phase_cdist_{name}_ns": 0 for name in _CDIST_PHASES})
 _STATS.update({f"phase_qr_{name}_ns": 0 for name in _QR_PHASES})
@@ -554,13 +555,17 @@ _STATS.update(
 _PHASE_LOCK = threading.Lock()
 
 
-def note_phase(name: str, ns: int) -> None:
+def note_phase(name: str, ns: int, parts: Optional[dict] = None) -> None:
     """Count one ``heat.place`` / ``heat.read`` interval of ``ns``
     nanoseconds (``DNDarray``'s forcing seam and host boundary, while
-    ``telemetry.tracing()``)."""
+    ``telemetry.tracing()``), and the nanoseconds of each of its ``parts``
+    (``telemetry.Phases.ns``: a read's ``ready`` and ``copy``) onto
+    ``phase_<name>_<part>_ns``."""
     with _PHASE_LOCK:
         _STATS[f"phase_{name}s"] += 1
         _STATS[f"phase_{name}_ns"] += ns
+        for part, took in (parts or {}).items():
+            _STATS[f"phase_{name}_{part}_ns"] += took
 
 
 def note_phases(prefix: str, ns: dict, **counts: int) -> None:

@@ -95,8 +95,9 @@ def qr(
     products in, 1 where they ran whole or no such program ran)
     whose children lie side by side: ``.prepare`` (sanitation, promotion),
     ``.dispatch`` (recording the multi-output node, or the eager jitted
-    call), ``.sync`` (the probe's one blocking read; the engine's own
-    ``heat.force`` spans nest under it), ``.wrap`` (the ``DNDarray``s); the
+    call), ``.sync`` (the probe's force, whose ``heat.force`` spans nest
+    under it, and the wait until the device has made it), ``.copy`` (the
+    probe's one read, ``telemetry.ready_then``), ``.wrap`` (the ``DNDarray``s); the
     same intervals add to ``fusion.cache_stats()``'s ``phase_qr_*`` keys,
     with the calls, their blocking reads, the probes that fell back and the
     calls whose CholeskyQR2 program took the blocked products.
@@ -295,7 +296,8 @@ def _cholqr2_deferred(a: DNDarray, calc_q: bool, mark):
     dispatch, one blocking sync). Returns ``(q, r, ok)`` with Q/R as DNDarray
     wrappers (Q None when ``calc_q=False``), or None to decline (collectives
     off, tracer payloads, record failures → the eager jitted kernel).
-    ``mark`` is the caller's (:func:`_qr`): the read is its ``sync`` phase."""
+    ``mark`` is the caller's (:func:`_qr`): the force and the wait for the
+    device are its ``sync`` phase, the read of the ready probe its ``copy``."""
     from .. import fusion
 
     if not fusion.collectives_active():
@@ -315,7 +317,7 @@ def _cholqr2_deferred(a: DNDarray, calc_q: bool, mark):
     mark("sync")
     with _T_COLLECTIVE:
         ok = fusion.force(okn)
-    ok = bool(ok)  # the call's one blocking read
+    ok = telemetry.ready_then(mark, ok, bool, "sync")  # the call's one blocking read
     mark("wrap")
     return q, r, ok
 

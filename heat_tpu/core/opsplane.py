@@ -166,12 +166,12 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_fusion_cache_size", (_G, "Compiled programs currently cached.", [])),
         ("heat_tpu_fusion_quarantined", (_G, "Program keys currently quarantined.", [])),
         ("heat_tpu_fusion_phase_forces_total", (_C, "Forced results whose phases were timed (telemetry on or a profiler session recording).", [])),
-        ("heat_tpu_fusion_phase_seconds_total", (_C, "Host time of timed forced results, by phase (admit/walk/lookup/dispatch/install/place/read).", ["phase"])),
+        ("heat_tpu_fusion_phase_seconds_total", (_C, "Host time of timed forced results, by phase (admit/walk/lookup/dispatch/install/place/read; read_ready and read_copy are read's two parts).", ["phase"])),
         ("heat_tpu_kmeans_fits_total", (_C, "KMeans fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_kmeans_dispatches_total", (_C, "Lloyd programs dispatched by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (n_iter and inertia together) made by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_label_epilogues_total", (_C, "XLA label passes over the rows run by the Lloyd programs of timed KMeans fits.", [])),
-        ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/wrap).", ["phase"])),
+        ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/copy/wrap).", ["phase"])),
         ("heat_tpu_cdist_calls_total", (_C, "Distance-matrix calls (cdist/rbf/manhattan) whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_cdist_rotations_total", (_C, "Operand-shard rotations (collective-permutes) made by the tile programs of timed distance-matrix calls.", [])),
         ("heat_tpu_cdist_phase_seconds_total", (_C, "Host time of timed distance-matrix calls, by phase (prepare/dispatch/place).", ["phase"])),
@@ -179,7 +179,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_qr_syncs_total", (_C, "Blocking host reads (the CholeskyQR2 probe) made by timed QR factorisations.", [])),
         ("heat_tpu_qr_fallbacks_total", (_C, "CholeskyQR2 attempts of timed QR factorisations whose probe failed and fell to Householder.", [])),
         ("heat_tpu_qr_blocked_total", (_C, "Timed QR factorisations whose CholeskyQR2 program took its tall products by column blocks (only the blocks a triangle holds).", [])),
-        ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/wrap).", ["phase"])),
+        ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/copy/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -300,7 +300,7 @@ def _collect_fusion(out: List[Sample]) -> None:
     ):
         out.append((f"heat_tpu_fusion_{field}_total", {}, float(stats[field])))
     out.append(("heat_tpu_fusion_phase_forces_total", {}, float(stats["phase_forces"])))
-    for phase in (*fusion._FORCE_PHASES, "place", "read"):
+    for phase in (*fusion._FORCE_PHASES, "place", "read", "read_ready", "read_copy"):
         out.append((
             "heat_tpu_fusion_phase_seconds_total", {"phase": phase},
             stats[f"phase_{phase}_ns"] * 1e-9,

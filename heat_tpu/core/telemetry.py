@@ -126,6 +126,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation as _Annotation
 
 __all__ = [
@@ -159,6 +160,7 @@ __all__ = [
     "on_timer",
     "operand_bytes",
     "program_costs",
+    "ready_then",
     "record_async_dispatch",
     "record_blocking_sync",
     "record_checkpoint",
@@ -414,6 +416,29 @@ def no_phase(name: str) -> int:
     """:meth:`Phases.phase` of a region that is not traced: what a function
     that marks its phases is handed when :func:`tracing` is off."""
     return 0
+
+
+def ready_then(mark, value, fetch, ready: str = "ready", copy: str = "copy"):
+    """``fetch(value)``, a blocking device-to-host read, with the wait for the
+    device parted from the copy: phase ``ready`` of the region whose
+    :meth:`Phases.phase` is ``mark`` lasts until the device has made
+    ``value`` (``jax.block_until_ready``), phase ``copy`` is what is left of
+    the fetch once the value is ready. The copy is asked for first
+    (``copy_to_host_async``), as the fetch alone asks for it: the runtime
+    starts it behind the program, not behind the host's wake-up, so the two
+    phases add up to the read as it is when nobody looks (asked for after the
+    wait, a scalar's copy is 90 us longer on a v5e: PERF.md, PR 37). For the
+    three places where the program itself blocks on the device
+    (``heat.read``, ``heat.kmeans.fit``, ``heat.qr``). A region that is not
+    traced (:func:`no_phase`) makes the fetch alone."""
+    if mark is no_phase:
+        return fetch(value)
+    mark(ready)
+    for leaf in jax.tree_util.tree_leaves(value):
+        leaf.copy_to_host_async()
+    jax.block_until_ready(value)
+    mark(copy)
+    return fetch(value)
 
 
 # ----------------------------------------------------------------------

@@ -10,7 +10,7 @@ exported Chrome/Perfetto trace files without writing any analysis code:
     $ python -m heat_tpu.telemetry validate-trace trace.json
     $ python -m heat_tpu.telemetry analyze trace.json           # tracelens verdict
     $ python -m heat_tpu.telemetry analyze new.json --against old.json --json
-    $ python -m heat_tpu.telemetry gaps /tmp/profile_dir       # device idle by heat.* span
+    $ python -m heat_tpu.telemetry gaps /tmp/profile_dir       # device idle on ONE clock: host by span, launch + completion
     $ python -m heat_tpu.telemetry memory                 # live process ledger
     $ python -m heat_tpu.telemetry memory report.json --json
     $ python -m heat_tpu.telemetry health                 # flight/watchdog/SLO
@@ -29,9 +29,14 @@ there live), existing so the CLI has a stable ``-m`` entry point.
 from __future__ import annotations
 
 import argparse
+import bisect
+import glob
 import json
+import os
 import sys
 from typing import Any, Dict, List, Optional
+
+import jax
 
 from heat_tpu.core import telemetry as _core
 
@@ -657,9 +662,12 @@ def _show_numerics(doc: Dict[str, Any], out) -> None:
 
 
 # ----------------------------------------------------------------------
-# gaps: the device's idle time by the program span that covered it, from a
-# profiler trace (.xplane.pb) alone
+# gaps: the device's idle time on ONE clock, from a profiler trace alone
 # ----------------------------------------------------------------------
+_ENQUEUE, _DONE = "DoEnqueueProgram", "tpu::System::Execute=>Done"  # the runtime's own host events
+_TRIP = ("host_before_us", "launch_plus_completion_us", "device_us", "host_after_us")  # a round trip
+
+
 def _innermost(spans) -> List[tuple]:
     """Nested ``(start, end, name)`` spans as sorted disjoint pieces, each
     named by the innermost span over it (of spans that overlap without
@@ -695,75 +703,180 @@ def _overlap(pieces, gaps) -> Dict[str, float]:
     return by
 
 
-def _gaps_doc(path: str) -> Dict[str, Any]:
-    """Busy and idle seconds of the busiest device in a profiler trace, and
-    the idle time by the innermost ``heat.*`` span over it (``outside``:
-    under none), inside the window from the first such span's start to the
-    last one's end. A device is a ``/device:*`` plane's ``XLA Ops`` line; a
-    CPU backend's operations are the host events with an ``hlo_op``, by
-    ``device_ordinal``. The shares are exact; which span a gap falls under
-    is as good as the profiler's alignment of its device lines with its
-    host lines (0.3-1.6 ms apart on a v5e: PERF.md, section 7)."""
-    import glob
-    import os
+def _shift_window(modules, enqueues, dones) -> Dict[str, Any]:
+    """The shifts that put one device's ``modules`` (its clock) on the clock of
+    the runtime's host events, sorted ``(start, end)`` seconds, the k-th with
+    the k-th: no program starts before its enqueue ended, none ends after its
+    ``Done`` began. An empty window: the shift moved inside the session (or they
+    do not pair); ``by_part_us``, the same window over each of up to ten equal
+    parts of the programs (32 at least in each), shows where."""
+    n = min(len(modules), len(enqueues), len(dones))
+    lows = [1e6 * (q[1] - m[0]) for q, m in zip(enqueues, modules[:n])]
+    highs = [1e6 * (d[0] - m[1]) for d, m in zip(dones, modules[:n])]
+    k = max(1, min(10, n // 32))
+    cuts = [t * n // k for t in range(k + 1)]
+    parts = [[max(lows[a:b], default=0.0), min(highs[a:b], default=0.0)] for a, b in zip(cuts, cuts[1:])]
+    lo, hi, unmatched = max(lows, default=0.0), min(highs, default=0.0), max(len(modules), len(enqueues), len(dones)) - n
+    return dict(programs=n, unmatched=unmatched, shift_lo_us=lo, shift_hi_us=hi, consistent=lo <= hi, by_part_us=parts)
 
-    import jax
 
-    if os.path.isdir(path):
-        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True))
-        if not found:
-            raise ValueError(f"no .xplane.pb under {path}")
-        path = found[-1]
-    devices: Dict[str, list] = {}
-    spans = []
+def _cut(gaps, modules, enqueues, dones, spans, shifts=((0.0, 0.0),)) -> Dict[str, Any]:
+    """One device's idle ``gaps`` (sorted, its clock) cut on the host's clock.
+    A gap lies between a program P that ended and a program N that starts (the
+    k-th module, enqueue and ``Done`` belong together). From P's ``Done`` start
+    to N's enqueue end the time is the HOST's and needs no shift: it goes to
+    the innermost of ``spans`` over it, ``outside`` under none (the caller). The
+    rest is P's ``completion`` (last operation's end -> ``Done``) plus N's
+    ``launch`` (enqueue's end -> first operation): the sum is exact, each a pair,
+    at the low and the high shift of N's part of the session (``shifts``:
+    :func:`_shift_window`'s ``by_part_us``). N enqueued before P was done:
+    ``queued``. Between one program's operations: ``inside_program``. Where P or
+    N is missing (the window's edges, a CPU backend) the gap's own end stands
+    for its event, the host's part stays inside the gap and no pair grows."""
+    starts, host, launch, completion = [m[0] for m in modules], [], [0.0, 0.0], [0.0, 0.0]
+    out = dict(launch_plus_completion_s=0.0, launch_s=launch, completion_s=completion, queued_s=0.0, inside_program_s=0.0)
+    for gs, ge in gaps:
+        p, nx = bisect.bisect_right(starts, gs) - 1, bisect.bisect_right(starts, ge) - 1
+        both = nx > p >= 0
+        h0 = dones[p][0] if p >= 0 else gs
+        h1 = max(h0, enqueues[nx][1] if nx > p else ge)
+        if p >= 0 and ge <= modules[p][1]:
+            out["inside_program_s"] += ge - gs
+        elif both and h1 <= h0:
+            out["queued_s"] += ge - gs
+        else:
+            h1 = h1 if both else min(h1, h0 + ge - gs)
+            host.append((h0, h1))
+            out["launch_plus_completion_s"] += (ge - gs) - (h1 - h0)
+            for i in (0, 1) if both else ():
+                shift = 1e-6 * shifts[nx * len(shifts) // len(starts)][i]
+                launch[i] += ge + shift - h1
+                completion[i] += h0 - gs - shift
+    by_span = _overlap(_innermost(spans), host)
+    by_span["outside"] = sum(e - s for s, e in host) - sum(by_span.values())
+    return {**out, "idle_by_span_s": dict(sorted(by_span.items(), key=lambda kv: -kv[1]))}
 
-    def seconds(e, name):
-        return e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, name
+
+def _round_trips(modules, enqueues, dones, forces) -> Dict[str, Any]:
+    """Per program and over ``all``: the count and ``[mean, p95]`` microseconds of
+    the host time before the enqueue (from the last ``Done``, or from where the
+    program's span opened if later), ``launch + completion`` (enqueue end ->
+    ``Done`` start less the device's time: exact whatever the shift; not of a
+    program enqueued before the last was done), the device's time, the host time
+    after ``Done`` (until the next span opens). A program goes by the ``program``
+    of the ``heat.force`` (``forces``: sorted ``(start, end, program)``) opened last
+    before its enqueue ended and after the one before, else ``-``."""
+    groups, opens, row = {}, [f[0] for f in forces], None
+    for k, (m, q, d) in enumerate(zip(modules, enqueues, dones)):
+        i = bisect.bisect_right(opens, q[1]) - 1
+        force = forces[i] if i >= 0 and (not k or forces[i][0] > enqueues[k - 1][1]) else None
+        free = dones[k - 1][0] if k else force[0] if force else q[0]  # the host has the last result
+        opened = max(free, force[0]) if force else free
+        if row:
+            row[3] = opened - free
+        row = [max(0.0, q[1] - opened), d[0] - q[1] - (m[1] - m[0]) if q[1] >= free else None, m[1] - m[0], 0.0]
+        for key in ("all", force[2] if force else "-"):
+            groups.setdefault(key, []).append(row)
+
+    def stat(values):
+        values = sorted(v for v in values if v is not None) or [0.0]
+        return [1e6 * sum(values) / len(values), 1e6 * values[-(-95 * len(values) // 100) - 1]]
+
+    return {k: {"n": len(rs), **{nm: stat(r[j] for r in rs) for j, nm in enumerate(_TRIP)}} for k, rs in groups.items()}
+
+
+def _trace_events(path: str):
+    """Of an ``.xplane.pb``: per device its ``ops`` and ``modules`` (a
+    ``/device:*:<n>`` plane's ``XLA Ops`` / ``XLA Modules`` lines) and the runtime's
+    ``enqueues`` and ``dones`` for it (host events, by their ``device_ordinal`` /
+    ``core_id`` stat), sorted ``(start, end)`` seconds; the ``heat.*`` spans ``(start,
+    end, name)``; the ``heat.force`` ones ``(start, end, program)``. A CPU backend's
+    operations are the host events with an ``hlo_op``, by ``device_ordinal``."""
+    devices, spans, forces, ordinal = {}, [], [], {}
+    kinds = {"XLA Ops": "ops", "XLA Modules": "modules", _ENQUEUE: "enqueues", _DONE: "dones"}
+
+    def add(name, kind, e):
+        lists = devices.setdefault(name, {k: [] for k in kinds.values()})
+        lists[kind].append((e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9))
 
     planes = list(jax.profiler.ProfileData.from_file(path).planes)
     for plane in planes:
-        for line in plane.lines:
-            if plane.name.startswith("/device:") and line.name == "XLA Ops":
-                devices[plane.name] = [seconds(e, "busy") for e in line.events]
-            elif plane.name == "/host:CPU":
-                spans += [seconds(e, e.name) for e in line.events if e.name.startswith("heat.")]
-    if not any(devices.values()):  # a CPU backend: no device plane
-        for e in (e for pl in planes if pl.name == "/host:CPU" for ln in pl.lines for e in ln.events):
-            stats = dict(e.stats)
-            if "hlo_op" in stats:
-                devices.setdefault(f"cpu:{stats.get('device_ordinal', 0)}", []).append(seconds(e, "busy"))
-    if not any(devices.values()):
+        if plane.name.startswith("/device:") and plane.name.rsplit(":", 1)[1].isdigit():
+            ordinal[int(plane.name.rsplit(":", 1)[1])] = plane.name
+            for kind, e in ((kinds[ln.name], e) for ln in plane.lines if ln.name in kinds for e in ln.events):
+                add(plane.name, kind, e)
+    for e in (e for pl in planes if pl.name == "/host:CPU" for ln in pl.lines for e in ln.events):
+        stats = dict(e.stats) if e.name in (_ENQUEUE, _DONE, "heat.force") or not ordinal else {}
+        at = ordinal.get(stats.get("device_ordinal", stats.get("core_id", 0)))
+        if e.name.startswith("heat."):
+            spans.append((e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, e.name))
+            if e.name == "heat.force":
+                forces.append((*spans[-1][:2], str(stats.get("program", "-"))))
+        elif e.name in (_ENQUEUE, _DONE) and at is not None:
+            add(at, kinds[e.name], e)
+        elif "hlo_op" in stats:  # a CPU backend: no device plane
+            add(f"cpu:{stats.get('device_ordinal', 0)}", "ops", e)
+    return {n: {kind: sorted(events) for kind, events in d.items()} for n, d in devices.items()}, spans, sorted(forces)
+
+
+def _gaps_doc(path: str) -> Dict[str, Any]:
+    """Busy and idle seconds of the busiest device in a profiler trace inside
+    the window from the first ``heat.*`` span's start to the last one's end (the
+    device's lines where the profiler put them), and the idle time cut on ONE
+    clock (:func:`_cut`, at the shifts of :func:`_shift_window`; ``shifts_us``: every
+    device's), with :func:`_round_trips`. A CPU backend's gaps are all the host's."""
+    found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)) if os.path.isdir(path) else [path]
+    if not found:
+        raise ValueError(f"no .xplane.pb under {path}")
+    path = found[-1]
+    devices, spans, forces = _trace_events(path)
+    if not any(d["ops"] for d in devices.values()):
         raise ValueError(f"{path} holds no device operation")
-    every = spans or [op for ops in devices.values() for op in ops]
+    every = spans or [op for d in devices.values() for op in d["ops"]]
     lo, hi = min(iv[0] for iv in every), max(iv[1] for iv in every)
-    busy = {  # per device, the union of its (nesting) operations inside the window
-        name: _innermost((max(s, lo), min(e, hi), b) for s, e, b in ops if e > lo and s < hi)
-        for name, ops in devices.items()
-    }
-    busy_s = {name: sum(e - s for s, e, _ in pieces) for name, pieces in busy.items()}
+    busy = {n: _innermost((max(s, lo), min(e, hi), "") for s, e in d["ops"] if e > lo and s < hi) for n, d in devices.items()}
+    busy_s = {n: sum(e - s for s, e, _ in pieces) for n, pieces in busy.items()}  # the union of a device's operations
     device = max(busy_s, key=busy_s.get)
     edges = [lo] + [t for piece in busy[device] for t in piece[:2]] + [hi]
     gaps = [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
-    idle = (hi - lo) - busy_s[device]
-    by_span = _overlap(_innermost(spans), gaps)
-    by_span["outside"] = idle - sum(by_span.values())
+    windows = {n: _shift_window(d["modules"], d["enqueues"], d["dones"]) for n, d in devices.items()}
+    window, idle = windows[device], (hi - lo) - busy_s[device]
+    matched = [devices[device][k][: window["programs"]] for k in ("modules", "enqueues", "dones")]
     return {
-        "source": path, "device": device, "devices": len(devices),
-        "window_s": hi - lo, "busy_s": busy_s[device], "idle_s": idle,
-        "idle_pct": 100.0 * idle / (hi - lo),
-        "idle_by_span_s": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
+        "source": path, "device": device, "devices": len(devices), "window_s": hi - lo,
+        "busy_s": busy_s[device], "idle_s": idle, "idle_pct": 100.0 * idle / (hi - lo),
+        "aligned": "by the runtime's events" if window["programs"] else "none needed", **window,
+        "shifts_us": {name: [w["shift_lo_us"], w["shift_hi_us"]] for name, w in windows.items()},
+        **_cut(gaps, *matched, spans, window["by_part_us"]),
+        "round_trips": _round_trips(*matched, forces),
     }
 
 
 def _show_gaps(doc: Dict[str, Any], out) -> None:
+    def row(name, secs):
+        print(f"  {name:<26} {secs:.6f} s  {100.0 * secs / (doc['idle_s'] or 1.0):5.1f} %", file=out)
+
     print(
         f"gaps ({doc['source']}): {doc['device']} (busiest of {doc['devices']}), window "
-        f"{doc['window_s']:.6f} s, busy {doc['busy_s']:.6f} s, idle {doc['idle_s']:.6f} s "
-        f"({doc['idle_pct']:.2f} %); idle by innermost heat.* span:",
+        f"{doc['window_s']:.6f} s, busy {doc['busy_s']:.6f} s, idle {doc['idle_s']:.6f} s ({doc['idle_pct']:.2f} %)\n"
+        f"device lines aligned: {doc['aligned']}; {doc['programs']} program(s) matched, {doc['unmatched']} "
+        f"event(s) unmatched; shift {doc['shift_lo_us']:+.1f} .. {doc['shift_hi_us']:+.1f} us"
+        + ("" if doc["consistent"] else "  INCONSISTENT: no one shift fits the session")
+        + "; by part of the session: " + ", ".join("{:+.0f} .. {:+.0f}".format(*w) for w in doc["by_part_us"])
+        + "\nidle time, cut on the host's clock:",
         file=out,
     )
+    row("launch + completion", doc["launch_plus_completion_s"])
+    for name in ("launch", "completion"):  # a pair each: at the low shift of each part, at the high
+        print("    {:<24} {:.6f} s at the low shifts, {:.6f} s at the high".format(name, *doc[name + "_s"]), file=out)
+    row("queued behind a program", doc["queued_s"])
+    row("inside a program", doc["inside_program_s"])
+    print("the host's part, idle by innermost heat.* span:", file=out)
     for name, secs in doc["idle_by_span_s"].items():
-        print(f"  {name:<24} {secs:.6f} s  {100.0 * secs / (doc['idle_s'] or 1.0):5.1f} %", file=out)
+        row(name, secs)
+    print("round trips, mean / p95 us: " + "; ".join(_TRIP), file=out)
+    for key, trip in doc["round_trips"].items():
+        print(f"  {key:<18} x{trip['n']:<6} " + "; ".join("{:9.1f} /{:9.1f}".format(*trip[nm]) for nm in _TRIP), file=out)
 
 
 def main(argv: Optional[List[str]] = None, out=None) -> int:
@@ -866,9 +979,11 @@ def main(argv: Optional[List[str]] = None, out=None) -> int:
     )
     p_gaps = sub.add_parser(
         "gaps",
-        help="a jax.profiler trace: busy and idle seconds of the busiest "
-        "device, and the idle time by the innermost heat.* span "
-        "(heat.force.<phase>, heat.place, heat.read) that covered it",
+        help="a jax.profiler trace on one clock: busy and idle seconds of the "
+        "busiest device, the window of the shift between its lines and the "
+        "host's (from the runtime's enqueue and Done events), and every idle "
+        "gap cut into the host's part by innermost heat.* span, launch + "
+        "completion, queued and inside-program time; round trips by program",
     )
     p_gaps.add_argument("trace", help="a profiler trace directory or an .xplane.pb file")
     p_gaps.add_argument("--json", action="store_true", help="emit JSON instead of text")

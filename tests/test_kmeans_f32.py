@@ -357,7 +357,7 @@ def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
     assert (parent[3]["mode"], int(parent[3]["n"]), int(parent[3]["f"]), int(parent[3]["k"])) == ("jnp", 1024, F, K)
     children = sorted((sp for sp in spans if sp is not parent), key=lambda sp: sp[1])
     names = [sp[0].rsplit(".", 1)[1] for sp in children]
-    assert names == ["init", "prepare", "dispatch", "sync", "wrap"]
+    assert names == ["init", "prepare", "dispatch", "sync", "copy", "wrap"]
     assert all(parent[1] <= sp[1] and sp[2] <= parent[2] for sp in children)
     assert all(a[2] <= b[1] for a, b in zip(children, children[1:])), "children overlap"
 

@@ -264,7 +264,7 @@ def test_spans_of_a_call_in_a_profiler_session(method, mode):
     }.items()
     children = sorted((s for s in spans if s[0].startswith("heat.qr.")), key=lambda s: s[1])
     names = [s[0].rsplit(".", 1)[1] for s in children]
-    assert names == (["prepare", "dispatch", "sync", "wrap"] if method == "auto" else ["prepare", "dispatch", "wrap"])
+    assert names == (["prepare", "dispatch", "sync", "copy", "wrap"] if method == "auto" else ["prepare", "dispatch", "wrap"])
     assert all(parent[1] <= s[1] and s[2] <= parent[2] for s in children)
     assert all(a[2] <= b[1] for a, b in zip(children, children[1:])), "children overlap"
     if method == "auto":  # the engine's own force lies under the call's one blocking read

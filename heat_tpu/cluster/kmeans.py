@@ -164,7 +164,9 @@ class KMeans(_KCluster):
         """Cluster ``x`` (n_samples, n_features) (reference kmeans.py:102-139).
 
         While ``telemetry.tracing()`` the fit is a ``heat.kmeans.fit`` span
-        (stats ``mode``, ``n``, ``f``, ``k``) whose children lie side by
+        (stats ``mode``, ``n``, ``f``, ``k``, and ``blocks`` / ``tail_blocks``:
+        the grid steps of one pass of the fused kernel and those of them that
+        take its masked body, 0 on the jnp path) whose children lie side by
         side: ``.init`` (the initial centres), ``.prepare`` (the dtype cast;
         no pass over the rows: the fused program reads them in place),
         ``.dispatch`` (the call of the fit's one Lloyd program, which stops
@@ -187,6 +189,7 @@ class KMeans(_KCluster):
         )
         try:
             counts = self._fit(x, mode, interpret, ph.phase)
+            ph.note(blocks=counts["blocks"], tail_blocks=counts["tail_blocks"])
         finally:
             ph.close()
         fusion.note_phases("kmeans", ph.ns, fits=1, **counts)
@@ -200,7 +203,8 @@ class KMeans(_KCluster):
         made (one each: the fit is one program, which checks convergence
         itself) and the XLA label passes over the rows that the program ran
         (a fused program's labels are its last kernel pass's, the jnp
-        program's its last iteration's: none)."""
+        program's its last iteration's: none), and ``blocks`` / ``tail_blocks``
+        (``ops/lloyd.py::pass_blocks`` of one device's samples)."""
         k, n_global = self.n_clusters, int(x.shape[0])
         mark("init")
         centers = self._initialize_cluster_centers(x)
@@ -244,4 +248,17 @@ class KMeans(_KCluster):
         )
         self._labels = self._wrap_labels(labels, x)
         epilogues = _lloyd.RUN_LABEL_EPILOGUES if mode else 0
-        return {"dispatches": 1, "syncs": 1, "label_epilogues": epilogues}
+        blocks = tail_blocks = 0
+        if mode:
+            # one device's samples, and the fewest valid among them any device
+            # holds (the last one's): that device masks the most blocks
+            rows = int(data.shape[0])
+            local = rows // (x.comm.size if mode == "sharded" else 1)
+            valid = min(max(n_global - (rows - local), 0), local)
+            blocks, tail_blocks = _lloyd.pass_blocks(
+                local, valid, int(x.shape[1]), k, jnp.dtype(ddtype).itemsize
+            )
+        return {
+            "dispatches": 1, "syncs": 1, "label_epilogues": epilogues,
+            "blocks": blocks, "tail_blocks": tail_blocks,
+        }

@@ -533,8 +533,11 @@ _STATS.update(
 # per phase of the heat.<prefix> span's children, and the region's own counts.
 # cluster/kmeans.py (heat.kmeans.fit): fits, the programs they dispatched,
 # their blocking host reads, the XLA label passes over the rows those programs
-# ran. spatial/distance.py (heat.cdist): calls, and the operand rotations
-# (collective-permutes of one operand shard) their tile programs made.
+# ran, and the grid steps of one pass of the fused kernel with those of them
+# that took the masked body (the blocks that do not lie wholly under n_valid;
+# 0 and 0 on the jnp path). spatial/distance.py (heat.cdist): calls, and the
+# operand rotations (collective-permutes of one operand shard) their tile
+# programs made.
 # core/linalg/qr.py (heat.qr): calls, their blocking host reads (the
 # CholeskyQR2 probe's one), the CholeskyQR2 attempts whose probe failed and
 # fell to Householder, and the calls whose CholeskyQR2 program took its tall
@@ -547,7 +550,8 @@ _STATS.update({f"phase_cdist_{name}_ns": 0 for name in _CDIST_PHASES})
 _STATS.update({f"phase_qr_{name}_ns": 0 for name in _QR_PHASES})
 _STATS.update(
     phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
-    phase_kmeans_label_epilogues=0, phase_cdist_calls=0, phase_cdist_rotations=0,
+    phase_kmeans_label_epilogues=0, phase_kmeans_blocks=0, phase_kmeans_tail_blocks=0,
+    phase_cdist_calls=0, phase_cdist_rotations=0,
     phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0, phase_qr_blocked=0,
 )
 # place, read, a fit, a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:

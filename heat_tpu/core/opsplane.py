@@ -171,6 +171,8 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_kmeans_dispatches_total", (_C, "Lloyd programs dispatched by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_syncs_total", (_C, "Blocking host reads (n_iter and inertia together) made by timed KMeans fits.", [])),
         ("heat_tpu_kmeans_label_epilogues_total", (_C, "XLA label passes over the rows run by the Lloyd programs of timed KMeans fits.", [])),
+        ("heat_tpu_kmeans_blocks_total", (_C, "Grid steps of one pass of the fused Lloyd kernel, added once per timed KMeans fit (0 on the jnp path).", [])),
+        ("heat_tpu_kmeans_tail_blocks_total", (_C, "Those grid steps of a pass that took the kernel's masked body (blocks not wholly under n_valid; on sharded rows the device with the most), added once per timed KMeans fit.", [])),
         ("heat_tpu_kmeans_phase_seconds_total", (_C, "Host time of timed KMeans fits, by phase (init/prepare/dispatch/sync/copy/wrap).", ["phase"])),
         ("heat_tpu_cdist_calls_total", (_C, "Distance-matrix calls (cdist/rbf/manhattan) whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_cdist_rotations_total", (_C, "Operand-shard rotations (collective-permutes) made by the tile programs of timed distance-matrix calls.", [])),
@@ -306,7 +308,7 @@ def _collect_fusion(out: List[Sample]) -> None:
             stats[f"phase_{phase}_ns"] * 1e-9,
         ))
     for prefix, counts, phases in (
-        ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues"), fusion._KMEANS_PHASES),
+        ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues", "blocks", "tail_blocks"), fusion._KMEANS_PHASES),
         ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
         ("qr", ("calls", "syncs", "fallbacks", "blocked"), fusion._QR_PHASES),
     ):

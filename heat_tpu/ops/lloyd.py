@@ -28,7 +28,13 @@ the program that holds the kernel, where it is a bitcast of the rows as
 XLA:TPU lays a narrow (n, f) array out (samples already in lanes); nothing
 pads the sample axis: the grid is ``cdiv(n, block)``, the last block ends
 inside the operand, and what its tail holds is masked like any column at or
-beyond ``n_valid``. Per-iteration HBM traffic is n·f reads and nothing
+beyond ``n_valid``. Only a block that HAS such columns pays for the mask: the
+kernel tests ``(i + 1) * block <= n_valid`` on the scalar unit (``n_valid``
+is an SMEM operand) and runs its body without the column index, the selects
+on the rows and the ``and`` on the one-hot rows wherever the block lies
+wholly under ``n_valid``: 2 139 blocks of 2 140 at the benchmark shape, to
+the bit the same sums, counts, labels and squares (PERF.md, PR 38).
+Per-iteration HBM traffic is n·f reads and nothing
 per-row written, except in the LAST pass of a program: that one stores the
 ``labels`` row it already holds, a lane-dense (1, block) int32 block (4 bytes
 a sample beside the 4·f it reads), and adds up the squares of the float32
@@ -127,6 +133,16 @@ def _block_cols(f: int, k: int, itemsize: int = 4) -> int:
     return max(1024, min(65536, blk // 128 * 128))
 
 
+def pass_blocks(n: int, n_valid: int, f: int, k: int, itemsize: int = 4) -> tuple:
+    """``(blocks, tail_blocks)``: the grid steps of one kernel pass over a
+    device's ``n`` samples, and those of them that take the masked body
+    because the block does not lie wholly under ``n_valid`` (the ragged tail,
+    padding). From shapes alone: what ``KMeans.fit`` notes on its span."""
+    block = _block_cols(f, k, itemsize)
+    blocks = -(-n // block)
+    return blocks, blocks - n_valid // block
+
+
 def fused_supported(n: int, f: int, k: int) -> bool:
     """TPU backend, single device (the kernel has no partitioning spec —
     a sharded operand would be gathered), and sublane-safe f/k."""
@@ -166,12 +182,30 @@ def _lloyd_kernel(
     *,
     kp: int,
     block: int,
+    masked=None,
 ):
     """One (f, block) sample block; accumulators live across the whole grid.
     Samples at column index >= nvalid (the last block's tail beyond the
     operand's end, or a device's share of the global padding under the sharded
     wrapper) are masked out of every accumulator. n_valid is a runtime (1, 1)
-    scalar operand so each device can carry its own count.
+    scalar operand in SMEM, so each device can carry its own count and the
+    kernel can branch on it.
+
+    **Only the block that has a tail is masked.** The mask is vector work in a
+    kernel that its vector unit binds (eight operations a 128-lane tile as
+    Mosaic lowers it: the column index, its compare, the selects on the rows,
+    the ``and`` on the one-hot rows), and at the benchmark's shape 2 139
+    blocks of 2 140 lie wholly under ``n_valid``, where every select returns
+    its operand. So the kernel picks its body from the block in hand:
+    ``(i + 1) * block <= n_valid``, a scalar test, runs the body without
+    ``cols`` and ``valid``; any other block (the ragged tail, a device's
+    padding, a block wholly beyond ``n_valid``) runs the masked body. Both are
+    built by one function (``body(masked)``), so the arithmetic is written
+    once, and a whole block's sums, counts, labels and squares are the masked
+    body's to the bit. ``masked`` is for the tests: ``None`` lets the block
+    decide, ``True`` runs the masked body on every block (6.56 ms a pass of
+    2^26 x 16 rows that way, 6.40 as shipped; the last pass 7.40 and 6.84,
+    where the select's result was a second copy of the block: PERF.md, PR 38).
 
     ``xsq_ref`` and ``labels_ref`` are the two outputs of a program's last
     pass alone. ``labels_ref`` takes the block's (1, block) argmin row as it
@@ -198,27 +232,7 @@ def _lloyd_kernel(
     replicated in destination but not in source" (observed on a v5e: each
     construct passes alone — only the 1-D chain fails)."""
     i = pl.program_id(0)
-
-    cols = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    valid = cols < nvalid_ref[0, 0]  # (1, block) bool
-
-    # Content at columns >= nvalid is UNSPECIFIED (the dndarray.parray
-    # contract; the last block's tail beyond the operand) — inf/NaN there
-    # would poison the accumulators through 0·inf = NaN in the sums
-    # contraction, so zero invalid samples rather than relying on
-    # multiplicative masking downstream.
-    xb = jnp.where(valid, xT_ref[:, :], 0)  # (f, block)
-    pieces = _bf16_pieces(xb)
-    stack = jnp.concatenate(pieces, axis=0)  # (p·f, block) bf16
-
-    # (kp, block) assignment scores; |x|² omitted (sample-constant for argmin)
-    dots = jnp.dot(c_ref[:, :], stack, preferred_element_type=jnp.float32)
-    score = csq_ref[:, :] + sum(
-        dots[g * kp : (g + 1) * kp] for g in range(len(pieces))
-    )
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
-    labels = jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)  # (1, block)
-    onehot = jnp.logical_and(labels == kcol, valid).astype(jnp.bfloat16)  # (kp, block)
+    nvalid = nvalid_ref[0, 0]
 
     @pl.when(i == 0)
     def _init():
@@ -227,23 +241,56 @@ def _lloyd_kernel(
         if xsq_ref is not None:
             xsq_ref[:, :] = jnp.zeros_like(xsq_ref)
 
-    # sums by piece, (kp, p·f): contract the lane (sample) axes of both
-    # operands on the MXU — dot_general, so neither is transposed. The one-hot
-    # rows are the streamed operand: with the p·f stack rows streamed against
-    # it the same product took 9.5 ms a pass where this takes 7.4 (v5e, 2^26 x
-    # 16, k = 8: PERF.md, PR 29)
-    sums_ref[:, :] += jax.lax.dot_general(
-        onehot, stack, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    # accumulate the count in f32: a bf16 onehot sum saturates at 256
-    counts_ref[:, :] += jnp.sum(
-        onehot, axis=1, keepdims=True, dtype=counts_ref.dtype
-    )
-    if labels_ref is None:
+    def body(masked: bool):
+        xb = xT_ref[:, :]  # (f, block)
+        if masked:
+            cols = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+            valid = cols < nvalid  # (1, block) bool
+            # Content at columns >= nvalid is UNSPECIFIED (the dndarray.parray
+            # contract; the last block's tail beyond the operand) — inf/NaN there
+            # would poison the accumulators through 0·inf = NaN in the sums
+            # contraction, so zero invalid samples rather than relying on
+            # multiplicative masking downstream.
+            xb = jnp.where(valid, xb, 0)
+        pieces = _bf16_pieces(xb)
+        stack = jnp.concatenate(pieces, axis=0)  # (p·f, block) bf16
+
+        # (kp, block) assignment scores; |x|² omitted (sample-constant for argmin)
+        dots = jnp.dot(c_ref[:, :], stack, preferred_element_type=jnp.float32)
+        score = csq_ref[:, :] + sum(
+            dots[g * kp : (g + 1) * kp] for g in range(len(pieces))
+        )
+        kcol = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
+        labels = jnp.argmin(score, axis=0, keepdims=True).astype(jnp.int32)  # (1, block)
+        hit = labels == kcol
+        if masked:
+            hit = jnp.logical_and(hit, valid)
+        onehot = hit.astype(jnp.bfloat16)  # (kp, block)
+
+        # sums by piece, (kp, p·f): contract the lane (sample) axes of both
+        # operands on the MXU — dot_general, so neither is transposed. The one-hot
+        # rows are the streamed operand: with the p·f stack rows streamed against
+        # it the same product took 9.5 ms a pass where this takes 7.4 (v5e, 2^26 x
+        # 16, k = 8: PERF.md, PR 29)
+        sums_ref[:, :] += jax.lax.dot_general(
+            onehot, stack, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        # accumulate the count in f32: a bf16 onehot sum saturates at 256
+        counts_ref[:, :] += jnp.sum(
+            onehot, axis=1, keepdims=True, dtype=counts_ref.dtype
+        )
+        if labels_ref is None:
+            return
+        labels_ref[:, :] = labels
+        x32 = xb.astype(jnp.float32)  # zero at invalid samples
+        xsq_ref[:, :] += _fold_lanes(x32 * x32)
+
+    if masked is not None:
+        body(masked)
         return
-    labels_ref[:, :] = labels
-    x32 = xb.astype(jnp.float32)  # zero at invalid samples
-    xsq_ref[:, :] += _fold_lanes(x32 * x32)
+    whole = (i + 1) * block <= nvalid
+    pl.when(whole)(functools.partial(body, False))
+    pl.when(jnp.logical_not(whole))(functools.partial(body, True))
 
 
 def _prepare(data: jax.Array) -> jax.Array:
@@ -260,7 +307,7 @@ def _prepare(data: jax.Array) -> jax.Array:
     return jnp.transpose(x)
 
 
-def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool, last: bool = False):
+def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool, last: bool = False, masked=None):
     """Invoke the kernel on a samples-in-lanes (f, n) operand, read in place:
     the grid is ``cdiv(n, block)`` and the last block ends inside the operand
     (its tail is unspecified and masked, as every column >= ``n_valid``).
@@ -305,14 +352,14 @@ def _kernel_call_T(xT, centers, k: int, n_valid, interpret: bool, last: bool = F
             pl.BlockSpec((1, block), lambda i: (0, i), memory_space=pltpu.VMEM),
         ]
     sums, counts, *rest = pl.pallas_call(
-        functools.partial(_lloyd_kernel, kp=kp, block=block),
+        functools.partial(_lloyd_kernel, kp=kp, block=block, masked=masked),
         out_shape=out_shape,
         grid=(pl.cdiv(n, block),),
         in_specs=[
             pl.BlockSpec((f, block), lambda i: (0, i), memory_space=pltpu.VMEM),
             whole((kp, 1)),
             whole((p * kp, p * f)),
-            whole((1, 1)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),  # a scalar to branch on
         ],
         out_specs=out_specs,
         interpret=interpret,

@@ -41,6 +41,7 @@ CENTERS_LIMIT, INERTIA_LIMIT = 1e-5, 1e-5
 MODES = ("single", "sharded", "jnp")
 KMEANS_KEYS = [f"phase_kmeans_{name}_ns" for name in fusion._KMEANS_PHASES] + [
     "phase_kmeans_fits", "phase_kmeans_dispatches", "phase_kmeans_syncs", "phase_kmeans_label_epilogues",
+    "phase_kmeans_blocks", "phase_kmeans_tail_blocks",
 ]
 
 
@@ -300,8 +301,29 @@ def test_counters_of_one_fit_with_telemetry_on(mode, rows, monkeypatch):
     # the program runs no XLA label pass over the rows: the fused program's last
     # kernel pass writes the labels, the jnp program's last iteration gives them
     assert got["phase_kmeans_label_epilogues"] == 0
+    # the grid steps of one kernel pass and those that took the masked body, once
+    # a fit (ISSUE 38): a device's 128 rows are one block, which is the tail block;
+    # the jnp program runs no kernel
+    blocks = (got["phase_kmeans_blocks"], got["phase_kmeans_tail_blocks"])
+    assert blocks == ((1, 1) if mode == "sharded" else (0, 0))
     for name in fusion._KMEANS_PHASES:
         assert got[f"phase_kmeans_{name}_ns"] > 0, name
+
+
+@pytest.mark.parametrize("mode,whole,over,want", [("sharded", 2, 5, (3, 1)), ("single", 2, 0, (2, 0))])
+def test_block_counters_of_a_fit_over_whole_blocks_and_a_tail(mode, whole, over, want, monkeypatch):
+    """Sharded rows, two whole blocks and five rows a device with three rows
+    of padding on the last: ``phase_kmeans_blocks`` 3 a fit and
+    ``phase_kmeans_tail_blocks`` 1, whatever ``max_iter``; one device's two
+    whole blocks alone: 2 and 0, no block is masked."""
+    devices = ht.get_comm().size if mode == "sharded" else 1
+    n = devices * (whole * lloyd._block_cols(F, K) + over) - (3 if over else 0)
+    data = np.random.default_rng(38).standard_normal((n, F)).astype(np.float32)
+    before = fusion.cache_stats()
+    with telemetry.enabled(1):
+        fit(mode, data, data[:K], monkeypatch, iters=2)
+    got = _kmeans_delta(before)
+    assert (got["phase_kmeans_fits"], got["phase_kmeans_blocks"], got["phase_kmeans_tail_blocks"]) == (1, *want)
 
 
 def test_label_epilogues_reader_on_a_made_up_window():
@@ -329,18 +351,20 @@ def test_counters_stay_where_they_are_with_telemetry_off(rows, monkeypatch):
     assert _kmeans_delta(before) == dict.fromkeys(KMEANS_KEYS, 0)
 
 
-def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
+@pytest.mark.parametrize("mode", ("jnp", "sharded"))
+def test_spans_of_one_fit_in_a_profiler_session(mode, rows, monkeypatch):
     """``heat.kmeans.fit`` with its children side by side, in the session's
-    own ``.xplane.pb``, and the ``gaps`` verb's reader sees them."""
+    own ``.xplane.pb``, and the ``gaps`` verb's reader sees them; the parent
+    carries the grid steps of a kernel pass and its masked ones (ISSUE 38)."""
     data, init = rows
-    fit("jnp", data[:1024], init, monkeypatch)
+    fit(mode, data[:1024], init, monkeypatch)
     with tempfile.TemporaryDirectory() as directory:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
         jax.profiler.start_trace(directory, profiler_options=options)
         try:
-            fit("jnp", data[:1024], init, monkeypatch)
+            fit(mode, data[:1024], init, monkeypatch)
         finally:
             jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(directory, "plugins", "profile", "*", "*.xplane.pb"))
@@ -354,7 +378,8 @@ def test_spans_of_one_fit_in_a_profiler_session(rows, monkeypatch):
                         for line in plane.lines for e in line.events if e.name.startswith("heat.kmeans.")
                     ]
     (parent,) = [sp for sp in spans if sp[0] == "heat.kmeans.fit"]
-    assert (parent[3]["mode"], int(parent[3]["n"]), int(parent[3]["f"]), int(parent[3]["k"])) == ("jnp", 1024, F, K)
+    assert (parent[3]["mode"], int(parent[3]["n"]), int(parent[3]["f"]), int(parent[3]["k"])) == (mode, 1024, F, K)
+    assert (int(parent[3]["blocks"]), int(parent[3]["tail_blocks"])) == ((1, 1) if mode == "sharded" else (0, 0))
     children = sorted((sp for sp in spans if sp is not parent), key=lambda sp: sp[1])
     names = [sp[0].rsplit(".", 1)[1] for sp in children]
     assert names == ["init", "prepare", "dispatch", "sync", "copy", "wrap"]
@@ -414,7 +439,9 @@ def test_kernel_compiles_for_a_v5e_inside_scoped_vmem(one_v5e, dtype, f, k, n, l
     inside its last block, off a lane-tile boundary (as the labels do); and
     on fewer rows than one block (ISSUE 32), where the (f, block) input block
     and the (1, block) labels block are WIDER than their arrays: interpret
-    mode cannot show Mosaic refusing that."""
+    mode cannot show Mosaic refusing that. Since ISSUE 38 the kernel holds two
+    bodies, the masked one and the one for whole blocks, under ``pl.when``:
+    they are never live together and both fit the block one fitted."""
     block = lloyd._block_cols(f, k, jnp.dtype(dtype).itemsize)
     assert n is None or n < block
     xT = jax.ShapeDtypeStruct((f, n or 4 * block - 77), jnp.dtype(dtype), sharding=one_v5e)

@@ -196,6 +196,141 @@ def test_rows_are_read_in_place_whatever_their_number(blocks, over, mode):
     np.testing.assert_allclose(float(got[2]), last, rtol=1e-5)
 
 
+def _garbage(rows, f):
+    """Rows of NaN and inf by turns: what the payload may hold beyond ``n_valid``."""
+    bad = np.full((rows, f), np.nan, np.float32)
+    bad[1::2] = np.inf
+    bad[3::4] = -np.inf
+    return bad
+
+
+def _last_pass(payload, centers, n_valid, mode, masked, last=True):
+    """The accumulators of ONE kernel pass, per device and unmerged, with the
+    kernel's body chosen by ``masked`` (``None``: the block decides; ``True``:
+    the masked body on every block, as before ISSUE 38): the sums, the counts
+    and, from a ``last`` pass, Σ|x|² and the labels. ``sharded``: each device
+    runs the pass on its own rows with its own ``n_valid``, as
+    ``fused_lloyd_run_sharded`` has it, and nothing is summed over devices."""
+    import jax
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+    from heat_tpu.ops.lloyd import _kernel_call_T, _prepare
+
+    def one(xl, valid):
+        got = _kernel_call_T(_prepare(xl), centers, _K, valid, True, last, masked)
+        return tuple(jnp.reshape(g, (1, -1)) for g in got[:3]) + tuple(got[3:])  # a row a device; the labels as they are
+
+    if mode == "single":
+        return jax.jit(lambda x: one(x, jnp.asarray(n_valid, jnp.int32)))(jnp.asarray(payload))
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    comm = ht.get_comm()
+    local = payload.shape[0] // comm.size
+
+    def device(xl):
+        valid = jnp.clip(n_valid - jax.lax.axis_index(comm.axis_name) * local, 0, local)
+        return one(xl, valid)
+
+    run = jax.jit(jax.shard_map(
+        device, mesh=comm.mesh, in_specs=P(comm.axis_name, None), out_specs=P(comm.axis_name), check_vma=False
+    ))
+    return run(jax.device_put(payload, NamedSharding(comm.mesh, P(comm.axis_name, None))))
+
+
+@pytest.mark.parametrize("mode", ["single", "sharded"])
+@pytest.mark.parametrize("blocks,over", [(2, 0), (2, 1), (3, -77), (0, 1000)], ids=["2b", "2b+1", "3b-77", "under-a-block"])
+def test_whole_blocks_take_the_unmasked_body_bit_equal_to_the_masked_one(blocks, over, mode):
+    """ISSUE 38: a block whose last column lies under ``n_valid`` runs a body
+    without ``cols``, ``valid``, the ``where`` on the rows and the ``and`` on
+    the one-hot rows; every other block runs the masked body. On a whole block
+    the mask is the identity, so a pass's sums, counts, labels and Σ|x|² are
+    BIT-equal to the masked body's run on every block, with NaN, +inf and -inf in
+    the payload beyond ``n_valid``: at two whole blocks, one sample over, ragged
+    after three, and under one block (the one block is the tail block). ``single``:
+    48 rows of garbage follow the valid ones, so at two whole blocks the grid's
+    third block lies wholly beyond ``n_valid``. ``sharded``: every device holds
+    that many rows, the last but one is valid to a little over its half (a tail
+    inside its second block, whole garbage blocks after it) and the last device
+    holds no valid row at all: every block of it takes the masked body and adds
+    nothing."""
+    import heat_tpu as ht
+    from heat_tpu.ops.lloyd import _block_cols, pass_blocks
+
+    block = _block_cols(_F, _K)
+    rows = blocks * block + over
+    data_np, centers = _rows(38, rows, _F, _K)
+    if mode == "single":
+        n_valid = rows
+        payload = np.concatenate([data_np, _garbage(48, _F)])
+        want_tail = pass_blocks(rows + 48, n_valid, _F, _K)[1]
+    else:
+        p = ht.get_comm().size
+        n_valid = (p - 2) * rows + rows // 2 + 3
+        payload = np.concatenate([np.tile(data_np, (p, 1))[:n_valid], _garbage(p * rows - n_valid, _F)])
+        want_tail = pass_blocks(rows, 0, _F, _K)[1]  # the last device's: all of its blocks
+        assert want_tail == -(-rows // block)
+    assert want_tail >= 1
+    for last in (True, False):
+        got = _last_pass(payload, centers, n_valid, mode, None, last)
+        want = _last_pass(payload, centers, n_valid, mode, True, last)
+        assert len(got) == (4 if last else 2)
+        for name, a, b in zip(("sums", "counts", "xsq", "labels"), got, want):
+            a, b = np.asarray(a), np.asarray(b)
+            if name == "labels":  # beyond n_valid a label is unspecified, by either body
+                a, b = a[:n_valid], b[:n_valid]
+            assert np.isfinite(a).all(), name
+            np.testing.assert_array_equal(a, b, err_msg=f"{name}, last={last}")
+    counts = np.asarray(got[1])
+    assert counts.sum() == n_valid  # no row of garbage was counted, by any device
+    if mode == "sharded":
+        assert counts[-1].sum() == 0 and counts[-2].sum() == rows // 2 + 3
+
+
+def test_a_device_whose_blocks_lie_wholly_beyond_its_n_valid_adds_nothing_to_a_sharded_run():
+    """``fused_lloyd_run_sharded`` on a payload whose logical length leaves
+    the last device no valid row and the one before it whole blocks of
+    garbage: the fit is the jnp program's on the logical rows, labels for
+    labels, and no NaN or inf of the payload reaches a centre or the inertia."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import heat_tpu as ht
+    from heat_tpu.cluster.kmeans import _lloyd_run
+    from heat_tpu.ops.lloyd import _block_cols, fused_lloyd_run_sharded
+
+    comm = ht.get_comm()
+    local = 2 * _block_cols(_F, _K) + 1
+    n = (comm.size - 2) * local + 5  # five valid rows on the last device but one
+    data_np, centers = _rows(39, n, _F, _K)
+    physical = np.concatenate([data_np, _garbage(comm.size * local - n, _F)])
+    payload = jax.device_put(physical, NamedSharding(comm.mesh, P(comm.axis_name, None)))
+    got = fused_lloyd_run_sharded(payload, centers, _K, comm, n, 2, -1.0, interpret=True)
+    ref = _lloyd_run(jnp.asarray(data_np), centers, _K, 2, -1.0)
+    assert got[1].shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(got[2]), float(ref[2]), rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "n,n_valid,f,k,itemsize,want",
+    [
+        (1 << 26, 1 << 26, 16, 8, 4, (2140, 1)),  # the kmeans_fit_1c cell: 31 360 columns a block
+        (2 * 31360, 2 * 31360, 16, 8, 4, (2, 0)),  # whole blocks alone: no block is masked
+        (2 * 31360, 2 * 31360 - 1, 16, 8, 4, (2, 1)),
+        (2 * 31360 + 1, 31360, 16, 8, 4, (3, 2)),
+        (1000, 1000, 16, 8, 4, (1, 1)),  # under one block: the one block is the tail block
+        (3 * 31360, 0, 16, 8, 4, (3, 3)),  # a device of padding alone
+    ],
+)
+def test_pass_blocks_counts_the_grid_and_its_masked_steps(n, n_valid, f, k, itemsize, want):
+    from heat_tpu.ops.lloyd import pass_blocks
+
+    assert pass_blocks(n, n_valid, f, k, itemsize) == want
+
+
 @pytest.mark.parametrize("offset", [0.0, 10.0, 100.0])
 def test_inertia_from_the_accumulators_is_the_per_sample_sum_on_uncentred_rows(offset):
     """``_inertia`` takes Σ d² from the last pass's Σ|x|², sums and counts, and

@@ -541,20 +541,25 @@ _STATS.update(
 # core/linalg/qr.py (heat.qr): calls, their blocking host reads (the
 # CholeskyQR2 probe's one), the CholeskyQR2 attempts whose probe failed and
 # fell to Householder, and the calls whose CholeskyQR2 program took its tall
-# products by column blocks (a multiple of 128 columns, at least 256)
+# products by column blocks (a multiple of 128 columns, at least 256).
+# regression/lasso.py (heat.lasso.fit): fits, the coordinate-descent sweeps
+# they ran, and their blocking host reads (one a sweep: the iterates' change)
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "copy", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
 _QR_PHASES = ("prepare", "dispatch", "sync", "copy", "wrap")
+_LASSO_PHASES = ("prepare", "gram", "dispatch", "sync", "copy", "wrap")
 _STATS.update({f"phase_kmeans_{name}_ns": 0 for name in _KMEANS_PHASES})
 _STATS.update({f"phase_cdist_{name}_ns": 0 for name in _CDIST_PHASES})
 _STATS.update({f"phase_qr_{name}_ns": 0 for name in _QR_PHASES})
+_STATS.update({f"phase_lasso_{name}_ns": 0 for name in _LASSO_PHASES})
 _STATS.update(
     phase_kmeans_fits=0, phase_kmeans_dispatches=0, phase_kmeans_syncs=0,
     phase_kmeans_label_epilogues=0, phase_kmeans_blocks=0, phase_kmeans_tail_blocks=0,
     phase_cdist_calls=0, phase_cdist_rotations=0,
     phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0, phase_qr_blocked=0,
+    phase_lasso_fits=0, phase_lasso_sweeps=0, phase_lasso_syncs=0,
 )
-# place, read, a fit, a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:
+# place, read, a fit (k-means, lasso), a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches
 _PHASE_LOCK = threading.Lock()
 
@@ -574,7 +579,8 @@ def note_phase(name: str, ns: int, parts: Optional[dict] = None) -> None:
 
 def note_phases(prefix: str, ns: dict, **counts: int) -> None:
     """Count one traced region of the library above the engine (a
-    ``heat.kmeans.fit``, a ``heat.cdist``, a ``heat.qr``; while ``telemetry.tracing()``): the
+    ``heat.kmeans.fit``, a ``heat.cdist``, a ``heat.qr``, a ``heat.lasso.fit``; while
+    ``telemetry.tracing()``): the
     nanoseconds of each phase it went through (``telemetry.Phases.ns``) onto
     ``phase_<prefix>_<phase>_ns`` and each of ``counts`` onto
     ``phase_<prefix>_<name>``."""

@@ -569,6 +569,53 @@ def _gram_entries(n: int) -> int:
     return sum((hi - lo) * (n - lo) for lo, hi in _col_blocks(n))
 
 
+def _streams_half(dtype) -> bool:
+    """Half-precision rows stream at their own width: one pass on the MXU,
+    accumulated in float32. Everything else contracts at ``HIGHEST`` in its
+    own dtype (``ops/mxu.py``'s rule)."""
+    return dtype in (jnp.bfloat16, jnp.float16)
+
+
+def _tall_product(a, b, contract):
+    """One ``dot_general`` of tall rows, by ``a``'s dtype (:func:`_streams_half`)."""
+    half = _streams_half(a.dtype)
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=None if half else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32 if half else a.dtype,
+    )
+
+
+def tall_gram(x):
+    """``xᴴx`` of tall rows ``x`` (m, n), contracting the (sharded) row axis:
+    the ONE tall Gram of the library, CholeskyQR2's two (:func:`_cholqr2_body`)
+    and ``regression/lasso.py``'s precompute, so that what a later PR does to
+    it moves both. Traced inside the caller's program; under GSPMD the
+    contraction ends in a psum over the split axis.
+
+    Where :func:`_col_blocks` cuts the columns, the Gram is taken at its upper
+    block triangle: block row ``i`` is ONE product ``x[:, block i]ᴴ @
+    x[:, block i:]``, the diagonal block and what lies right of it, and the
+    strictly lower block triangle is the conjugate mirror, so the result is
+    Hermitian to the bit (its diagonal real) and every entry still contracts
+    all rows in one product. Half-precision rows stream at their own width
+    and accumulate in float32; float32 rows and wider multiply at
+    ``HIGHEST`` (:func:`_tall_product`)."""
+    n = x.shape[1]
+    blocks = _col_blocks(n)
+    if len(blocks) == 1:
+        return _tall_product(jnp.conjugate(x), x, ((0,), (0,)))
+    # block row i: the diagonal block and what lies right of it, one
+    # product; what lies left of it is the mirror of a block above
+    acc_t = jnp.float32 if _streams_half(x.dtype) else x.dtype
+    u = jnp.zeros((n, n), acc_t)
+    for lo, hi in blocks:
+        row = _tall_product(jnp.conjugate(x[:, lo:hi]), x[:, lo:], ((0,), (0,)))
+        u = jax.lax.dynamic_update_slice(u, row, (lo, lo))
+    above = jnp.triu(u, 1)
+    return above + jnp.conjugate(above).mT + jnp.diag(jnp.diagonal(u).real.astype(acc_t))
+
+
 def _cholqr2_body(x, calc_q: bool = True):
     """Two CholeskyQR passes returning ``(q, r, ok)`` — the UNJITTED body
     shared by the eager jitted wrapper (:func:`_cholqr2_kernel`) and the
@@ -641,31 +688,16 @@ def _cholqr2_body(x, calc_q: bool = True):
     1 250 000 x 512 a Gram takes 15.2 ms and Q1 or Q 15.0 instead of 21 and
     a call 62 ms instead of 87; two blocks of 256 (75 %) take 16.3 and
     17.0 (PERF.md, PR 36)."""
-    half = x.dtype in (jnp.bfloat16, jnp.float16)
+    half = _streams_half(x.dtype)
     acc_t = jnp.float32 if half else x.dtype
     prec = None if half else jax.lax.Precision.HIGHEST
     n = x.shape[1]
     eye = jnp.eye(n, dtype=acc_t)
     blocks = _col_blocks(n)
 
-    def product(a, b, contract):
-        return jax.lax.dot_general(
-            a, b, (contract, ((), ())), precision=prec, preferred_element_type=acc_t
-        )
-
     def gram_chol(x):
         # (n, n) — contracts the (sharded) row axis; psum under GSPMD
-        if len(blocks) == 1:
-            g = product(jnp.conjugate(x), x, ((0,), (0,)))
-        else:
-            # block row i: the diagonal block and what lies right of it, one
-            # product; what lies left of it is the mirror of a block above
-            u = jnp.zeros((n, n), acc_t)
-            for lo, hi in blocks:
-                row = product(jnp.conjugate(x[:, lo:hi]), x[:, lo:], ((0,), (0,)))
-                u = jax.lax.dynamic_update_slice(u, row, (lo, lo))
-            above = jnp.triu(u, 1)
-            g = above + jnp.conjugate(above).mT + jnp.diag(jnp.diagonal(u).real.astype(acc_t))
+        g = tall_gram(x)
         return jnp.conjugate(jnp.linalg.cholesky(g)).mT, g  # upper factor
 
     def inv_upper(r):  # (n, n) solve against I: small, exact, off the hot path
@@ -678,7 +710,7 @@ def _cholqr2_body(x, calc_q: bool = True):
         # 128 XLA:TPU writes it in place (spatial/distance.py::_write_tile)
         q = jax.lax.empty(x.shape, x.dtype)
         for lo, hi in blocks:
-            cols = product(x[:, :hi], r_inv[:hi, lo:hi], ((1,), (0,))).astype(x.dtype)
+            cols = _tall_product(x[:, :hi], r_inv[:hi, lo:hi], ((1,), (0,))).astype(x.dtype)
             q = jax.lax.dynamic_update_slice(q, cols, (0, lo))
         return q
 

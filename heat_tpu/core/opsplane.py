@@ -182,6 +182,10 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_qr_fallbacks_total", (_C, "CholeskyQR2 attempts of timed QR factorisations whose probe failed and fell to Householder.", [])),
         ("heat_tpu_qr_blocked_total", (_C, "Timed QR factorisations whose CholeskyQR2 program took its tall products by column blocks (only the blocks a triangle holds).", [])),
         ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/copy/wrap).", ["phase"])),
+        ("heat_tpu_lasso_fits_total", (_C, "Lasso fits whose phases were timed (telemetry on or a profiler session recording).", [])),
+        ("heat_tpu_lasso_sweeps_total", (_C, "Coordinate-descent sweeps run by timed Lasso fits.", [])),
+        ("heat_tpu_lasso_syncs_total", (_C, "Blocking host reads (one a sweep: the iterates' change) made by timed Lasso fits.", [])),
+        ("heat_tpu_lasso_phase_seconds_total", (_C, "Host time of timed Lasso fits, by phase (prepare/gram/dispatch/sync/copy/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
         ("heat_tpu_latency_seconds", (_H, "Operation latency, by metric (sync/dispatch/compile).", ["metric"])),
@@ -311,6 +315,7 @@ def _collect_fusion(out: List[Sample]) -> None:
         ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues", "blocks", "tail_blocks"), fusion._KMEANS_PHASES),
         ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
         ("qr", ("calls", "syncs", "fallbacks", "blocked"), fusion._QR_PHASES),
+        ("lasso", ("fits", "sweeps", "syncs"), fusion._LASSO_PHASES),
     ):
         for count in counts:
             out.append((f"heat_tpu_{prefix}_{count}_total", {}, float(stats[f"phase_{prefix}_{count}"])))

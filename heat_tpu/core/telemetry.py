@@ -428,8 +428,8 @@ def ready_then(mark, value, fetch, ready: str = "ready", copy: str = "copy"):
     starts it behind the program, not behind the host's wake-up, so the two
     phases add up to the read as it is when nobody looks (asked for after the
     wait, a scalar's copy is 90 us longer on a v5e: PERF.md, PR 37). For the
-    three places where the program itself blocks on the device
-    (``heat.read``, ``heat.kmeans.fit``, ``heat.qr``). A region that is not
+    four places where the program itself blocks on the device (``heat.read``,
+    ``heat.kmeans.fit``, ``heat.qr``, ``heat.lasso.fit``). A region that is not
     traced (:func:`no_phase`) makes the fetch alone."""
     if mark is no_phase:
         return fetch(value)

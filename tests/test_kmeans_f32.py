@@ -659,3 +659,77 @@ def test_qr_program_on_sharded_rows_reduces_block_rows_once_a_pass(v5e_2x2):
         entries = sum(int(np.prod([int(d) for d in dims.split(",")])) for dims in re.findall(r"f32\[([\d,]+)\]", shapes))
         assert entries == qr_mod._gram_entries(n) == 128 * 1280, shapes
 
+
+
+# -- ISSUE 40: the lasso's precompute at the benchmark's size (3 145 728 x 512), the Gram
+# it shares with the QR program above (``core/linalg/qr.py::tall_gram``; here for the fixture)
+@pytest.fixture(scope="module")
+def lasso_rows():
+    cfg = spec.Cell("lasso_1c").config
+    return cfg["rows"]["1"], cfg["features"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lasso_precompute_holds_the_rows_and_nothing_of_their_size(one_v5e, lasso_rows, dtype):
+    """G and cy from the rows as they lie, read in place chunk by chunk: no
+    transposed copy, no float32 copy of bfloat16 rows, no temporary to speak
+    of. 6.455 GB of arguments (the rows and the labels) is what the chip holds."""
+    from heat_tpu.regression import lasso
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    n, m = lasso_rows
+    mesh = Mesh(np.array([one_v5e._device]), ("x",))  # the one program, on a mesh of one
+    rows = NamedSharding(mesh, P("x", None))
+    x = jax.ShapeDtypeStruct((n, m), jnp.dtype(dtype), sharding=rows)
+    y = jax.ShapeDtypeStruct((n, 1), jnp.float32, sharding=rows)
+    compiled = _compiled(lasso._gram_precompute(mesh, "x"), x, y)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == n * m * jnp.dtype(dtype).itemsize + n * 4
+    assert memory.temp_size_in_bytes < 1 << 22 and memory.output_size_in_bytes < (m * m + m) * 4 + (1 << 12)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(rf"= \w+\[(?:{n},{m}|{m},{n})\][^\n]*? (?:copy|transpose|convert|concatenate|pad)\([^\n]*", entry)
+    products = re.findall(r" convolution\([^\n]*", text)
+    assert len(products) == 4 and not re.search(r" dot\(", text)  # a chunk's four block rows; cy is a multiply-reduce on the VPU
+    assert len(re.findall(r" while\(", entry)) == 1  # the chunks of _SUM_ROWS rows
+    if dtype == "float32":
+        assert all("operand_precision={highest,highest}" in line for line in products)
+    else:
+        assert not any("highest" in line for line in products)  # one bfloat16 pass
+
+
+def test_lasso_precompute_on_sharded_rows_is_one_all_reduce(v5e_2x2, lasso_rows):
+    """Rows sharded over the four described chips: each sums its own rows, then
+    G and cy cross the chips together (at most two all-reduces, the budget
+    ``test_mesh64_compile.py`` pins at 64 devices), nothing of the operand's
+    size moves, and no all-gather."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from heat_tpu.regression import lasso
+
+    n, m = lasso_rows
+    mesh = Mesh(np.array(v5e_2x2), ("x",))
+    rows = NamedSharding(mesh, P("x", None))
+    x = jax.ShapeDtypeStruct((4 * n, m), jnp.float32, sharding=rows)
+    y = jax.ShapeDtypeStruct((4 * n, 1), jnp.float32, sharding=rows)
+    text = _compiled_text(lasso._gram_precompute(mesh, "x"), x, y)
+    assert not re.search(r" all-gather(-start)?\(| all-to-all\(| collective-permute(-start)?\(", text)
+    reduces = re.findall(r"= (\([^=]*\)|\S+) all-reduce(?:-start)?\(", text)
+    assert 1 <= len(reduces) <= 2, reduces
+    entries = sum(int(np.prod([int(d) for d in dims.split(",")])) for shapes in reduces for dims in re.findall(r"f32\[([\d,]+)\]", shapes))
+    assert entries == m * m + m  # G, summed and mirrored on each chip, and cy
+
+
+def test_lasso_sweep_is_one_loop_without_collectives(one_v5e, lasso_rows):
+    """The sweep program is ONE ``while`` (the coordinate steps: what
+    ``cd_step_us`` reads in the device's trace) over G, 1 MB."""
+    from heat_tpu.regression import lasso
+
+    _, m = lasso_rows
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_v5e) for s, d in
+              (((m, m), jnp.float32), ((m,), jnp.float32), ((m, 1), jnp.float32), ((), jnp.float32), ((), jnp.int32))]
+    text = _compiled_text(lasso._cd_sweep_gram, *shapes)
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r" while\(", entry)) == 1 and f"f32[{m},{m}]" in entry
+    assert not re.search(r" all-reduce(-start)?\(| all-gather(-start)?\(", text)

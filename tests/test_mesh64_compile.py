@@ -141,9 +141,10 @@ timed("lloyd10", build_lloyd)
 from heat_tpu.regression.lasso import _cd_sweep_gram, _gram_precompute
 
 def build_lasso_gram_precompute():
-    xt = jax.device_put(jnp.zeros((6, 4 * p), jnp.float32), comm.sharding(2, 1))
+    # the rows as they lie, (n, m) split=0: each device contracts axis 0 of its own rows (ISSUE 40)
+    x = jax.device_put(jnp.zeros((4 * p, 6), jnp.float32), comm.sharding(2, 0))
     y = jax.device_put(jnp.zeros((4 * p, 1), jnp.float32), comm.sharding(2, 0))
-    return _gram_precompute.lower(xt, y).compile().as_text()
+    return _gram_precompute(comm.mesh, comm.axis_name).lower(x, y).compile().as_text()
 
 timed("lasso_gram_pre", build_lasso_gram_precompute)
 

@@ -543,7 +543,8 @@ _STATS.update(
 # fell to Householder, and the calls whose CholeskyQR2 program took its tall
 # products by column blocks (a multiple of 128 columns, at least 256).
 # regression/lasso.py (heat.lasso.fit): fits, the coordinate-descent sweeps
-# they ran, and their blocking host reads (one a sweep: the iterates' change)
+# the device ran for them, and their blocking host reads (one a fit: the sweeps
+# run and the last change; the loop over sweeps is the descent program's)
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "copy", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
 _QR_PHASES = ("prepare", "dispatch", "sync", "copy", "wrap")

@@ -184,7 +184,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_qr_phase_seconds_total", (_C, "Host time of timed QR factorisations, by phase (prepare/dispatch/sync/copy/wrap).", ["phase"])),
         ("heat_tpu_lasso_fits_total", (_C, "Lasso fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_lasso_sweeps_total", (_C, "Coordinate-descent sweeps run by timed Lasso fits.", [])),
-        ("heat_tpu_lasso_syncs_total", (_C, "Blocking host reads (one a sweep: the iterates' change) made by timed Lasso fits.", [])),
+        ("heat_tpu_lasso_syncs_total", (_C, "Blocking host reads (one a fit: the sweeps run and the last change) made by timed Lasso fits.", [])),
         ("heat_tpu_lasso_phase_seconds_total", (_C, "Host time of timed Lasso fits, by phase (prepare/gram/dispatch/sync/copy/wrap).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------

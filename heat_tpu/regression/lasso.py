@@ -17,11 +17,13 @@ forms, chosen from the operand's shape alone:
   either. It is one ``shard_map`` program on every mesh, of one chip or of
   p (``_gram_precompute``): rows are summed where they lie and cross the
   chips once; an operand that is not split=0 is resplit first, and the
-  padding of a ragged split is read as zeros. Then every sweep is
-  one program (``lasso_cd_sweep``), a ``lax.fori_loop`` of ``m`` coordinate
-  steps on the replicated m-vector ``c = cy - Gθ``, with no collective.
+  padding of a ragged split is read as zeros. Then ONE program
+  (``lasso_descent``) runs every sweep: a sweep (``lasso_cd_sweep``) is a
+  ``lax.fori_loop`` of ``m`` coordinate steps on the replicated m-vector
+  ``c = cy - Gθ``, with no collective.
 * **Residual mode** (wider operands): the incremental-residual sweep
-  :func:`_cd_sweep` on a transposed float32 copy of the rows.
+  :func:`_cd_sweep` on a transposed float32 copy of the rows, all sweeps in
+  one program too.
 
 Both multiply by ``ops/mxu.py``'s rule, read off the dtype of the rows and
 nothing else: float32 rows (and wider, carried as float32) multiply in
@@ -29,8 +31,14 @@ float32 (``Precision.HIGHEST``; left at XLA's default the MXU rounds both
 operands to bfloat16), bfloat16 rows keep their one bfloat16 pass with
 float32 accumulation (Gram mode streams them at their own width).
 
-The loop over sweeps is the host's: after each sweep one blocking read of
-the iterates' root-mean-square change (reference lasso.py:166-171).
+The loop over sweeps is the device's (:func:`_descend`, the one loop of both
+modes): after each sweep it takes the iterates' root-mean-square change and
+stops once that is under ``tol`` or ``max_iter`` sweeps have run (reference
+lasso.py:166-171, whose loop and test are the host's). ``max_iter`` and
+``tol`` are traced scalars, so one compiled program per shape serves every
+value of them and of ``lam``. A fit is two dispatches (the Gram, the descent;
+one in residual mode) and ONE blocking host read, of the sweeps run and the
+last change, whatever ``max_iter``.
 
 **The step's contract is upstream's: columns of unit mean square.** A
 coordinate step sets ``θ_j = soft(rho_j, λ)`` with ``rho_j = mean(x_j · (y -
@@ -52,6 +60,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..core import factories, fusion, manipulations, telemetry, types
@@ -69,7 +78,6 @@ def _soft_step(j, rho, lam):
     return jnp.where(j == 0, rho, jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam, 0.0))
 
 
-@partial(jax.jit, static_argnames=("precision",))
 def _cd_sweep(XT: jax.Array, y: jax.Array, theta: jax.Array, lam: jnp.float32, precision=None):
     """One full coordinate-descent sweep over all features in the residual
     form (feature 0 is the unpenalized intercept, reference lasso.py:120-141).
@@ -223,7 +231,58 @@ def lasso_cd_sweep(G: jax.Array, cy: jax.Array, theta: jax.Array, lam: jnp.float
     return theta
 
 
-_cd_sweep_gram = jax.jit(lasso_cd_sweep)
+def _descend(sweep, theta: jax.Array, max_iter, tol):
+    """One fit's sweeps, the one loop of both modes. ``sweep(theta)`` is one
+    coordinate-descent sweep. Sweeps run under a ``while_loop`` until the
+    root-mean-square change of one is under ``tol`` or ``max_iter`` have run
+    (reference lasso.py:166-171); the carry holds the outcome of ``diff <
+    tol``, so a NaN change keeps sweeping as a host's ``float(diff) < tol``
+    would. ``max_iter`` (int32) and ``tol`` (float32) are traced scalars.
+    Returns ``(theta, n_iter, diff)``, the change the last sweep's (infinite
+    when none ran)."""
+
+    def body(carry):
+        done, old, _, _ = carry
+        new = sweep(old)
+        diff = jnp.sqrt(jnp.mean((new - old) ** 2))
+        return done + 1, new, diff < tol, diff
+
+    done, theta, _, diff = jax.lax.while_loop(
+        lambda carry: jnp.logical_and(carry[0] < max_iter, jnp.logical_not(carry[2])),
+        body,
+        (jnp.zeros((), jnp.int32), theta, jnp.zeros((), bool), jnp.full((), jnp.inf, jnp.float32)),
+    )
+    return theta, done, diff
+
+
+@jax.jit
+def lasso_descent(G: jax.Array, cy: jax.Array, lam: jnp.float32, n: int, max_iter, tol):
+    """Gram mode's sweeps from θ = 0 in ONE program (:func:`_descend` over
+    :func:`lasso_cd_sweep`), with no collective: each chip of a mesh runs it
+    whole on its replicated ``G`` and ``cy``."""
+    theta = jnp.zeros((G.shape[0], 1), jnp.float32)
+    return _descend(lambda th: lasso_cd_sweep(G, cy, th, lam, n), theta, max_iter, tol)
+
+
+@partial(jax.jit, static_argnames=("precision",))
+def _descent_residual(XT: jax.Array, y: jax.Array, lam: jnp.float32, max_iter, tol, precision=None):
+    """Residual mode's sweeps from θ = 0 in ONE program (:func:`_descend`
+    over :func:`_cd_sweep`); each step's all-reduce sits inside the loops."""
+    theta = jnp.zeros((XT.shape[0], 1), jnp.float32)
+    return _descend(lambda th: _cd_sweep(XT, y, th, lam, precision), theta, max_iter, tol)
+
+
+def _tol_operand(tol: Optional[float]):
+    """``tol`` as the descent program's float32 operand: ``None`` never stops
+    a fit (``-inf``), and a float64 is rounded towards +inf, so that ``diff <
+    tol`` on the device decides as the host's comparison of the float32
+    ``diff`` with the Python float did."""
+    if tol is None:
+        return np.float32(-np.inf)
+    with np.errstate(over="ignore"):
+        rounded = np.float32(tol)
+    # float(): numpy would weigh a float32 against a Python float in float32
+    return np.nextafter(rounded, np.float32(np.inf)) if float(rounded) < tol else rounded
 
 
 class Lasso(RegressionMixin, BaseEstimator):
@@ -280,12 +339,12 @@ class Lasso(RegressionMixin, BaseEstimator):
         (stats ``mode=gram|residual``, ``n``, ``m``, ``p``, ``sweeps``) whose
         children lie side by side: ``.prepare`` (casts and reshapes; in
         residual mode the transposed copy), ``.gram`` (the dispatch of the
-        precompute, Gram mode only), and for each sweep ``.dispatch`` (the
-        sweep's jitted call and the change's four small eager ones), ``.sync``
-        (the wait until the device has made the change) and ``.copy`` (its
-        read, ``telemetry.ready_then``), then ``.wrap`` (θ into a
-        ``DNDarray``); the same intervals add to ``fusion.cache_stats()``'s
-        ``phase_lasso_*`` keys."""
+        precompute, Gram mode only), ``.dispatch`` (the descent program's one
+        call: every sweep and its convergence check), ``.sync`` (the wait
+        until the device has run them all) and ``.copy`` (the fit's one read:
+        the sweeps run and the last change, ``telemetry.ready_then``), then
+        ``.wrap`` (θ into a ``DNDarray``), each once a fit; the same
+        intervals add to ``fusion.cache_stats()``'s ``phase_lasso_*`` keys."""
         if not isinstance(x, DNDarray) or not isinstance(y, DNDarray):
             raise TypeError("x and y must be DNDarrays")
         if x.ndim != 2:
@@ -311,14 +370,14 @@ class Lasso(RegressionMixin, BaseEstimator):
             ph.note(sweeps=sweeps)
         finally:
             ph.close()
-        fusion.note_phases("lasso", ph.ns, fits=1, sweeps=sweeps, syncs=sweeps)
+        fusion.note_phases("lasso", ph.ns, fits=1, sweeps=sweeps, syncs=1)
         return self
 
     def _fit(self, x: DNDarray, y: DNDarray, gram_mode: bool, mark) -> int:
         """:meth:`fit` past its checks. ``mark(name)`` opens the fit's next
         phase (``telemetry.Phases.phase``; nothing when the fit is not
-        traced). Returns the sweeps run, each of which ended in one blocking
-        host read."""
+        traced). Returns the sweeps the device ran, which the fit's one
+        blocking host read brought back."""
         mark("prepare")
         # as in the reference, the first column of x is treated as the
         # (unregularized) intercept feature — no ones column is prepended
@@ -330,32 +389,25 @@ class Lasso(RegressionMixin, BaseEstimator):
         if not (gram_mode and rows.dtype == jnp.bfloat16):
             rows = rows.astype(jnp.float32)  # float32 rows come back as they are
         yl = y.larray.astype(jnp.float32).reshape(-1, 1)
-        n, m = (int(s) for s in x.shape)
-        theta = jnp.zeros((m, 1), jnp.float32)
-        lam = jnp.float32(self.__lam)
+        n = int(x.shape[0])
+        # operands of the descent program, which holds the loop over sweeps and the
+        # rmse convergence criterion (reference lasso.py:166-171): no value of them compiles anew
+        lam, max_iter, tol = np.float32(self.__lam), np.int32(self.max_iter), _tol_operand(self.tol)
         if gram_mode:
             mark("gram")
             comm, padding = x.comm, rows.shape[0] - n
             if padding:
                 yl = jnp.pad(yl, ((0, padding), (0, 0)))
             G, cy = _gram_precompute(comm.mesh, comm.axis_name, n if padding else None)(rows, yl)
+            mark("dispatch")
+            theta, n_iter, diff = lasso_descent(G, cy, lam, n, max_iter, tol)
         else:
             XT = jnp.transpose(rows)  # one pass; every sweep slice is contiguous
-
-        for it in range(self.max_iter):
             mark("dispatch")
-            theta_old = theta
-            if gram_mode:
-                theta = _cd_sweep_gram(G, cy, theta, lam, n)
-            else:
-                theta = _cd_sweep(XT, yl, theta, lam, precision=precision)
-            # rmse convergence criterion, as in reference lasso.py:166-171
-            diff = telemetry.ready_then(
-                mark, jnp.sqrt(jnp.mean((theta - theta_old) ** 2)), float, "sync"
-            )
-            if self.tol is not None and diff < self.tol:
-                break
-        self.n_iter = it + 1
+            theta, n_iter, diff = _descent_residual(XT, yl, lam, max_iter, tol, precision=precision)
+        # the fit's one read waits for the whole program: theta is final when it returns
+        n_iter, _ = telemetry.ready_then(mark, (n_iter, diff), jax.device_get, "sync")
+        self.n_iter = int(n_iter)
         mark("wrap")
         arr = _ensure_split(theta, None, x.comm)
         self.__theta = DNDarray(

@@ -721,15 +721,24 @@ def test_lasso_precompute_on_sharded_rows_is_one_all_reduce(v5e_2x2, lasso_rows)
     assert entries == m * m + m  # G, summed and mirrored on each chip, and cy
 
 
-def test_lasso_sweep_is_one_loop_without_collectives(one_v5e, lasso_rows):
-    """The sweep program is ONE ``while`` (the coordinate steps: what
-    ``cd_step_us`` reads in the device's trace) over G, 1 MB."""
+def test_lasso_descent_is_a_loop_of_sweeps_without_collectives(one_v5e, lasso_rows):
+    """The descent program (ISSUE 41) is ONE ``while`` over the sweeps, which
+    carries the count, theta and the stop flag, around ONE ``while`` of the
+    coordinate steps over G, 1 MB: the loop ``cd_step_us`` finds in the
+    device's trace by its carry (the step's index, ``c``, theta), and the
+    outer loop is not taken for it. ``max_iter`` and ``tol`` are operands."""
     from heat_tpu.regression import lasso
 
+    is_sweep_loop = spec.load_module("layer_metrics", "cd_step_us.py").is_sweep_loop
     _, m = lasso_rows
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_v5e) for s, d in
-              (((m, m), jnp.float32), ((m,), jnp.float32), ((m, 1), jnp.float32), ((), jnp.float32), ((), jnp.int32))]
-    text = _compiled_text(lasso._cd_sweep_gram, *shapes)
+              (((m, m), jnp.float32), ((m,), jnp.float32), ((), jnp.float32), ((), jnp.int32), ((), jnp.int32), ((), jnp.float32))]
+    text = _compiled_text(lasso.lasso_descent, *shapes)
+    assert "jit_lasso_descent" in text
     entry = text[text.index("ENTRY"):]
-    assert len(re.findall(r" while\(", entry)) == 1 and f"f32[{m},{m}]" in entry
+    loops = [line.strip() for line in text.splitlines() if re.search(r" while\(", line)]
+    outer = [line for line in loops if line in entry]
+    assert len(loops) == 2 and len(outer) == 1 and f"f32[{m},{m}]" in entry
+    assert re.sub(r"\{[^}]*\}", "", outer[0].partition(" = ")[2]).startswith(f"(s32[], f32[{m},1], pred[],")
+    assert [is_sweep_loop(line, m) for line in loops] == [line not in outer for line in loops]
     assert not re.search(r" all-reduce(-start)?\(| all-gather(-start)?\(", text)

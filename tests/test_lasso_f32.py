@@ -6,7 +6,9 @@ mode, on float32 and on bfloat16 rows, replicated and split=0 on the CPU mesh;
 the float32 Gram of the rows as they lie against a float64 one and against the
 Gram of bfloat16-rounded rows; the generator's unit mean square, and what
 upstream's step does on columns of mean square 2 (what refused PR 39); the
-spans and counters of a fit; the ``opsplane`` families.
+device's loop over sweeps against a host's (ISSUE 41: the same sweeps, the
+same stop, one read a fit, one compiled program whatever ``max_iter`` and
+``tol``); the spans and counters of a fit; the ``opsplane`` families.
 
 What only the chip shows (the precompute at 3 145 728 x 512: its memory, its
 products' precision, its all-reduces over four chips) is compiled for a
@@ -160,7 +162,10 @@ def test_columns_of_mean_square_two_diverge(reference, problem):
     sizes = [np.abs(reference.fit_blocks(x, y, LAM, sweeps)).max() for sweeps in (5, 10, 20)]
     assert sizes[0] > 10 and sizes[1] > 100 * sizes[0] and sizes[2] > 1e4 * sizes[1]
     assert gap(fit(x, y, 0, max_iter=10).theta.larray, reference.fit_blocks(x, y, LAM, 10)) < 1e-3  # the same growing iterates
-    assert not np.isfinite(np.asarray(fit(x, y, 0, max_iter=80).theta.larray)).all()
+    # a NaN change is not under any tol: the device's loop sweeps on to max_iter, as the host's did
+    for tol in (-1.0, 1e-3):
+        blown = fit(x, y, 0, max_iter=80, tol=tol)
+        assert blown.n_iter == 80 and not np.isfinite(np.asarray(blown.theta.larray)).all()
     settled = [np.abs(reference.fit_blocks(*problem, LAM, sweeps)).max() for sweeps in (5, 30)]
     assert max(settled) < 2.0
 
@@ -170,6 +175,72 @@ def test_tolerance_stops_the_sweeps_and_counts_them(problem):
     est = fit(x, y, 0, max_iter=200, tol=1e-3)
     assert 1 < est.n_iter < 200
     assert fit(x, y, 0, max_iter=200, tol=None).n_iter == 200
+
+
+def _host_descent(sweep, max_iter, tol):
+    """The loop ``Lasso._fit`` held before ISSUE 41: one jitted sweep at a
+    time, the change taken eagerly and read, ``float(diff) < tol`` on the host."""
+    theta = jnp.zeros((M, 1), jnp.float32)
+    for done in range(1, max_iter + 1):
+        old, theta = theta, sweep(theta)
+        diff = float(jnp.sqrt(jnp.mean((theta - old) ** 2)))
+        if tol is not None and diff < tol:
+            break
+    return theta, done, diff
+
+
+@pytest.mark.parametrize("tol", [None, -1.0, 1e-3, 10.0], ids=["none", "negative", "reached", "first_sweep"])
+@pytest.mark.parametrize("mode", ["gram", "residual"])
+def test_device_loop_is_the_host_loop(problem, monkeypatch, mode, tol):
+    """The descent program stops where a host's loop over the jitted sweep
+    does and gives its theta, to the bit on the CPU backend (the sweep is the
+    same computation inside the ``while`` as alone)."""
+    x, y = problem
+    xs, lam = ht.array(x, split=0), jnp.float32(LAM)
+    if mode == "gram":
+        G, cy = lasso_mod._gram_precompute(xs.comm.mesh, xs.comm.axis_name)(xs.larray, jnp.asarray(y))
+        one = jax.jit(lasso_mod.lasso_cd_sweep)
+        sweep = lambda theta: one(G, cy, theta, lam, N)
+    else:
+        monkeypatch.setattr(lasso_mod, "_GRAM_MAX_ELEMENTS", 0)
+        XT, one = jnp.transpose(xs.larray), jax.jit(lasso_mod._cd_sweep, static_argnames="precision")
+        sweep = lambda theta: one(XT, jnp.asarray(y), theta, lam, precision=lasso_mod.mxu_precision(XT.dtype))
+    theta, n_iter, _ = _host_descent(sweep, SWEEPS, tol)
+    est = fit(x, y, 0, tol=tol)
+    assert est.n_iter == n_iter == {None: SWEEPS, -1.0: SWEEPS, 10.0: 1}.get(tol, n_iter) and 1 <= n_iter <= SWEEPS
+    assert np.array_equal(est.theta.numpy(), np.asarray(theta))
+
+
+def test_descent_returns_the_last_change_and_rounds_tol_up():
+    """``_descend``'s third result is the last sweep's change (what the host
+    read after every sweep), and ``tol`` becomes the float32 at or above it:
+    a float32 ``diff`` is under the one exactly when it is under the other."""
+    halve = lambda theta: theta * jnp.float32(0.5)
+    theta, n_iter, diff = jax.jit(lambda k, t: lasso_mod._descend(halve, jnp.ones((4, 1), jnp.float32), k, t))(np.int32(3), np.float32(-1))
+    assert int(n_iter) == 3 and float(diff) == 0.125 and np.array_equal(theta, np.full((4, 1), 0.125, np.float32))
+    _, none_ran, diff = lasso_mod._descend(halve, jnp.ones((4, 1), jnp.float32), np.int32(0), np.float32(-1))
+    assert int(none_ran) == 0 and float(diff) == np.inf
+    assert lasso_mod._tol_operand(None) == -np.inf and lasso_mod._tol_operand(0.5) == 0.5 and lasso_mod._tol_operand(-1.0) == -1.0
+    for tol in (1e-3, 1e-6, 0.1, -0.1, 1e300, 1e-300):
+        up = lasso_mod._tol_operand(tol)
+        assert up.dtype == np.float32 and float(up) >= tol and float(np.nextafter(up, np.float32(-np.inf))) < tol
+    assert np.isnan(lasso_mod._tol_operand(float("nan")))
+
+
+@pytest.mark.parametrize("mode", ["gram", "residual"])
+def test_max_iter_and_tol_are_operands_of_one_program(problem, monkeypatch, mode):
+    """An analyst who changes ``max_iter`` or ``tol`` compiles nothing new:
+    six fits add ONE entry to the descent program's cache (none where an
+    earlier test fitted this shape), not six."""
+    x, y = problem
+    program = lasso_mod.lasso_descent if mode == "gram" else lasso_mod._descent_residual
+    if mode == "residual":
+        monkeypatch.setattr(lasso_mod, "_GRAM_MAX_ELEMENTS", 0)
+    before = program._cache_size()
+    for max_iter in (5, 6, 7):
+        for tol in (None, 1e-3):
+            assert 1 <= fit(x, y, 0, max_iter=max_iter, tol=tol).n_iter <= max_iter
+    assert program._cache_size() - before <= 1 <= program._cache_size()
 
 
 @pytest.mark.parametrize("m", [24, 256, 512], ids=["whole", "two_blocks", "four_blocks"])
@@ -295,12 +366,12 @@ def test_counters_of_a_fit_with_telemetry_on(problem, monkeypatch, mode):
     with telemetry.enabled(1):
         est = fit(x, y, 0, max_iter=7)
     after = _stats()
-    assert est.n_iter == 7 and [after[k] - before[k] for k in LASSO_KEYS[-3:]] == [1, 7, 7]
+    assert est.n_iter == 7 and [after[k] - before[k] for k in LASSO_KEYS[-3:]] == [1, 7, 1]  # one read a fit
     timed = {k: after[k] - before[k] for k in LASSO_KEYS[:-3]}
     assert (timed.pop("phase_lasso_gram_ns") > 0) == (mode == "gram") and all(v > 0 for v in timed.values())
     with telemetry.enabled(1):
-        est = fit(x, y, 0, max_iter=50, tol=1e-2)  # stopped by its tolerance: the sweeps it ran, a read each
-    assert [_stats()[k] - after[k] for k in LASSO_KEYS[-3:]] == [1, est.n_iter, est.n_iter] and est.n_iter < 50
+        est = fit(x, y, 0, max_iter=50, tol=1e-2)  # stopped by its tolerance: the sweeps the device ran, and still one read
+    assert [_stats()[k] - after[k] for k in LASSO_KEYS[-3:]] == [1, est.n_iter, 1] and 1 < est.n_iter < 50
 
 
 def test_counters_stay_where_they_are_with_telemetry_off(problem, monkeypatch):
@@ -355,7 +426,7 @@ def test_spans_of_a_fit_in_a_profiler_session(problem, monkeypatch, mode):
     }.items()
     children = sorted((s for s in spans if s[0].startswith("heat.lasso.fit.")), key=lambda s: s[1])
     names = [s[0].rsplit(".", 1)[1] for s in children]
-    assert names == ["prepare"] + ["gram"] * (mode == "gram") + ["dispatch", "sync", "copy"] * 3 + ["wrap"]
+    assert names == ["prepare"] + ["gram"] * (mode == "gram") + ["dispatch", "sync", "copy", "wrap"]  # each once, whatever max_iter
     assert all(parent[1] <= s[1] and s[2] <= parent[2] for s in children)
     assert all(a[2] <= b[1] for a, b in zip(children, children[1:])), "children overlap"
 
@@ -374,12 +445,16 @@ def test_opsplane_exports_the_lasso_counters(problem):
 
 def test_the_programs_are_named_for_the_trace():
     """The device's trace names a program by its jitted function: the
-    precompute and the sweep carry names of their own, and stay importable
-    under the names the compile tests use."""
+    precompute and the descent carry names of their own, and stay importable
+    under the names the compile tests use. The descent is a ``while`` (the
+    sweeps) around a ``while`` (a sweep's coordinate steps), with the one
+    product of a sweep at ``HIGHEST``."""
     comm = ht.get_comm()
     assert lasso_mod._gram_precompute(comm.mesh, comm.axis_name).__wrapped__.__name__ == "lasso_gram"
-    assert lasso_mod._cd_sweep_gram.__wrapped__.__name__ == "lasso_cd_sweep"
-    text = lasso_mod._cd_sweep_gram.lower(
-        jnp.zeros((8, 8), jnp.float32), jnp.zeros(8, jnp.float32), jnp.zeros((8, 1), jnp.float32), jnp.float32(0.1), 64
+    assert lasso_mod.lasso_descent.__wrapped__.__name__ == "lasso_descent"
+    text = lasso_mod.lasso_descent.lower(
+        jnp.zeros((8, 8), jnp.float32), jnp.zeros(8, jnp.float32), jnp.float32(0.1), 64, np.int32(30), np.float32(-1)
     ).as_text()
-    assert "jit_lasso_cd_sweep" in text and "stablehlo.while" in text
+    assert "jit_lasso_descent" in text and text.count("stablehlo.while") == 2
+    (product,) = [line for line in text.splitlines() if "stablehlo.dot_general" in line]
+    assert "precision = [HIGHEST, HIGHEST]" in product  # c = cy - G theta at the head of a sweep, in float32

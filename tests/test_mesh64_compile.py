@@ -137,8 +137,8 @@ def build_lloyd():
 
 timed("lloyd10", build_lloyd)
 
-# --- lasso Gram mode: sweeps are collective-FREE, precompute pays 2 -------
-from heat_tpu.regression.lasso import _cd_sweep_gram, _gram_precompute
+# --- lasso Gram mode: the descent is collective-FREE, precompute pays 2 ----
+from heat_tpu.regression.lasso import _gram_precompute, lasso_descent
 
 def build_lasso_gram_precompute():
     # the rows as they lie, (n, m) split=0: each device contracts axis 0 of its own rows (ISSUE 40)
@@ -148,13 +148,16 @@ def build_lasso_gram_precompute():
 
 timed("lasso_gram_pre", build_lasso_gram_precompute)
 
-def build_lasso_gram_sweep():
-    G = jnp.zeros((6, 6), jnp.float32)
-    cy = jnp.zeros((6,), jnp.float32)
-    th = jnp.zeros((6, 1), jnp.float32)
-    return _cd_sweep_gram.lower(G, cy, th, jnp.float32(0.1), 4 * p).compile().as_text()
+def build_lasso_gram_descent():
+    # every sweep and the convergence check in one program (ISSUE 41), on G and cy
+    # replicated over the mesh as the precompute leaves them: each device runs it whole
+    G = jax.device_put(jnp.zeros((6, 6), jnp.float32), comm.sharding(2, None))
+    cy = jax.device_put(jnp.zeros((6,), jnp.float32), comm.sharding(1, None))
+    hlo = lasso_descent.lower(G, cy, jnp.float32(0.1), 4 * p, jnp.int32(30), jnp.float32(-1.0)).compile().as_text()
+    out["lasso_gram_descent_loops"] = len(re.findall(r" while\(", hlo))
+    return hlo
 
-timed("lasso_gram_sweep", build_lasso_gram_sweep)
+timed("lasso_gram_descent", build_lasso_gram_descent)
 
 print(json.dumps(out))
 """
@@ -182,7 +185,7 @@ class TestMesh64Compile(unittest.TestCase):
 
     NAMES = (
         "panel_qr", "sort", "exscan", "ring", "tri_solve", "det", "cholesky",
-        "lloyd10", "lasso_gram_pre", "lasso_gram_sweep",
+        "lloyd10", "lasso_gram_pre", "lasso_gram_descent",
     )
 
     def test_all_programs_compiled(self):
@@ -221,8 +224,10 @@ class TestMesh64Compile(unittest.TestCase):
                 f"{name} collective ops scale with p: {self.out}",
             )
 
-    def test_lasso_gram_sweep_collective_free(self):
-        # the covariance-update sweep runs on replicated (m,)-vectors only:
-        # ZERO collectives — the whole point of Gram mode (the per-feature
-        # all-reduce of the residual form was the lasso weak-scaling cost)
-        self.assertEqual(self.out["lasso_gram_sweep_collective_ops"], 0, self.out)
+    def test_lasso_gram_descent_collective_free(self):
+        # the covariance-update sweeps, all of a fit in one program, run on
+        # replicated (m,)-vectors only: ZERO collectives — the whole point of
+        # Gram mode (the per-feature all-reduce of the residual form was the
+        # lasso weak-scaling cost), in a loop of sweeps around a loop of steps
+        self.assertEqual(self.out["lasso_gram_descent_collective_ops"], 0, self.out)
+        self.assertEqual(self.out["lasso_gram_descent_loops"], 2, self.out)

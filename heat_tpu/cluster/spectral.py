@@ -11,7 +11,9 @@ of the embedding multiplies float32 in float32 (``ops/mxu.py``'s rule, from
 the dtype alone: ``solver._matvec`` and :func:`_embed`).
 
 While ``telemetry.tracing()`` a fit is a ``heat.spectral.fit`` span (stats
-``n``, ``f``, ``k``, ``m``, ``p``) whose children lie side by side:
+``n``, ``f``, ``k``, ``m``, ``p``, and ``product``: ``upper`` where each
+Lanczos product read only L's upper block triangle, ``ops/symv.py``, else
+``full``) whose children lie side by side:
 ``.affinity`` (the similarity's call; ``heat.cdist`` nests in it),
 ``.laplacian`` (the Laplacian program's call), ``.lanczos`` (the start vector
 and the Lanczos program's call), ``.embed`` (the tridiagonal's eigenpairs and
@@ -21,7 +23,8 @@ time of everything above), ``.assign`` (``KMeans.fit``, whose
 ``heat.kmeans.fit`` nests in it); the same intervals add to
 ``fusion.cache_stats()``'s ``phase_spectral_*`` keys, with the fit's
 blocking host reads (``syncs``: the k-means initialisation's and its one read,
-the eigengap's) and its products with the Laplacian (``lanczos_steps``).
+the eigengap's), its products with the Laplacian (``lanczos_steps``) and
+those of them that read only its upper block triangle (``upper_steps``).
 """
 
 from __future__ import annotations
@@ -105,6 +108,7 @@ class Spectral(ClusteringMixin, BaseEstimator):
             n_clusters=n_clusters if n_clusters is not None else 8, **params
         )
         self._labels = None
+        self._upper = False  # whether the last embedding's Lanczos read L's upper block triangle only
         self.eigenvalues_ = None
         self.embedding_ = None
 
@@ -121,6 +125,7 @@ class Spectral(ClusteringMixin, BaseEstimator):
         L = self._laplacian._construct(x, mark)
         mark("lanczos")
         m = min(self.n_lanczos, L.shape[0])
+        self._upper = solver._reads_upper(L, jnp.float32)  # lanczos' start vector is float32
         V, T = solver.lanczos(L, m)
         del L
         mark("embed")
@@ -152,9 +157,10 @@ class Spectral(ClusteringMixin, BaseEstimator):
         )
         try:
             syncs = self._fit(x, ph.phase)
+            ph.note(product="upper" if self._upper else "full")
         finally:
             ph.close()
-        fusion.note_phases("spectral", ph.ns, fits=1, syncs=syncs, lanczos_steps=m)
+        fusion.note_phases("spectral", ph.ns, fits=1, syncs=syncs, lanczos_steps=m, upper_steps=m * self._upper)
         return self
 
     def _fit(self, x: DNDarray, mark) -> int:

@@ -546,8 +546,9 @@ _STATS.update(
 # the device ran for them, and their blocking host reads (one a fit: the sweeps
 # run and the last change; the loop over sweeps is the descent program's).
 # cluster/spectral.py (heat.spectral.fit): fits, their blocking host reads (the
-# k-means initialisation's and its one read, the eigengap's) and their products
-# with the Laplacian (the Lanczos steps)
+# k-means initialisation's and its one read, the eigengap's), their products
+# with the Laplacian (the Lanczos steps) and those of them that read only the
+# Laplacian's upper block triangle (ops/symv.py)
 _KMEANS_PHASES = ("init", "prepare", "dispatch", "sync", "copy", "wrap")
 _CDIST_PHASES = ("prepare", "dispatch", "place")
 _QR_PHASES = ("prepare", "dispatch", "sync", "copy", "wrap")
@@ -564,7 +565,7 @@ _STATS.update(
     phase_cdist_calls=0, phase_cdist_rotations=0,
     phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0, phase_qr_blocked=0,
     phase_lasso_fits=0, phase_lasso_sweeps=0, phase_lasso_syncs=0,
-    phase_spectral_fits=0, phase_spectral_syncs=0, phase_spectral_lanczos_steps=0,
+    phase_spectral_fits=0, phase_spectral_syncs=0, phase_spectral_lanczos_steps=0, phase_spectral_upper_steps=0,
 )
 # place, read, a fit (k-means, lasso, spectral), a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:
 # their adds take this lock, which only the traced path ever touches

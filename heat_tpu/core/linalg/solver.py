@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from .. import factories, fusion, resilience, sanitation, telemetry
 from ..dndarray import DNDarray
 from .basics import dot, matmul, norm, transpose
+from ...ops import symv
 from ...ops.mxu import mxu_precision  # the rule every Lanczos product multiplies by, for whoever asks
 
 _T_COLLECTIVE = telemetry.force_trigger("collective")
@@ -455,19 +456,22 @@ def _matvec(a, v):
     return jnp.matmul(a, v, precision=mxu_precision(jnp.result_type(a, v)))
 
 
-def _lanczos_body(Al, v0l, m: int, key):
+def _lanczos_body(Al, v0l, m: int, key, product=None):
     """Whole Lanczos run as ONE XLA program (reference solver.py:68-184 does
     m Python iterations with a host sync per dot/norm; here the loop, the
     full reorthogonalization, and the breakdown restart all live on device).
     Each of the m steps is one iteration of the loop and takes one product
-    with ``Al``. V is carried row-major (m, n) so each step is one row
-    update; its rows from the step's own on are still zero, so the
-    reorthogonalization against all of V is against the rows made so far.
-    Every product asks for float32 in float32 (:func:`_matvec`). XLA:TPU
-    takes a float32 matrix-VECTOR product as a VPU multiply and row sum at
-    any precision (no ``dot`` in the compiled program, PR 42), so on a v5e
-    the request changes nothing; it keeps the rule where a compiler would
-    take the MXU's default, one pass on bfloat16-rounded operands."""
+    with ``Al``: ``product(Al, v)``, :func:`_matvec` where it is None. V is
+    carried row-major (m, n) so each step is one row update; its rows from
+    the step's own on are still zero, so the reorthogonalization against all
+    of V is against the rows made so far. Every product asks for float32 in
+    float32 (:func:`_matvec`). XLA:TPU takes a float32 matrix-VECTOR product
+    as a VPU multiply and row sum at any precision (no ``dot`` in the
+    compiled program), so on a v5e the request changes nothing; it
+    keeps the rule where a compiler would take the MXU's default, one pass on
+    bfloat16-rounded operands. :data:`_lanczos_upper` runs this body with
+    ``symv.upper_matvec`` as the product with ``Al``: each step reads Al's
+    upper block triangle once, float32 on the VPU, and nothing else of it."""
     n = v0l.shape[0]
     dtype = v0l.dtype
     V = jnp.zeros((m, n), dtype)
@@ -485,7 +489,7 @@ def _lanczos_body(Al, v0l, m: int, key):
         vr = cand - _matvec(V.T, _matvec(V, cand))
         nrm = jnp.linalg.norm(vr)
         vr = jnp.where(nrm > 1e-12, vr / jnp.maximum(nrm, 1e-30), cand)
-        w2 = _matvec(Al, vr)
+        w2 = (product or _matvec)(Al, vr)
         alpha = _matvec(w2, vr)
         last = jnp.maximum(i - 1, 0)
         w_next = w2 - alpha * vr - beta * V[last]
@@ -501,6 +505,22 @@ def _lanczos_body(Al, v0l, m: int, key):
 _lanczos_fused = jax.jit(_lanczos_body, static_argnums=(2,))
 
 
+def _lanczos_upper_body(Al, v0l, m: int, key):
+    """:func:`_lanczos_body` whose every product with ``Al`` reads only its
+    upper block triangle (``symv.upper_matvec``)."""
+    return _lanczos_body(Al, v0l, m, key, product=symv.upper_matvec)
+
+
+_lanczos_upper = jax.jit(_lanczos_upper_body, static_argnums=(2,))
+
+
+def _reads_upper(A: DNDarray, dtype) -> bool:
+    """Whether :func:`lanczos` of ``A``, cast to ``dtype`` (its start
+    vector's; float32 where none is given), takes :data:`_lanczos_upper`:
+    ``symv.takes`` of the operand as the program gets it."""
+    return symv.takes(A.shape, dtype, A.larray.sharding)
+
+
 def lanczos(
     A: DNDarray,
     m: int,
@@ -510,7 +530,13 @@ def lanczos(
 ):
     """Lanczos tridiagonalization with full reorthogonalization (reference
     solver.py:68-184), fused into a single XLA program (no per-iteration
-    host round-trips)."""
+    host round-trips). ``A`` is taken to be symmetric, as Lanczos assumes (T
+    is written symmetric). On one TPU chip, for a square float32 operand whose
+    side is a multiple of ``symv.BLOCK``, each product reads only A's upper
+    block triangle, as :func:`eigh` reads its ``UPLO`` triangle: the product
+    is with the matrix whose lower block triangle mirrors the upper one
+    (``ops/symv.py``). Elsewhere (other backends, dtypes, sides, a sharded
+    A) each product reads all of A."""
     if not isinstance(A, DNDarray):
         raise TypeError(f"A needs to be of type DNDarray, but was {type(A)}")
     if not isinstance(m, (int,)):
@@ -530,9 +556,9 @@ def lanczos(
         if v0.split != A.split:
             v0 = factories.array(v0, split=A.split, copy=True)
 
-    v_arr, t_arr = _lanczos_fused(
-        A.larray.astype(v0.dtype.jax_type()), v0.larray, m, jax.random.PRNGKey(0)
-    )
+    dtype = v0.dtype.jax_type()
+    program = _lanczos_upper if _reads_upper(A, dtype) else _lanczos_fused
+    v_arr, t_arr = program(A.larray.astype(dtype), v0.larray, m, jax.random.PRNGKey(0))
     V = factories.array(v_arr, comm=A.comm, device=A.device)
     V.resplit_(A.split)
     T = factories.array(t_arr, comm=A.comm, device=A.device)

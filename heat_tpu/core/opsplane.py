@@ -189,6 +189,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_spectral_fits_total", (_C, "Spectral fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_spectral_syncs_total", (_C, "Blocking host reads (the k-means initialisation's and its one read, the eigengap's) made by timed Spectral fits.", [])),
         ("heat_tpu_spectral_lanczos_steps_total", (_C, "Lanczos steps (products with the Laplacian) run by timed Spectral fits.", [])),
+        ("heat_tpu_spectral_upper_steps_total", (_C, "Lanczos steps of timed Spectral fits whose product read only the Laplacian's upper block triangle.", [])),
         ("heat_tpu_spectral_phase_seconds_total", (_C, "Host time of timed Spectral fits, by phase (affinity/laplacian/lanczos/embed/sync/assign).", ["phase"])),
         # -- latency (health_runtime histograms; key = program key or
         # sync trigger, LRU-capped at health_runtime._PROGRAM_CAP) ------
@@ -320,7 +321,7 @@ def _collect_fusion(out: List[Sample]) -> None:
         ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
         ("qr", ("calls", "syncs", "fallbacks", "blocked"), fusion._QR_PHASES),
         ("lasso", ("fits", "sweeps", "syncs"), fusion._LASSO_PHASES),
-        ("spectral", ("fits", "syncs", "lanczos_steps"), fusion._SPECTRAL_PHASES),
+        ("spectral", ("fits", "syncs", "lanczos_steps", "upper_steps"), fusion._SPECTRAL_PHASES),
     ):
         for count in counts:
             out.append((f"heat_tpu_{prefix}_{count}_total", {}, float(stats[f"phase_{prefix}_{count}"])))

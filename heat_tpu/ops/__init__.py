@@ -15,9 +15,12 @@ pallas kernels where the schedule matters. Current contents:
 - :mod:`~heat_tpu.ops.lloyd` — single-pass fused Lloyd iteration for
   k-means (single-device and shard_map forms; the benchmark cell
   ``kmeans_fit_1c`` measures it through ``KMeans.fit``).
+- :mod:`~heat_tpu.ops.symv` — the product of a symmetric float32 matrix with
+  a vector from one read of its upper block triangle (each Lanczos step's
+  product with the Laplacian on one TPU chip: ``core/linalg/solver.py``).
 """
 
-from . import flash, lloyd, pairwise
+from . import flash, lloyd, pairwise, symv
 from .flash import flash_attention_tpu
 from .lloyd import fused_lloyd_run, fused_lloyd_run_sharded
 from .pairwise import pairwise_distance
@@ -26,6 +29,7 @@ __all__ = [
     "flash",
     "lloyd",
     "pairwise",
+    "symv",
     "pairwise_distance",
     "flash_attention_tpu",
     "fused_lloyd_run",

@@ -797,3 +797,30 @@ def test_lanczos_program_holds_the_laplacian_and_its_basis(one_v5e, spectral_sha
     assert len(loops) == 1 and is_lanczos_loop(loops[0], m, n), loops
     assert not is_lanczos_loop(loops[0], m + 1, n)
     assert "tpu_custom_call" not in text
+
+
+def test_upper_lanczos_program_reads_the_laplacian_in_place(one_v5e, spectral_shape):
+    """The program a v5e runs for the cell: each step's product with L is the
+    one Pallas kernel of ``ops/symv.py`` (its upper block triangle, float32 on
+    the VPU), L read in place: no pad, no transpose, no copy, under 2 MB of
+    temporaries beside V. The loop keeps the carry ``lanczos_step_us`` finds,
+    and stays the only ``while``."""
+    from heat_tpu.core.linalg import solver
+    from heat_tpu.ops import symv
+
+    is_lanczos_loop = spec.load_module("layer_metrics", "lanczos_step_us.py").is_lanczos_loop
+    n, m = spectral_shape
+    assert n % symv.BLOCK == 0
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_v5e)
+    v = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_v5e)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e)
+    lower = lambda a, v, key: solver._lanczos_upper.lower(a, v, m, key)  # noqa: E731
+    compiled = _compiled(types.SimpleNamespace(lower=lower), a, v, key)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < n * n * 4 + (1 << 20)
+    assert memory.output_size_in_bytes < 4 * m * n + (1 << 20) and memory.temp_size_in_bytes < 2 << 20  # V, T
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "bf16" not in text and not re.search(r" dot\(| convolution\(", text)
+    assert not re.search(rf"f32\[{n},{n}\]\S* (copy|transpose|pad)\(", text)
+    loops = [line.strip() for line in text.splitlines() if re.search(r" while\(", line)]
+    assert len(loops) == 1 and is_lanczos_loop(loops[0], m, n), loops

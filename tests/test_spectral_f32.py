@@ -31,11 +31,12 @@ from heat_tpu.cluster import spectral as spectral_mod
 from heat_tpu.core import fusion, telemetry
 from heat_tpu.core.linalg import solver
 from heat_tpu.graph import laplacian as laplacian_mod
+from heat_tpu.ops import symv
 
 CFG = spec.Cell("spectral_1c").config
 K, F, STEPS, N = int(CFG["n_clusters"]), int(CFG["features"]), 100, 8 * 128
 SPECTRAL_KEYS = [f"phase_spectral_{name}_ns" for name in fusion._SPECTRAL_PHASES] + [
-    "phase_spectral_fits", "phase_spectral_syncs", "phase_spectral_lanczos_steps"
+    "phase_spectral_fits", "phase_spectral_syncs", "phase_spectral_lanczos_steps", "phase_spectral_upper_steps"
 ]
 # CPU limits at 1 024 rows. Sound fits read 2e-7 (eigenvalues), 4e-7 (subspace)
 # and 4e-7 (residuals); products rounded to bfloat16, or rows cast to it, read
@@ -170,22 +171,63 @@ def test_bfloat16_falls_outside_the_limits(rows, want, monkeypatch, how):
     assert got["eig"] > LIMITS["eig"] and got["residual"] > LIMITS["residual"], got
 
 
+def _primitives(jaxpr, out):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations carry."""
+    for eqn in jaxpr.eqns:
+        out.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+def _interpreted(block=128):
+    """The upper-triangle product as the CPU runs it: Pallas interpret mode."""
+    return functools.partial(symv.upper_matvec, block=block, interpret=True)
+
+
 def test_no_lanczos_or_embedding_product_is_left_at_the_default():
     """What only the chip shows: XLA's default takes float32 operands on the
     MXU in one bfloat16 pass (a matrix-vector product it lowers to the VPU,
     at any precision, on a v5e: ``test_kmeans_f32.py``). So every contraction
-    of the traced programs asks for ``HIGHEST``."""
+    of the traced programs asks for ``HIGHEST``. On the kernel's path the
+    product with L is the one ``pallas_call`` (``ops/symv.py``: float32 on the
+    VPU) beside the three float32 products of the reorthogonalisation and
+    alpha, and nothing anywhere is bfloat16."""
     from test_kmeans_f32 import _dots, _float32_products_only
 
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     v = jax.ShapeDtypeStruct((256,), jnp.float32)
     dots = _dots(jax.make_jaxpr(lambda a, v: solver._lanczos_body(a, v, 20, jax.random.PRNGKey(0)))(a, v).jaxpr, [])
     assert len(dots) == 4 and all(_float32_products_only(eqn) for eqn in dots), [str(e) for e in dots]
+    upper = jax.make_jaxpr(lambda a, v: solver._lanczos_body(a, v, 20, jax.random.PRNGKey(0), product=_interpreted()))(a, v)
+    eqns = _primitives(upper.jaxpr, [])
+    dots = _dots(upper.jaxpr, [])
+    assert len(dots) == 3 and all(_float32_products_only(eqn) for eqn in dots), [str(e) for e in dots]
+    assert [e.primitive.name for e in eqns].count("pallas_call") == 1
+    assert not any(getattr(var.aval, "dtype", None) == jnp.bfloat16 for e in eqns for var in (*e.invars, *e.outvars))
     embed = jax.make_jaxpr(lambda V, T: spectral_mod._embed(V, T, 3))(
         jax.ShapeDtypeStruct((256, 20), jnp.float32), jax.ShapeDtypeStruct((20, 20), jnp.float32)
     )
     (product,) = _dots(embed.jaxpr, [])
     assert _float32_products_only(product)
+
+
+def test_lanczos_through_the_upper_product_finds_the_same_ritz_values(rows):
+    """A symmetric L (the rows' L_sym, float32, 1 024 rows) through the kernel,
+    interpreted, and through XLA's product over all of it: 100 steps give the
+    same Ritz values to 1e-5."""
+    lap = jnp.asarray(dense_laplacian(rows[0]), jnp.float32)
+    v0 = jax.random.normal(jax.random.PRNGKey(3), (N,), jnp.float32)
+    v0 = v0 / jnp.linalg.norm(v0)
+    key = jax.random.PRNGKey(0)
+    _, full = solver._lanczos_fused(lap, v0, STEPS, key)
+    body = functools.partial(solver._lanczos_body, product=_interpreted(256))
+    _, upper = jax.jit(body, static_argnums=(2,))(lap, v0, STEPS, key)
+    ritz = [np.linalg.eigvalsh(np.asarray(t, np.float64)) for t in (full, upper)]
+    assert np.abs(ritz[0] - ritz[1]).max() < 1e-5 and np.abs(ritz[0][:K]).max() < 0.3
 
 
 # -- the one-buffer Laplacian -----------------------------------------------------------------
@@ -269,8 +311,9 @@ def test_counters_of_a_fit_with_telemetry_on(rows, n_clusters):
     k = est.n_clusters
     # k-means++ reads its first index, then a draw and an index a centre; the Lloyd fit one; the eigengap one
     reads = 2 * k - 1 + 1 + (n_clusters is None)
-    assert [after[key] - before[key] for key in SPECTRAL_KEYS[-3:]] == [1, reads, STEPS]
-    assert all(after[key] - before[key] > 0 for key in SPECTRAL_KEYS[:-3])
+    # on the CPU each product reads all of L: no upper-triangle step
+    assert [after[key] - before[key] for key in SPECTRAL_KEYS[-4:]] == [1, reads, STEPS, 0]
+    assert all(after[key] - before[key] > 0 for key in SPECTRAL_KEYS[:-4])
 
 
 def test_counters_stay_where_they_are_with_telemetry_off(rows):
@@ -310,7 +353,7 @@ def test_spans_of_a_fit_in_a_profiler_session(rows):
             ]
     (parent,) = [s for s in spans if s[0] == "heat.spectral.fit"]
     assert {k: str(v) for k, v in parent[3].items()}.items() >= {
-        "n": str(N), "f": str(F), "k": str(K), "m": str(STEPS), "p": str(ht.get_comm().size)
+        "n": str(N), "f": str(F), "k": str(K), "m": str(STEPS), "p": str(ht.get_comm().size), "product": "full"
     }.items()
     children = sorted((s for s in spans if s[0].startswith("heat.spectral.fit.")), key=lambda s: s[1])
     assert [s[0].rsplit(".", 1)[1] for s in children] == list(fusion._SPECTRAL_PHASES)
@@ -328,8 +371,38 @@ def test_opsplane_exports_the_spectral_counters(rows):
         fit(ht.array(rows[0], split=0))
     text = opsplane.render()
     assert not opsplane.validate_exposition(text)
-    assert all(f"heat_tpu_spectral_{c}_total" in text for c in ("fits", "syncs", "lanczos_steps"))
+    assert all(f"heat_tpu_spectral_{c}_total" in text for c in ("fits", "syncs", "lanczos_steps", "upper_steps"))
     assert all(f'heat_tpu_spectral_phase_seconds_total{{phase="{ph}"}}' in text for ph in fusion._SPECTRAL_PHASES)
+
+
+def _exported(name):
+    from heat_tpu.core import opsplane
+
+    (line,) = [line for line in opsplane.render().splitlines() if line.startswith(name + " ")]
+    return float(line.split()[1])
+
+
+def test_upper_steps_count_a_fit_on_the_kernels_path(rows, monkeypatch):
+    """Where L takes the upper-triangle product (pretended here, the kernel
+    interpreted), a traced fit counts its m steps in
+    ``phase_spectral_upper_steps`` and ``opsplane``'s
+    ``heat_tpu_spectral_upper_steps_total``, and says ``product=upper`` on its
+    span; an untraced fit counts nothing."""
+    monkeypatch.setattr(symv, "takes", lambda shape, dtype, sharding: True)
+    monkeypatch.setattr(solver, "_lanczos_upper", jax.jit(
+        functools.partial(solver._lanczos_body, product=_interpreted(256)), static_argnums=(2,)
+    ))
+    x = ht.array(rows[0], split=None)
+    key, name = "phase_spectral_upper_steps", "heat_tpu_spectral_upper_steps_total"
+    before, exported = fusion.cache_stats()[key], _exported(name)
+    fit(x)
+    assert fusion.cache_stats()[key] == before and _exported(name) == exported
+    stats = []
+    monkeypatch.setattr(telemetry.Phases, "note", lambda self, **kw: stats.append((self.name, kw)))
+    with telemetry.enabled(1):
+        fit(x)
+    assert fusion.cache_stats()[key] - before == STEPS and _exported(name) - exported == STEPS
+    assert [kw for span, kw in stats if span == "heat.spectral.fit"] == [{"product": "upper"}]
 
 
 def test_predict_embeds_new_rows_in_the_same_way(rows):

@@ -543,8 +543,9 @@ _STATS.update(
 # fell to Householder, and the calls whose CholeskyQR2 program took its tall
 # products by column blocks (a multiple of 128 columns, at least 256).
 # regression/lasso.py (heat.lasso.fit): fits, the coordinate-descent sweeps
-# the device ran for them, and their blocking host reads (one a fit: the sweeps
-# run and the last change; the loop over sweeps is the descent program's).
+# the device ran for them, their blocking host reads (one a fit: the sweeps
+# run and the last change; the loop over sweeps is the descent program's), and
+# those of the sweeps that ran as one Pallas kernel (ops/cd.py).
 # cluster/spectral.py (heat.spectral.fit): fits, their blocking host reads (the
 # k-means initialisation's and its one read, the eigengap's), their products
 # with the Laplacian (the Lanczos steps) and those of them that read only the
@@ -564,7 +565,7 @@ _STATS.update(
     phase_kmeans_label_epilogues=0, phase_kmeans_blocks=0, phase_kmeans_tail_blocks=0,
     phase_cdist_calls=0, phase_cdist_rotations=0,
     phase_qr_calls=0, phase_qr_syncs=0, phase_qr_fallbacks=0, phase_qr_blocked=0,
-    phase_lasso_fits=0, phase_lasso_sweeps=0, phase_lasso_syncs=0,
+    phase_lasso_fits=0, phase_lasso_sweeps=0, phase_lasso_syncs=0, phase_lasso_kernel_sweeps=0,
     phase_spectral_fits=0, phase_spectral_syncs=0, phase_spectral_lanczos_steps=0, phase_spectral_upper_steps=0,
 )
 # place, read, a fit (k-means, lasso, spectral), a cdist and a qr are timed outside _FORCE_LOCK, from any serving thread:

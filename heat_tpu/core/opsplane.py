@@ -185,6 +185,7 @@ SCHEMA: "OrderedDict[str, Dict[str, Any]]" = OrderedDict(
         ("heat_tpu_lasso_fits_total", (_C, "Lasso fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_lasso_sweeps_total", (_C, "Coordinate-descent sweeps run by timed Lasso fits.", [])),
         ("heat_tpu_lasso_syncs_total", (_C, "Blocking host reads (one a fit: the sweeps run and the last change) made by timed Lasso fits.", [])),
+        ("heat_tpu_lasso_kernel_sweeps_total", (_C, "Coordinate-descent sweeps of timed Lasso fits that ran as one Pallas kernel with G in VMEM.", [])),
         ("heat_tpu_lasso_phase_seconds_total", (_C, "Host time of timed Lasso fits, by phase (prepare/gram/dispatch/sync/copy/wrap).", ["phase"])),
         ("heat_tpu_spectral_fits_total", (_C, "Spectral fits whose phases were timed (telemetry on or a profiler session recording).", [])),
         ("heat_tpu_spectral_syncs_total", (_C, "Blocking host reads (the k-means initialisation's and its one read, the eigengap's) made by timed Spectral fits.", [])),
@@ -320,7 +321,7 @@ def _collect_fusion(out: List[Sample]) -> None:
         ("kmeans", ("fits", "dispatches", "syncs", "label_epilogues", "blocks", "tail_blocks"), fusion._KMEANS_PHASES),
         ("cdist", ("calls", "rotations"), fusion._CDIST_PHASES),
         ("qr", ("calls", "syncs", "fallbacks", "blocked"), fusion._QR_PHASES),
-        ("lasso", ("fits", "sweeps", "syncs"), fusion._LASSO_PHASES),
+        ("lasso", ("fits", "sweeps", "syncs", "kernel_sweeps"), fusion._LASSO_PHASES),
         ("spectral", ("fits", "syncs", "lanczos_steps", "upper_steps"), fusion._SPECTRAL_PHASES),
     ):
         for count in counts:

@@ -18,9 +18,12 @@ pallas kernels where the schedule matters. Current contents:
 - :mod:`~heat_tpu.ops.symv` — the product of a symmetric float32 matrix with
   a vector from one read of its upper block triangle (each Lanczos step's
   product with the Laplacian on one TPU chip: ``core/linalg/solver.py``).
+- :mod:`~heat_tpu.ops.cd` — one coordinate-descent sweep of the lasso as one
+  kernel, the Gram matrix held in VMEM (each sweep of ``Lasso.fit``'s Gram
+  mode on TPUs: ``regression/lasso.py``).
 """
 
-from . import flash, lloyd, pairwise, symv
+from . import cd, flash, lloyd, pairwise, symv
 from .flash import flash_attention_tpu
 from .lloyd import fused_lloyd_run, fused_lloyd_run_sharded
 from .pairwise import pairwise_distance
@@ -30,6 +33,7 @@ __all__ = [
     "lloyd",
     "pairwise",
     "symv",
+    "cd",
     "pairwise_distance",
     "flash_attention_tpu",
     "fused_lloyd_run",

@@ -18,9 +18,12 @@ forms, chosen from the operand's shape alone:
   p (``_gram_precompute``): rows are summed where they lie and cross the
   chips once; an operand that is not split=0 is resplit first, and the
   padding of a ragged split is read as zeros. Then ONE program
-  (``lasso_descent``) runs every sweep: a sweep (``lasso_cd_sweep``) is a
-  ``lax.fori_loop`` of ``m`` coordinate steps on the replicated m-vector
-  ``c = cy - Gθ``, with no collective.
+  (``lasso_descent``) runs every sweep on the replicated m-vector
+  ``c = cy - Gθ``, with no collective. On TPUs, for a float32 G that fits the
+  kernel's VMEM (``ops/cd.py::takes``: every m up to 1 024), a sweep is ONE
+  Pallas kernel that holds G in VMEM and runs the m coordinate steps inside
+  it (``ops/cd.py``); elsewhere it is ``lasso_cd_sweep``, a ``lax.fori_loop``
+  of the m steps. Both take the same float32 operations in the same order.
 * **Residual mode** (wider operands): the incremental-residual sweep
   :func:`_cd_sweep` on a transposed float32 copy of the rows, all sweeps in
   one program too.
@@ -67,6 +70,7 @@ from ..core import factories, fusion, manipulations, telemetry, types
 from ..core.base import BaseEstimator, RegressionMixin
 from ..core.dndarray import DNDarray, _ensure_split
 from ..core.linalg.qr import tall_gram
+from ..ops import cd
 from ..ops.mxu import mxu_precision
 
 __all__ = ["Lasso"]
@@ -202,6 +206,12 @@ def _gram_precompute(mesh, axis: str, n: Optional[int] = None):
     return jax.jit(lasso_gram_rows)
 
 
+def _head(G: jax.Array, cy: jax.Array, theta: jax.Array) -> jax.Array:
+    """``c = cy - G θ`` at the head of a sweep, the one product of a sweep,
+    taken in float32 (``G`` and ``θ`` are float32 whatever the rows were)."""
+    return cy - jnp.matmul(G, theta.reshape(-1), precision=jax.lax.Precision.HIGHEST)
+
+
 def lasso_cd_sweep(G: jax.Array, cy: jax.Array, theta: jax.Array, lam: jnp.float32, n: int):
     """One coordinate-descent sweep in the covariance-update form (sklearn's
     ``precompute=True``): with ``G = X'X`` and ``cy = X'y`` replicated, the
@@ -216,7 +226,7 @@ def lasso_cd_sweep(G: jax.Array, cy: jax.Array, theta: jax.Array, lam: jnp.float
     column's mean square ``G_jj / n``, so it minimises over coordinate ``j``
     only where that is 1 (the module docstring: the contract, and what
     happens on columns of mean square 2)."""
-    c = cy - jnp.matmul(G, theta.reshape(-1), precision=jax.lax.Precision.HIGHEST)
+    c = _head(G, cy, theta)
 
     def body(j, carry):
         c, th = carry
@@ -255,13 +265,31 @@ def _descend(sweep, theta: jax.Array, max_iter, tol):
     return theta, done, diff
 
 
-@jax.jit
-def lasso_descent(G: jax.Array, cy: jax.Array, lam: jnp.float32, n: int, max_iter, tol):
-    """Gram mode's sweeps from θ = 0 in ONE program (:func:`_descend` over
-    :func:`lasso_cd_sweep`), with no collective: each chip of a mesh runs it
-    whole on its replicated ``G`` and ``cy``."""
+@partial(jax.jit, static_argnames=("mesh",))
+def lasso_descent(G: jax.Array, cy: jax.Array, lam: jnp.float32, n: int, max_iter, tol, mesh=None):
+    """Gram mode's sweeps from θ = 0 in ONE program (:func:`_descend`), with
+    no collective: each chip of a mesh runs it whole on its replicated ``G``
+    and ``cy``. With ``mesh`` None a sweep is :func:`lasso_cd_sweep`'s loop;
+    given the mesh ``G`` lies on, where ``ops/cd.py::takes`` it, a sweep is
+    :func:`_head` and then ONE kernel (``ops/cd.py::sweep``, G held in VMEM),
+    on each device of the mesh its own copy (``shard_map``, replicated in and
+    out). On a mesh of other devices than TPUs the kernel runs in Pallas'
+    interpreter."""
     theta = jnp.zeros((G.shape[0], 1), jnp.float32)
-    return _descend(lambda th: lasso_cd_sweep(G, cy, th, lam, n), theta, max_iter, tol)
+    if mesh is None:
+        return _descend(lambda th: lasso_cd_sweep(G, cy, th, lam, n), theta, max_iter, tol)
+    interpret = mesh.devices.flat[0].platform != "tpu"
+
+    def local(G, cy, lam, n, max_iter, tol):
+        held = cd.hold(G)  # once a fit
+
+        def sweep(th):
+            return cd.sweep(held, _head(G, cy, th), th.reshape(-1), lam, n, interpret).reshape(-1, 1)
+
+        return _descend(sweep, theta, max_iter, tol)
+
+    # check_vma off: the kernel's result says nothing of how it varies over the mesh (it does not)
+    return jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)(G, cy, lam, n, max_iter, tol)
 
 
 @partial(jax.jit, static_argnames=("precision",))
@@ -311,6 +339,7 @@ class Lasso(RegressionMixin, BaseEstimator):
         self.tol = tol
         self.__theta = None
         self.n_iter = None
+        self._kernel = False  # whether the last fit's sweeps ran as ops/cd.py's kernel
 
     @property
     def lam(self) -> float:
@@ -336,7 +365,9 @@ class Lasso(RegressionMixin, BaseEstimator):
         """Coordinate-descent fit (reference lasso.py:90-141).
 
         While ``telemetry.tracing()`` the fit is a ``heat.lasso.fit`` span
-        (stats ``mode=gram|residual``, ``n``, ``m``, ``p``, ``sweeps``) whose
+        (stats ``mode=gram|residual``, ``n``, ``m``, ``p``, ``sweeps``, and
+        ``sweep``: ``kernel`` where every sweep ran as ``ops/cd.py``'s kernel,
+        else ``loop``) whose
         children lie side by side: ``.prepare`` (casts and reshapes; in
         residual mode the transposed copy), ``.gram`` (the dispatch of the
         precompute, Gram mode only), ``.dispatch`` (the descent program's one
@@ -344,7 +375,9 @@ class Lasso(RegressionMixin, BaseEstimator):
         until the device has run them all) and ``.copy`` (the fit's one read:
         the sweeps run and the last change, ``telemetry.ready_then``), then
         ``.wrap`` (θ into a ``DNDarray``), each once a fit; the same
-        intervals add to ``fusion.cache_stats()``'s ``phase_lasso_*`` keys."""
+        intervals add to ``fusion.cache_stats()``'s ``phase_lasso_*`` keys,
+        with the fit's sweeps, its one read and the sweeps that ran as the
+        kernel (``kernel_sweeps``)."""
         if not isinstance(x, DNDarray) or not isinstance(y, DNDarray):
             raise TypeError("x and y must be DNDarrays")
         if x.ndim != 2:
@@ -367,10 +400,10 @@ class Lasso(RegressionMixin, BaseEstimator):
         )
         try:
             sweeps = self._fit(x, y, gram_mode, ph.phase)
-            ph.note(sweeps=sweeps)
+            ph.note(sweeps=sweeps, sweep="kernel" if self._kernel else "loop")
         finally:
             ph.close()
-        fusion.note_phases("lasso", ph.ns, fits=1, sweeps=sweeps, syncs=1)
+        fusion.note_phases("lasso", ph.ns, fits=1, sweeps=sweeps, syncs=1, kernel_sweeps=sweeps * self._kernel)
         return self
 
     def _fit(self, x: DNDarray, y: DNDarray, gram_mode: bool, mark) -> int:
@@ -399,10 +432,12 @@ class Lasso(RegressionMixin, BaseEstimator):
             if padding:
                 yl = jnp.pad(yl, ((0, padding), (0, 0)))
             G, cy = _gram_precompute(comm.mesh, comm.axis_name, n if padding else None)(rows, yl)
+            self._kernel = cd.takes(G.shape, G.dtype, G.sharding)
             mark("dispatch")
-            theta, n_iter, diff = lasso_descent(G, cy, lam, n, max_iter, tol)
+            theta, n_iter, diff = lasso_descent(G, cy, lam, n, max_iter, tol, comm.mesh if self._kernel else None)
         else:
             XT = jnp.transpose(rows)  # one pass; every sweep slice is contiguous
+            self._kernel = False
             mark("dispatch")
             theta, n_iter, diff = _descent_residual(XT, yl, lam, max_iter, tol, precision=precision)
         # the fit's one read waits for the whole program: theta is final when it returns

@@ -724,25 +724,40 @@ def test_lasso_precompute_on_sharded_rows_is_one_all_reduce(v5e_2x2, lasso_rows)
 
 
 def test_lasso_descent_is_a_loop_of_sweeps_without_collectives(one_v5e, lasso_rows):
-    """The descent program (ISSUE 41) is ONE ``while`` over the sweeps, which
-    carries the count, theta and the stop flag, around ONE ``while`` of the
-    coordinate steps over G, 1 MB: the loop ``cd_step_us`` finds in the
-    device's trace by its carry (the step's index, ``c``, theta), and the
-    outer loop is not taken for it. ``max_iter`` and ``tol`` are operands."""
+    """The descent program on the chip's path (``Lasso._fit`` hands it the
+    mesh where ``ops/cd.py::takes`` G) is ONE ``while`` over the sweeps,
+    which carries the count, theta and the stop flag, around ONE
+    ``lasso_cd_sweep`` kernel (G held in VMEM, a sweep's 512 steps inside it:
+    the custom call ``cd_kernel_step_us`` finds by its name) and ONE product,
+    the head's ``c = cy - G theta`` at ``HIGHEST`` (a VPU multiply-reduce on a
+    v5e, no bfloat16 pass). No loop of coordinate steps is left for
+    ``cd_step_us`` to find. ``max_iter`` and ``tol`` are operands; no
+    collective."""
+    from jax.sharding import Mesh
+
+    from heat_tpu.ops import cd
     from heat_tpu.regression import lasso
 
     is_sweep_loop = spec.load_module("layer_metrics", "cd_step_us.py").is_sweep_loop
+    is_sweep_kernel = spec.load_module("layer_metrics", "cd_kernel_step_us.py").is_sweep_kernel
     _, m = lasso_rows
+    assert cd.held_bytes(m) <= cd.VMEM_BUDGET
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_v5e) for s, d in
               (((m, m), jnp.float32), ((m,), jnp.float32), ((), jnp.float32), ((), jnp.int32), ((), jnp.int32), ((), jnp.float32))]
-    text = _compiled_text(lasso.lasso_descent, *shapes)
-    assert "jit_lasso_descent" in text
-    entry = text[text.index("ENTRY"):]
+    mesh = Mesh(np.array([one_v5e._device]), ("x",))
+    bound = functools.partial(lasso.lasso_descent.lower, mesh=mesh)
+    with jax.enable_x64(False):
+        products = [line for line in bound(*shapes).as_text().splitlines() if "stablehlo.dot_general" in line]
+    assert len(products) == 1 and "precision = [HIGHEST, HIGHEST]" in products[0]
+    text = _compiled_text(types.SimpleNamespace(lower=bound), *shapes)
+    assert "jit_lasso_descent" in text and "bf16" not in text
     loops = [line.strip() for line in text.splitlines() if re.search(r" while\(", line)]
-    outer = [line for line in loops if line in entry]
-    assert len(loops) == 2 and len(outer) == 1 and f"f32[{m},{m}]" in entry
-    assert re.sub(r"\{[^}]*\}", "", outer[0].partition(" = ")[2]).startswith(f"(s32[], f32[{m},1], pred[],")
-    assert [is_sweep_loop(line, m) for line in loops] == [line not in outer for line in loops]
+    assert len(loops) == 1 and loops[0] in text[text.index("ENTRY"):]
+    assert re.sub(r"\{[^}]*\}", "", loops[0].partition(" = ")[2]).startswith(f"(s32[], f32[{m},1], pred[],")
+    assert not is_sweep_loop(loops[0], m)
+    calls = [line.strip() for line in text.splitlines() if " custom-call(" in line]
+    assert len(calls) == 1 and 'custom_call_target="tpu_custom_call"' in calls[0] and is_sweep_kernel(calls[0])
+    assert len([line for line in text.splitlines() if re.search(r' fusion\(.*op_name="[^"]*/dot_general"', line)]) == 1
     assert not re.search(r" all-reduce(-start)?\(| all-gather(-start)?\(", text)
 
 

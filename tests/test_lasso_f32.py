@@ -8,7 +8,9 @@ Gram of bfloat16-rounded rows; the generator's unit mean square, and what
 upstream's step does on columns of mean square 2 (what refused PR 39); the
 device's loop over sweeps against a host's (ISSUE 41: the same sweeps, the
 same stop, one read a fit, one compiled program whatever ``max_iter`` and
-``tol``); the spans and counters of a fit; the ``opsplane`` families.
+``tol``); the sweep as one Pallas kernel (``ops/cd.py``, interpreted here)
+against the loop, and when a fit takes it; the spans and counters of a fit;
+the ``opsplane`` families.
 
 What only the chip shows (the precompute at 3 145 728 x 512: its memory, its
 products' precision, its all-reduces over four chips) is compiled for a
@@ -19,7 +21,9 @@ fixture.
 import ast
 import glob
 import os
+import re
 import tempfile
+import types
 import warnings
 
 import jax
@@ -30,6 +34,7 @@ import pytest
 import heat_tpu as ht
 from chipbench import design, spec
 from heat_tpu.core import fusion, telemetry
+from heat_tpu.ops import cd
 from heat_tpu.regression import lasso as lasso_mod
 
 N, M, LAM, SWEEPS = 768, 24, 0.1, 30
@@ -422,7 +427,7 @@ def test_spans_of_a_fit_in_a_profiler_session(problem, monkeypatch, mode):
     assert after["phase_lasso_fits"] - before["phase_lasso_fits"] == 1  # a profiler session is the switch too
     (parent,) = [s for s in spans if s[0] == "heat.lasso.fit"]
     assert {k: str(v) for k, v in parent[3].items()}.items() >= {
-        "mode": mode, "n": str(N), "m": str(M), "p": str(ht.get_comm().size), "sweeps": "3"
+        "mode": mode, "n": str(N), "m": str(M), "p": str(ht.get_comm().size), "sweeps": "3", "sweep": "loop"
     }.items()
     children = sorted((s for s in spans if s[0].startswith("heat.lasso.fit.")), key=lambda s: s[1])
     names = [s[0].rsplit(".", 1)[1] for s in children]
@@ -439,7 +444,7 @@ def test_opsplane_exports_the_lasso_counters(problem):
         fit(x, y, 0, max_iter=2)
     text = opsplane.render()
     assert not opsplane.validate_exposition(text)
-    assert all(f"heat_tpu_lasso_{c}_total" in text for c in ("fits", "sweeps", "syncs"))
+    assert all(f"heat_tpu_lasso_{c}_total" in text for c in ("fits", "sweeps", "syncs", "kernel_sweeps"))
     assert all(f'heat_tpu_lasso_phase_seconds_total{{phase="{ph}"}}' in text for ph in fusion._LASSO_PHASES)
 
 
@@ -458,3 +463,115 @@ def test_the_programs_are_named_for_the_trace():
     assert "jit_lasso_descent" in text and text.count("stablehlo.while") == 2
     (product,) = [line for line in text.splitlines() if "stablehlo.dot_general" in line]
     assert "precision = [HIGHEST, HIGHEST]" in product  # c = cy - G theta at the head of a sweep, in float32
+
+
+# -- the sweep as one kernel (ops/cd.py) ----------------------------------------
+def _moments(m, seed):
+    """G and cy of off-centre rows of unit mean square, m features over 4 m
+    rows: column 0 the intercept, four coefficients in the truth."""
+    rng = np.random.default_rng(seed)
+    x = ((1.0 + rng.standard_normal((4 * m, m))) / np.sqrt(2.0)).astype(np.float32)
+    x[:, 0] = 1.0
+    y = x[:, :4] @ np.array([0.5, 1.0, -1.0, 0.3], np.float32) + 0.1 * rng.standard_normal(4 * m).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        return _GRAM(jnp.asarray(x), jnp.asarray(y[:, None]))
+
+
+@pytest.mark.parametrize("m", [8, 130, 256], ids=["one_tile", "ragged", "two_tiles"])
+def test_sweep_kernel_is_the_loop_sweep(m):
+    """Three sweeps from 0 as the kernel (interpreted) and as the loop: the
+    same theta to float32's rounding, the intercept kept, the same
+    coordinates set to 0 by the threshold, padding never stepped."""
+    G, cy = _moments(m, m)
+    n, lam = 4 * m, jnp.float32(LAM)
+    held = cd.hold(G)
+    assert held.shape == (m, -(-m // 128), 128) and not np.asarray(held).reshape(m, -1)[:, m:].any()
+    loop = jax.jit(lasso_mod.lasso_cd_sweep)
+    theta = jnp.zeros((m, 1), jnp.float32)
+    for _ in range(3):
+        want = loop(G, cy, theta, lam, n)
+        got = cd.sweep(held, lasso_mod._head(G, cy, theta), theta.reshape(-1), lam, n, interpret=True)
+        assert got.shape == (m,) and np.abs(np.asarray(got) - np.asarray(want)[:, 0]).max() <= 1e-6 * np.abs(np.asarray(want)).max()
+        theta = want
+    theta = np.asarray(theta)[:, 0]
+    assert theta[0] != 0 and ((np.asarray(got) == 0) == (theta == 0)).all() and (theta[1:] == 0).any()
+
+
+def _one_device_mesh():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:1]), ("x",))
+
+
+@pytest.mark.parametrize("tol", [-1.0, 10.0], ids=["every_sweep", "first_sweep"])
+def test_descent_on_the_kernels_path_is_the_loops(tol):
+    """``lasso_descent`` with the kernel's path forced (a mesh given; on the
+    CPU's devices the kernel is interpreted): the loop's theta, sweeps and
+    last change."""
+    G, cy = _moments(130, 1)
+    args = (G, cy, np.float32(LAM), 520, np.int32(3), np.float32(tol))
+    loop = lasso_mod.lasso_descent(*args)
+    kernel = lasso_mod.lasso_descent(*args, _one_device_mesh())
+    assert int(loop[1]) == int(kernel[1]) == (3 if tol < 0 else 1)
+    assert np.abs(np.asarray(loop[0]) - np.asarray(kernel[0])).max() <= 1e-6 * np.abs(np.asarray(loop[0])).max()
+    assert float(kernel[2]) == pytest.approx(float(loop[2]), rel=1e-5)
+
+
+def test_kernels_path_on_the_mesh_runs_no_collective():
+    """On the CPU mesh G and cy are replicated: each device runs the kernel on
+    its own copy, and the program holds no all-reduce and no all-gather."""
+    from jax.sharding import NamedSharding
+
+    comm = ht.get_comm()
+    assert comm.size > 1
+    G, cy = (jax.device_put(v, NamedSharding(comm.mesh, jax.sharding.PartitionSpec())) for v in _moments(64, 2))
+    args = (G, cy, np.float32(LAM), 256, np.int32(3), np.float32(-1))
+    theta = lasso_mod.lasso_descent(*args, comm.mesh)[0]
+    assert theta.sharding.is_fully_replicated
+    assert np.abs(np.asarray(theta) - np.asarray(lasso_mod.lasso_descent(*args)[0])).max() < 1e-6
+    text = lasso_mod.lasso_descent.lower(*args, comm.mesh).compile().as_text()
+    assert not re.search(r" all-reduce(-start)?\(| all-gather(-start)?\(", text)
+
+
+def test_takes_reads_devices_shape_dtype_and_the_budget():
+    """The kernel is G's sweep on TPUs only, for a square float32 G held within
+    the module's VMEM budget: every m up to 1 024, none past it."""
+    tpu = types.SimpleNamespace(device_set=[types.SimpleNamespace(platform="tpu")] * 4)
+    cpu = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    assert cd.takes((512, 512), jnp.float32, tpu) and cd.takes((1024, 1024), jnp.float32, tpu)
+    assert cd.takes((130, 130), jnp.float32, tpu)
+    assert not cd.takes((512, 512), jnp.float32, cpu)
+    assert cd.held_bytes(1024) <= cd.VMEM_BUDGET < cd.held_bytes(1025)
+    assert not cd.takes((1025, 1025), jnp.float32, tpu) and not cd.takes((2048, 2048), jnp.float32, tpu)
+    for dtype in (jnp.bfloat16, jnp.float64):
+        assert not cd.takes((512, 512), dtype, tpu)
+    assert not cd.takes((512, 256), jnp.float32, tpu)
+
+
+def test_kernel_sweeps_count_a_fit_on_the_kernels_path(problem, monkeypatch):
+    """Where G takes the kernel (pretended here, the kernel interpreted), a
+    traced fit counts its sweeps in ``phase_lasso_kernel_sweeps`` and
+    ``opsplane``'s ``heat_tpu_lasso_kernel_sweeps_total``, says ``sweep=kernel``
+    on its span and gives the loop's theta; on the loop's path (the CPU, as it
+    is) it counts none and says ``sweep=loop``; an untraced fit counts
+    nothing."""
+    from heat_tpu.core import opsplane
+
+    def exported():
+        (line,) = [line for line in opsplane.render().splitlines() if line.startswith("heat_tpu_lasso_kernel_sweeps_total ")]
+        return float(line.split()[1])
+
+    x, y = problem
+    key, stats = "phase_lasso_kernel_sweeps", []
+    monkeypatch.setattr(telemetry.Phases, "note", lambda self, **kw: stats.append((self.name, kw)))
+    before, counted = fusion.cache_stats()[key], exported()
+    with telemetry.enabled(1):
+        loop = fit(x, y, 0, max_iter=3).theta.numpy()
+    assert fusion.cache_stats()[key] == before and exported() == counted
+    monkeypatch.setattr(cd, "takes", lambda shape, dtype, sharding: True)
+    assert np.abs(fit(x, y, 0, max_iter=3).theta.numpy() - loop).max() <= 1e-6 * np.abs(loop).max()
+    assert fusion.cache_stats()[key] == before
+    with telemetry.enabled(1):
+        est = fit(x, y, 0, max_iter=3)
+    assert fusion.cache_stats()[key] - before == 3 == est.n_iter and exported() - counted == 3
+    assert [kw["sweep"] for span, kw in stats if span == "heat.lasso.fit"] == ["loop", "kernel"]

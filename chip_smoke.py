@@ -9,11 +9,11 @@ directory) and then, as the last line, the verdict with exactly these keys —
 exiting 0 only when every phase passed. It is NOT a benchmark: the seconds
 it reports are per-phase wall clock including compilation.
 
-Phases: analytics (KMeans on the fused Lloyd kernel vs the jnp oracle, cdist,
+Phases: analytics (KMeans on the fused Lloyd kernel vs the jnp path, cdist,
 moments, QR, an eager chain + resplit), server (four ``serving.Session``
 client threads), trainer (``nn.DataParallel(ResNet18)``, plus DASO on more
-than one device), the three pallas kernels compiled (``interpret=False``),
-and a final "nothing was swallowed" audit of the fusion degrade counters.
+than one device), and a final "nothing was swallowed" audit of the fusion
+degrade counters.
 
 The check functions take sizes so ``tests/test_chip_smoke.py`` can call them
 tiny on the CPU mesh (passing ``interpret=True``); ``main`` always runs the
@@ -26,7 +26,7 @@ four chips as the benchmark cell ``cdist_ring_4c`` (50 000 x 64, the same
 chips there are, is 32768 x 64 (a 4.3 GB result).
 """
 
-import functools
+import contextlib
 import importlib.metadata
 import json
 import sys
@@ -47,7 +47,6 @@ FULL = {
     "server": dict(clients=4, requests=20, n=4096),
     "trainer": dict(batch=256, steps=5),
     "daso": dict(batch=256),
-    "kernels": dict(n=4096, f=64, seq=4096, heads=8, dim=64),
 }
 
 
@@ -79,9 +78,25 @@ def _max_abs_err(got, want) -> float:
 # ----------------------------------------------------------------------
 # analytics
 # ----------------------------------------------------------------------
+@contextlib.contextmanager
+def _lloyd_takes(mode):
+    """``ops/lloyd.py::takes`` answers ``mode`` (``None``: the jnp path) for
+    every operand while the block runs."""
+    from heat_tpu.ops import lloyd
+
+    real = lloyd.takes
+    lloyd.takes = lambda shape, k, split, sharding: mode
+    try:
+        yield
+    finally:
+        lloyd.takes = real
+
+
 def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol=1e-3):
-    """``KMeans.fit`` on the fused Lloyd kernel (the product dispatch on TPU)
-    against a ``use_fused=False`` fit from the same seed.
+    """``KMeans.fit`` on the fused Lloyd kernel (the product dispatch on TPU;
+    with ``interpret`` a pretended ``lloyd.takes`` sends the fit to the
+    interpreted kernel) against a fit on the jnp path (``takes`` pretended
+    ``None``) from the same seed.
 
     Tolerances: both fits run ``iters`` Lloyd steps from identical initial
     centers on unstructured N(0, 1) data, and both multiply float32 rows in
@@ -106,18 +121,16 @@ def check_kmeans(n, f, k, iters, interpret=False, center_atol=2e-3, inertia_rtol
     comm = x.comm
     out = {}
 
-    fused = ht.cluster.KMeans(
-        n_clusters=k, max_iter=iters, tol=0.0, random_state=7,
-        use_fused=True if interpret else None,
-    )
-    mode, interp = fused._fused_mode(x)
-    out["mode"], out["interpret"] = mode, interp
-    assert mode in ("single", "sharded"), f"KMeans did not take the fused path: {mode!r}"
-    assert interp is interpret, f"fused Lloyd interpret={interp}, expected {interpret}"
-    fused.fit(x)
-    oracle = ht.cluster.KMeans(
-        n_clusters=k, max_iter=iters, tol=0.0, random_state=7, use_fused=False
-    ).fit(x)
+    fused = ht.cluster.KMeans(n_clusters=k, max_iter=iters, tol=0.0, random_state=7)
+    pretend = _lloyd_takes("sharded" if comm.size > 1 else "single") if interpret else contextlib.nullcontext()
+    with pretend:
+        mode, interp = fused._fused_mode(x)
+        out["mode"], out["interpret"] = mode, interp
+        assert mode in ("single", "sharded"), f"KMeans did not take the fused path: {mode!r}"
+        assert interp is interpret, f"fused Lloyd interpret={interp}, expected {interpret}"
+        fused.fit(x)
+    with _lloyd_takes(None):
+        oracle = ht.cluster.KMeans(n_clusters=k, max_iter=iters, tol=0.0, random_state=7).fit(x)
 
     centers = fused.cluster_centers_.numpy()
     assert centers.shape == (k, f), centers.shape
@@ -358,60 +371,6 @@ def check_daso(batch, model=None):
 
 
 # ----------------------------------------------------------------------
-# kernels
-# ----------------------------------------------------------------------
-def check_kernels(n, f, seq, heads, dim, interpret=False,
-                  pairwise_rtol=1e-4, flash_atol=3e-2):
-    """The pairwise-distance and flash-attention pallas kernels against
-    their jnp references (the Lloyd kernel is covered by
-    :func:`check_kmeans`). The attention reference is
-    ``dot_product_attention`` at HIGHEST matmul precision on f32 copies of
-    the operands. ``flash_atol`` is bf16 rounding of O(1) scores and
-    probabilities (2⁻⁸ relative) for BOTH dtypes: on the MXU an f32 matmul
-    at default precision rounds its operands to bf16 too, in the kernel as
-    in XLA — ``dense_default_f32_err`` reports the same reference's error at
-    the default precision for comparison."""
-    import jax
-    import jax.numpy as jnp
-
-    from heat_tpu.nn.attention import dot_product_attention, flash_attention
-    from heat_tpu.ops import pairwise_distance
-    from heat_tpu.ops.flash import flash_attention_tpu
-    from heat_tpu.spatial.distance import _euclidian, _manhattan
-
-    out = {}
-    kx, ky, kq, kk, kv = jax.random.split(jax.random.PRNGKey(6), 5)
-    x = jax.random.normal(kx, (n, f), jnp.float32)
-    y = jax.random.normal(ky, (n, f), jnp.float32)
-    for p, ref in ((1, _manhattan), (2, _euclidian)):  # the exact jnp expressions
-        got = np.asarray(pairwise_distance(x, y, p=p, interpret=interpret))
-        want = np.asarray(jax.jit(ref)(x, y))
-        assert got.shape == (n, n)
-        out[f"pairwise_p{p}_err"] = _max_abs_err(got, want)
-        np.testing.assert_allclose(got, want, rtol=pairwise_rtol, atol=1e-4, err_msg=str(out))
-
-    q, k, v = (jax.random.normal(kk_, (1, seq, heads, dim), jnp.float32) for kk_ in (kq, kk, kv))
-    if interpret:
-        flash = functools.partial(flash_attention_tpu, causal=True, interpret=True)
-    else:
-        flash = functools.partial(flash_attention, causal=True, impl="pallas")
-    for name, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
-        qd, kd, vd = (t.astype(dtype) for t in (q, k, v))
-        got = flash(qd, kd, vd)
-        assert got.shape == q.shape and got.dtype == dtype, (got.shape, got.dtype)
-        q32, k32, v32 = (t.astype(jnp.float32) for t in (qd, kd, vd))
-        with jax.default_matmul_precision("highest"):
-            want = dot_product_attention(q32, k32, v32, causal=True)
-        out[f"flash_{name}_err"] = _max_abs_err(got.astype(jnp.float32), want)
-        if dtype == jnp.float32:
-            out["dense_default_f32_err"] = _max_abs_err(
-                dot_product_attention(q32, k32, v32, causal=True), want
-            )
-        assert out[f"flash_{name}_err"] <= flash_atol, out
-    return out
-
-
-# ----------------------------------------------------------------------
 # audit
 # ----------------------------------------------------------------------
 def check_nothing_swallowed():
@@ -473,7 +432,6 @@ def main() -> int:
         ("server", check_server),
         ("trainer", check_trainer),
         *([("daso", check_daso)] if facts["device"]["count"] > 1 else []),
-        ("kernels", check_kernels),
         ("audit", check_nothing_swallowed),
     ]
     seconds, measured, failed = {}, {}, []

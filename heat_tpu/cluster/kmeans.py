@@ -75,15 +75,14 @@ class KMeans(_KCluster):
     """K-Means with Lloyd's algorithm (reference kmeans.py:14-139).
 
     Parameters mirror the reference: n_clusters=8, init='random',
-    max_iter=300, tol=1e-4, random_state=None. ``use_fused`` (beyond the
-    reference) selects the single-pass samples-in-lanes pallas Lloyd kernel
-    (ops/lloyd.py): ``None`` auto-selects it on TPU backends, where it reads
-    the operand once per iteration where the jnp path reads it twice, and
-    the last pass of a fit writes the labels it assigned (``labels_`` is the
-    assignment ``inertia_`` is summed over; no separate label pass);
-    ``True`` forces it (interpret mode off-TPU — the testing path), ``False``
-    pins the jnp oracle path. A kernel that fails to lower or run raises:
-    there is no fallback from the fused path to the oracle.
+    max_iter=300, tol=1e-4, random_state=None. Where ``ops/lloyd.py::takes``
+    the rows (on TPUs, f <= 512, k <= 128) a fit runs the single-pass
+    samples-in-lanes pallas Lloyd kernel, which reads the operand once per
+    iteration where the jnp path reads it twice, and whose last pass writes
+    the labels it assigned (``labels_`` is the assignment ``inertia_`` is
+    summed over; no separate label pass); elsewhere it runs the jnp path. A
+    kernel that fails to lower or run raises: there is no fallback from the
+    fused path to the jnp path.
 
     When a fit stops, on every path: iterations run until the shift of an
     iteration (the squared distance its centres moved, all clusters added
@@ -116,11 +115,9 @@ class KMeans(_KCluster):
         max_iter: int = 300,
         tol: float = 1e-4,
         random_state: Optional[int] = None,
-        use_fused: Optional[bool] = None,
     ):
         if isinstance(init, str) and init in ("kmeans++", "k-means++"):
             init = "probability_based"
-        self.use_fused = use_fused
         super().__init__(
             metric=_sq_dist,  # module-level identity: kernels cache across instances
             n_clusters=n_clusters,
@@ -131,34 +128,13 @@ class KMeans(_KCluster):
         )
 
     def _fused_mode(self, x: DNDarray):
-        """Resolve the Lloyd dispatch: ('single'|'sharded', interpret) or
-        (None, False) for the jnp path."""
-        n, f = int(x.shape[0]), int(x.shape[1])
-        k = self.n_clusters
-        if self.use_fused is False:
-            return None, False
-        if _lloyd.fused_supported(n, f, k):
-            return "single", False
-        if x.split == 0 and _lloyd.fused_sharded_supported(f, k):
-            return "sharded", False
-        if not self.use_fused:
-            return None, False  # auto never interprets: jnp is faster off-TPU
-        # forced off-TPU (the testing path): pallas interpret mode
-        if x.split == 0 and f <= 512 and k <= 128:
-            return "sharded", True
-        if len(jax.devices()) == 1 and f <= 512 and k <= 128:
-            return "single", True
-        # use_fused=True could not be honored — say so loudly instead of
-        # letting a test of the fused path pass vacuously on the jnp oracle
-        import warnings
-
-        warnings.warn(
-            f"KMeans(use_fused=True) falling back to the jnp path: shape "
-            f"(n={n}, f={f}, k={k}, split={x.split}) has no fused dispatch "
-            "(needs f<=512, k<=128, and split=0 or a single device)",
-            stacklevel=3,
-        )
-        return None, False
+        """The Lloyd dispatch of a fit of ``x``: ``(mode, interpret)``, mode
+        ``ops/lloyd.py::takes`` of the rows (``None`` for the jnp path) and
+        ``interpret`` whether they lie on other devices than TPUs, where only
+        a pretended ``takes`` sends them to a kernel: Pallas' interpreter."""
+        sharding = x.comm.sharding(x.ndim, x.split)
+        mode = _lloyd.takes(x.shape, self.n_clusters, x.split, sharding)
+        return mode, any(d.platform != "tpu" for d in sharding.device_set)
 
     def fit(self, x: DNDarray) -> "KMeans":
         """Cluster ``x`` (n_samples, n_features) (reference kmeans.py:102-139).
@@ -247,7 +223,6 @@ class KMeans(_KCluster):
             x.comm,
         )
         self._labels = self._wrap_labels(labels, x)
-        epilogues = _lloyd.RUN_LABEL_EPILOGUES if mode else 0
         blocks = tail_blocks = 0
         if mode:
             # one device's samples, and the fewest valid among them any device
@@ -259,6 +234,6 @@ class KMeans(_KCluster):
                 local, valid, int(x.shape[1]), k, jnp.dtype(ddtype).itemsize
             )
         return {
-            "dispatches": 1, "syncs": 1, "label_epilogues": epilogues,
+            "dispatches": 1, "syncs": 1, "label_epilogues": 0,
             "blocks": blocks, "tail_blocks": tail_blocks,
         }

@@ -10,8 +10,7 @@ module makes those mechanisms first-class for the long-context case:
 * :func:`dot_product_attention` — dense softmax attention, the oracle.
 * :func:`flash_attention` — blockwise online-softmax attention expressed as a
   ``lax.scan`` over key/value tiles. O(seq) memory instead of O(seq²); XLA
-  fuses each tile into MXU matmuls. (A hand-tiled pallas kernel for the same
-  math lives in :mod:`heat_tpu.ops.flash`.)
+  fuses each tile into MXU matmuls.
 * :func:`ring_attention` — sequence parallelism over the device mesh: Q stays
   resident, K/V shards rotate via ``lax.ppermute`` (exactly the reference's
   ring cdist schedule), each step folding one tile into the online-softmax
@@ -126,33 +125,6 @@ def _finalize(l, o, dtype):
     return (o / denom[..., None]).astype(dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_pallas_diff(q, k, v, causal, scale):
-    from ..ops.flash import flash_attention_tpu
-
-    return flash_attention_tpu(q, k, v, causal=causal, scale=scale)
-
-
-def _flash_pallas_fwd(q, k, v, causal, scale):
-    return _flash_pallas_diff(q, k, v, causal, scale), (q, k, v)
-
-
-def _flash_pallas_bwd(causal, scale, res, g):
-    # backward through the scan-flash path: same O(seq) memory class as the
-    # forward, so 'auto' never changes a training run's memory behavior
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: flash_attention(q, k, v, causal=causal, scale=scale, impl="scan"),
-        q,
-        k,
-        v,
-    )
-    return vjp(g)
-
-
-_flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -161,33 +133,13 @@ def flash_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     block_size: int = 512,
-    impl: str = "auto",
 ) -> jax.Array:
-    """Blockwise online-softmax attention (flash-style).
+    """Blockwise online-softmax attention (flash-style): a ``lax.scan`` over
+    key tiles of ``block_size``; runs everywhere, fully differentiable, XLA
+    schedules the tiles.
 
     Memory is O(q_len·heads·head_dim) instead of O(q_len·k_len·heads).
-
-    ``impl`` selects the backend:
-
-    * ``'scan'`` — a ``lax.scan`` over key tiles; runs everywhere, fully
-      differentiable, XLA schedules the tiles.
-    * ``'pallas'`` — the hand-tiled TPU kernel (:mod:`heat_tpu.ops.flash`);
-      owns the (q, k) tile grid, skips above-diagonal tiles when causal.
-      Its win over dense is memory class (O(seq) vs O(seq²)); its speed
-      against dense and scan is not measured on current code.
-      Differentiable via a custom VJP whose backward re-runs the scan path
-      (same O(seq) memory).
-      ``block_size`` does not apply — the kernel picks its own 128-aligned
-      tiles (pass ``block_q``/``block_k`` to
-      :func:`heat_tpu.ops.flash.flash_attention_tpu` directly to tune them).
-    * ``'auto'`` — ``'scan'``, everywhere. The pallas kernel is opt-in until
-      a chip measurement shows it beating the scan path (the
-      measured-fastest path owns the default).
     """
-    if impl not in ("auto", "scan", "pallas"):
-        raise ValueError(f"unknown flash impl {impl!r}")
-    if impl == "pallas":
-        return _flash_pallas_diff(q, k, v, causal, scale)
     acc = _acc_dtype(q.dtype)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])

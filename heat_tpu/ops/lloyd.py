@@ -59,11 +59,10 @@ the single rounded pass. bfloat16 rows are one piece: they keep their
 bfloat16 multiplication with float32 accumulation and half the HBM stream.
 The jnp path asks XLA for the same (``ops/mxu.py::matmul``).
 
-This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here on
-TPU (``fused_supported`` / ``fused_sharded_supported``) and takes the jnp
-path (``cluster/kmeans.py:_lloyd_run``) for wider shapes or
-``use_fused=False``; a kernel that fails to lower raises.
-:func:`fused_lloyd_run` is
+This kernel IS the product path: ``cluster.KMeans.fit`` dispatches here
+where :func:`takes` the rows and takes the jnp path
+(``cluster/kmeans.py:_lloyd_run``) elsewhere; a kernel that fails to lower
+raises. :func:`fused_lloyd_run` is
 single-device (its pallas_call has no partitioning spec);
 :func:`fused_lloyd_run_sharded` is the
 multi-chip form: a shard_map running the kernel per device and merging the
@@ -97,15 +96,8 @@ from .mxu import bf16_pieces as _bf16_pieces, mxu_precision  # noqa: F401  (the 
 __all__ = [
     "fused_lloyd_run",
     "fused_lloyd_run_sharded",
-    "fused_sharded_supported",
-    "fused_supported",
+    "takes",
 ]
-
-
-RUN_LABEL_EPILOGUES = 0
-"""XLA passes over the rows that one ``fused_lloyd_run*`` program makes for
-its labels: none, its last kernel pass writes them. ``KMeans.fit`` adds it per
-program it dispatches (``fusion.cache_stats()["phase_kmeans_label_epilogues"]``)."""
 
 
 def _pad8(n: int) -> int:
@@ -143,21 +135,19 @@ def pass_blocks(n: int, n_valid: int, f: int, k: int, itemsize: int = 4) -> tupl
     return blocks, blocks - n_valid // block
 
 
-def fused_supported(n: int, f: int, k: int) -> bool:
-    """TPU backend, single device (the kernel has no partitioning spec —
-    a sharded operand would be gathered), and sublane-safe f/k."""
-    return (
-        jax.default_backend() == "tpu"
-        and len(jax.devices()) == 1
-        and f <= 512
-        and k <= 128
-    )
-
-
-def fused_sharded_supported(f: int, k: int) -> bool:
-    """TPU backend and sublane-safe shapes; device count is irrelevant (the
-    shard_map wrapper runs the kernel per device)."""
-    return jax.default_backend() == "tpu" and f <= 512 and k <= 128
+def takes(shape, k: int, split, sharding):
+    """The fused form that runs a fit of ``k`` centres on rows of ``shape``
+    (n, f), split along ``split`` and laid out by ``sharding``: ``"single"``
+    where the rows lie on one TPU (:func:`fused_lloyd_run`; its pallas_call
+    has no partitioning spec), ``"sharded"`` where they lie on several TPUs
+    split by rows (:func:`fused_lloyd_run_sharded`), ``None`` (the jnp path)
+    elsewhere, and wherever f passes 512 or k passes 128."""
+    devices = sharding.device_set
+    if not all(d.platform == "tpu" for d in devices) or shape[1] > 512 or k > 128:
+        return None
+    if len(devices) == 1:
+        return "single"
+    return "sharded" if split == 0 else None
 
 
 def _fold_lanes(rows: jax.Array) -> jax.Array:

@@ -164,30 +164,22 @@ def test_long_sequence_ring_memory_shape():
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("S,D", [(256, 16), (200, 16), (256, 128), (130, 8)])
-def test_pallas_flash_kernel_interpret_matches_dense(causal, S, D):
-    # the hand-tiled TPU kernel (ops/flash.py) in pallas interpret mode vs
-    # the dense oracle — covers ragged S (non-block-multiple), D < 128 lane
-    # padding, and the 2-D online-softmax state end-to-end (the kernel is
-    # otherwise only exercised on real TPU hardware)
-    from heat_tpu.ops.flash import flash_attention_tpu
-
+def test_flash_tiles_of_128_match_dense(causal, S, D):
+    # tiles of 128 keys: ragged S (a masked tail tile), D below and at the
+    # 128 lanes, and several tiles folded into the online-softmax state
     q, k, v = _qkv(B=1, S=S, H=2, D=D, seed=5)
-    out = flash_attention_tpu(
-        q, k, v, causal=causal, block_q=128, block_k=128, interpret=True
-    )
+    out = flash_attention(q, k, v, causal=causal, block_size=128)
     ref = dot_product_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
-def test_pallas_flash_kernel_interpret_bf16():
-    # bfloat16 inputs stay bf16 on both MXU contractions (the r05 kernel
-    # keeps the streamed dtype; only the online-softmax state is f32) —
-    # results must still match the f32 dense oracle to bf16 tolerance
-    from heat_tpu.ops.flash import flash_attention_tpu
-
+def test_flash_bf16_stays_bf16():
+    # bfloat16 inputs stay bfloat16 on both contractions and in the result;
+    # only the online-softmax state is float32 — within bfloat16 rounding of
+    # the float32 dense oracle
     q, k, v = _qkv(B=1, S=256, H=2, D=64, seed=7)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    out = flash_attention_tpu(qb, kb, vb, causal=True, interpret=True)
+    out = flash_attention(qb, kb, vb, causal=True, block_size=128)
     assert out.dtype == jnp.bfloat16
     ref = dot_product_attention(q, k, v, causal=True)
     np.testing.assert_allclose(
@@ -195,13 +187,34 @@ def test_pallas_flash_kernel_interpret_bf16():
     )
 
 
-def test_pallas_flash_kernel_interpret_big_blocks():
-    # block_q != block_k and blocks larger than the sequence
-    from heat_tpu.ops.flash import flash_attention_tpu
-
+def test_flash_block_larger_than_the_sequence():
+    # one tile of the whole sequence: block_size clips to the key length
     q, k, v = _qkv(B=1, S=96, H=2, D=16, seed=6)
-    out = flash_attention_tpu(
-        q, k, v, causal=True, block_q=256, block_k=512, interpret=True
-    )
+    out = flash_attention(q, k, v, causal=True, block_size=512)
     ref = dot_product_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+def test_flash_cross_attention_lengths():
+    # 70 queries against 300 keys: the key axis alone is tiled and padded
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (1, 70, 2, 16), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 300, 2, 16), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 300, 2, 16), jnp.float32)
+    out = flash_attention(q, k, v, block_size=128)
+    ref = dot_product_attention(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def test_flash_grads_match_dense():
+    # the scan differentiates as it runs: its gradients are the dense oracle's
+    q, k, v = _qkv(B=1, S=32, H=2, D=8)
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v, causal=True) ** 2).sum()
+
+    g_ref = jax.grad(loss(dot_product_attention), argnums=(0, 1, 2))(q, k, v)
+    flash = lambda q, k, v, causal: flash_attention(q, k, v, causal=causal, block_size=8)  # noqa: E731
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)

@@ -56,14 +56,15 @@ def test_trainer_checks():
 
 
 def test_kernel_checks_interpreted():
-    # chip_smoke.main audits a fresh process; an xdist worker has run other
+    # the kernel chip_smoke checks (the Lloyd kernel, interpreted), then the
+    # audit that closes main. chip_smoke.main audits a fresh process; an xdist worker has run other
     # files first, and test_resilience.py's last degraded force leaves its
     # quarantined program behind (degraded 1, quarantined 1: it turned this
     # test red once, PR 32, when both files fell to one worker)
     from heat_tpu.core import fusion
 
     fusion.clear_cache()
-    chip_smoke.check_kernels(n=256, f=8, seq=256, heads=2, dim=16, interpret=True)
+    chip_smoke.check_kmeans(n=1031, f=8, k=4, iters=3, interpret=True)
     chip_smoke.check_nothing_swallowed()
 
 

@@ -61,17 +61,16 @@ def rows():
 
 def fit(mode, data, init, monkeypatch, dtype=None, iters=ITERS, tol=-1.0):
     """One ``KMeans.fit`` through the public API on the path ``mode``: the
-    sharded kernel in interpret mode on the suite's virtual devices, the
-    single-device kernel likewise (steered here: off the chip ``_fused_mode``
-    takes it on one device only), or the jnp path. ``tol`` is the cell's: one
-    that no shift reaches, so that all ``iters`` iterations run."""
+    sharded or the single-device kernel in interpret mode on the suite's
+    virtual devices (``lloyd.takes`` pretended, as the chip would answer), or
+    the jnp path the CPU takes. ``tol`` is the cell's: one that no shift
+    reaches, so that all ``iters`` iterations run."""
     x = ht.array(data, split=None if mode == "single" else 0)
     if dtype is not None:
         x = x.astype(dtype)
-    km = KMeans(n_clusters=K, init=ht.array(init), max_iter=iters, tol=tol,
-                use_fused=False if mode == "jnp" else True)
-    if mode == "single":
-        monkeypatch.setattr(KMeans, "_fused_mode", lambda self, x: ("single", True))
+    km = KMeans(n_clusters=K, init=ht.array(init), max_iter=iters, tol=tol)
+    if mode != "jnp":
+        monkeypatch.setattr(lloyd, "takes", lambda shape, k, split, sharding: mode)
     assert (km._fused_mode(x)[0] or "jnp") == mode
     return km.fit(x)
 

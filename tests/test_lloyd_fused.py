@@ -2,8 +2,7 @@
 (the last pass's own argmin row, ISSUE 30) and the rows read in place, with
 the inertia from that same pass's accumulators (ISSUE 32), included.
 
-Runs in pallas interpret mode on CPU (the same strategy as
-tests/test_ops_pallas.py); real-TPU timing is the benchmark cell
+Runs in pallas interpret mode on CPU; real-TPU timing is the benchmark cell
 ``kmeans_fit_1c`` (``BENCHMARK.json``, ``chipbench/run.py``).
 """
 
@@ -19,6 +18,33 @@ def _rows(seed, n, f, k, scale=2.0):
     rng = np.random.default_rng(seed)
     data = rng.standard_normal((n, f)).astype(np.float32)
     return data, jnp.asarray(rng.standard_normal((k, f)).astype(np.float32) * scale)
+
+
+def _devices(platform, count):
+    """A stand-in for a sharding over ``count`` devices of ``platform``."""
+    import types
+
+    return types.SimpleNamespace(device_set=[types.SimpleNamespace(platform=platform, id=i) for i in range(count)])
+
+
+@pytest.mark.parametrize("platform,count,split,f,k,mode", [
+    ("tpu", 1, None, 16, 8, "single"),  # one TPU: the single form, whatever the split
+    ("tpu", 4, 0, 512, 128, "sharded"),  # TPUs, rows split: the sharded form, up to the widest f and k
+    ("tpu", 4, None, 16, 8, None),  # TPUs, rows not split: the jnp path
+    ("tpu", 1, 0, 513, 8, None),  # wider than the kernel holds
+    ("cpu", 1, 0, 16, 8, None),  # not TPUs
+    ("tpu", 4, 0, 600, 8, None),  # f past 512
+    ("tpu", 1, 0, 16, 129, None),  # k past 128
+])
+def test_lloyd_takes(platform, count, split, f, k, mode):
+    """``lloyd.takes`` picks the fused form from the operand alone: its
+    devices, their number, its split, f and k."""
+    import jax
+
+    from heat_tpu.ops import lloyd
+
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0]) if platform == "cpu" else _devices(platform, count)
+    assert lloyd.takes((100_000, f), k, split, sharding) == mode
 
 
 @pytest.mark.parametrize("path,n_steps", [("single", 1), ("single", 8), ("sharded", 1), ("sharded", 8)])
@@ -574,15 +600,20 @@ class TestFusedLloyd(TestCase):
     def test_kmeans_fit_keeps_bf16_stream(self):
         import jax.numpy as jnp
 
+        import unittest.mock
+
         import heat_tpu as ht
         from heat_tpu.cluster import KMeans
+        from heat_tpu.ops import lloyd
 
         rng = np.random.default_rng(12)
         x = ht.array(rng.standard_normal((600, 4)).astype(np.float32), split=0).astype(
             ht.bfloat16
         )
-        km = KMeans(n_clusters=3, max_iter=8, random_state=0, use_fused=True)
-        km.fit(x)
+        km = KMeans(n_clusters=3, max_iter=8, random_state=0)
+        with unittest.mock.patch.object(lloyd, "takes", lambda shape, k, split, sharding: "sharded"):
+            assert km._fused_mode(x) == ("sharded", True)
+            km.fit(x)
         # centroids computed (and exposed) in at-least-f32
         assert km.cluster_centers_.dtype in (ht.float32, ht.float64)
         assert km.labels_.shape[0] == 600

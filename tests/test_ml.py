@@ -103,64 +103,65 @@ class TestCluster(TestCase):
             km.fit(X)
 
     def test_kmeans_fused_path_matches_jnp(self):
-        # the product fused-pallas dispatch (use_fused=True -> interpret mode
-        # on the CPU mesh): same fixed point and labels as the jnp oracle.
-        # Only split=0 — a replicated operand on a multi-device mesh has no
-        # fused dispatch (the jnp comparison would be oracle-vs-oracle).
+        # the sharded fused-pallas dispatch (``lloyd.takes`` pretended, the
+        # kernel interpreted on the CPU mesh): same fixed point and labels as
+        # the jnp path the CPU takes. Only split=0 — a replicated operand on a
+        # multi-device mesh has no fused dispatch.
+        import unittest.mock
+
+        from heat_tpu.ops import lloyd
+
         X, y = make_blobs()
-        for split in (0,):
-            x = ht.array(X, split=split)
-            ref = ht.cluster.KMeans(
-                n_clusters=3, init="kmeans++", max_iter=50, random_state=5, use_fused=False
-            ).fit(x)
-            got = ht.cluster.KMeans(
-                n_clusters=3, init="kmeans++", max_iter=50, random_state=5, use_fused=True
-            ).fit(x)
-            self.assertGreater(_cluster_accuracy(got.labels_.numpy(), y, 3), 0.95)
-            np.testing.assert_array_equal(got.labels_.numpy(), ref.labels_.numpy())
-            np.testing.assert_allclose(
-                got.cluster_centers_.numpy(), ref.cluster_centers_.numpy(), rtol=1e-4, atol=1e-4
-            )
-            np.testing.assert_allclose(got.inertia_, ref.inertia_, rtol=1e-3)
+        x = ht.array(X, split=0)
+        ref = ht.cluster.KMeans(n_clusters=3, init="kmeans++", max_iter=50, random_state=5)
+        self.assertIsNone(ref._fused_mode(x)[0])
+        ref.fit(x)
+        with unittest.mock.patch.object(lloyd, "takes", lambda shape, k, split, sharding: "sharded"):
+            got = ht.cluster.KMeans(n_clusters=3, init="kmeans++", max_iter=50, random_state=5)
+            self.assertEqual(got._fused_mode(x), ("sharded", True))
+            got.fit(x)
+        self.assertGreater(_cluster_accuracy(got.labels_.numpy(), y, 3), 0.95)
+        np.testing.assert_array_equal(got.labels_.numpy(), ref.labels_.numpy())
+        np.testing.assert_allclose(
+            got.cluster_centers_.numpy(), ref.cluster_centers_.numpy(), rtol=1e-4, atol=1e-4
+        )
+        np.testing.assert_allclose(got.inertia_, ref.inertia_, rtol=1e-3)
 
     def test_kmeans_fused_ragged_rows(self):
         # prime row count: the sharded kernel must mask the physical pad
+        import unittest.mock
+
+        from heat_tpu.ops import lloyd
+
         rng = np.random.default_rng(12)
         X = np.concatenate(
             [rng.normal(0, 0.3, (101, 3)), rng.normal(4, 0.3, (102, 3))]
         ).astype(np.float32)
         y = np.array([0] * 101 + [1] * 102)
         x = ht.array(X, split=0)
-        km = ht.cluster.KMeans(n_clusters=2, random_state=3, use_fused=True).fit(x)
+        with unittest.mock.patch.object(lloyd, "takes", lambda shape, k, split, sharding: "sharded"):
+            km = ht.cluster.KMeans(n_clusters=2, random_state=3)
+            self.assertEqual(km._fused_mode(x)[0], "sharded")
+            km.fit(x)
         self.assertGreater(_cluster_accuracy(km.labels_.numpy(), y, 2), 0.99)
         self.assertEqual(km.labels_.shape[0], 203)
 
     def test_kmeans_fused_backend_failure_propagates(self):
         # a pallas kernel that fails to lower or run raises out of fit():
-        # there is no path from a kernel exception to the jnp oracle
+        # there is no path from a kernel exception to the jnp path
         import unittest.mock
 
-        from heat_tpu.ops import lloyd as _lloyd_mod
+        from heat_tpu.ops import lloyd
 
         X, _ = make_blobs()
         with unittest.mock.patch.object(
-            _lloyd_mod, "fused_lloyd_run_sharded", side_effect=RuntimeError("mosaic")
+            lloyd, "takes", lambda shape, k, split, sharding: "sharded"
+        ), unittest.mock.patch.object(
+            lloyd, "fused_lloyd_run_sharded", side_effect=RuntimeError("mosaic")
         ):
-            km = ht.cluster.KMeans(n_clusters=3, random_state=5, use_fused=True, max_iter=50)
+            km = ht.cluster.KMeans(n_clusters=3, random_state=5, max_iter=50)
             with pytest.raises(RuntimeError, match="mosaic"):
                 km.fit(ht.array(X, split=0))
-
-    def test_kmeans_forced_fused_unhonorable_warns(self):
-        # use_fused=True with no fused dispatch available must be loud, not
-        # a vacuous pass through the jnp oracle
-        import warnings as _w
-
-        X = np.random.default_rng(14).standard_normal((40, 600)).astype(np.float32)
-        km = ht.cluster.KMeans(n_clusters=2, max_iter=2, random_state=0, use_fused=True)
-        with _w.catch_warnings(record=True) as rec:
-            _w.simplefilter("always")
-            km.fit(ht.array(X, split=0))  # f=600 > 512: no fused dispatch
-        self.assertTrue(any("use_fused=True" in str(x.message) for x in rec))
 
     def test_kmeans_precomputed_init(self):
         X, y = make_blobs()

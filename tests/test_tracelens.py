@@ -207,6 +207,23 @@ import contextlib, sys, time
 import heat_tpu.core.telemetry as telemetry
 import heat_tpu.core.resilience as resilience
 
+
+class Clock:
+    # the host's clock as telemetry reads it, driven by this script alone:
+    # perf_counter reads it, sleep (the trace.hostdelay site's) advances it
+    now = 1000.0
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def perf_counter(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+clock = telemetry.time = Clock()
 out_path, slow = sys.argv[1], sys.argv[2] == "slow"
 telemetry.set_mode("verbose")
 telemetry.reset()
@@ -214,7 +231,7 @@ ctx = resilience.inject("trace.hostdelay", times=None) if slow else contextlib.n
 with ctx:
     for _ in range(12):
         telemetry.record_collective("allreduce", axis="x", nbytes=1024, dtype="float32")
-        time.sleep(0.002)
+        clock.now += 0.002
 telemetry.export_trace(out_path)
 from heat_tpu.core import communication
 assert communication.MESH_WORLD is None, "worker initialized a backend"
@@ -224,9 +241,11 @@ assert communication.MESH_WORLD is None, "worker initialized a backend"
 class TestStragglerAttribution(TracelensCase):
     def _run_hosts(self, td, n_hosts, slow_host):
         """One simulated host per subprocess, all recording the same
-        collective sequence; ``slow_host`` (if any) runs with the
-        ``trace.hostdelay`` fault armed so every record sleeps
-        HEAT_TPU_TRACE_DELAY_MS — cumulative lag only tracelens can name."""
+        collective sequence on a clock the worker drives (2 ms a record);
+        ``slow_host`` (if any) runs with the ``trace.hostdelay`` fault armed,
+        so every record first advances that clock by
+        HEAT_TPU_TRACE_DELAY_MS — cumulative lag only tracelens can name,
+        and the same however busy the machine is."""
         env = dict(os.environ)
         env.setdefault("JAX_PLATFORMS", "cpu")
         env.pop("HEAT_TPU_FAULTS", None)  # deterministic workers under the ci leg
@@ -241,10 +260,8 @@ class TestStragglerAttribution(TracelensCase):
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE,
                 text=True, env=env, cwd=_REPO,
             ))
-            # bound concurrent jax imports by core count: on a 1-core host
-            # co-scheduled workers' import/record quanta show up as ~100ms+
-            # cumulative lag on FAST hosts — rivaling the injected delay the
-            # assertion must attribute — so workers run serially there
+            # bound concurrent imports by core count (load only: no worker
+            # reads the wall clock)
             if len(procs) >= max(1, min(4, os.cpu_count() or 1)):
                 procs.pop(0).wait()
         for p in procs:
@@ -278,16 +295,12 @@ class TestStragglerAttribution(TracelensCase):
             self.assertEqual(findings[0]["host"], slow)
             self.assertEqual(findings[0]["severity"], "warning")
 
-            # control: merging only the healthy hosts names no straggler.
-            # Concurrent worker startup adds scheduler jitter — up to ~90ms
-            # on a single-core box where 4 workers' jax imports time-slice
-            # against each other's record loops — so the control runs with
-            # the threshold above that jitter but still far below the ~180ms
-            # injected lag the main assertion detects at the default.
+            # control: merging only the healthy hosts names no straggler at
+            # the same default threshold (their driven clocks agree exactly)
             healthy = [p for h, p in enumerate(paths) if h != slow]
             merged2 = os.path.join(td, "healthy.json")
             telemetry.merge_traces(healthy, merged2)
-            ana2 = tracelens.analyze(merged2, straggler_ms=120.0)
+            ana2 = tracelens.analyze(merged2)
             self.assertIsNone(ana2["stragglers"]["straggler"], ana2["stragglers"])
             self.assertEqual(
                 [f for f in ana2["findings"] if f["rule"] == "tracelens.straggler"], []
